@@ -278,7 +278,7 @@ def check_module(module):
         if not (isinstance(node, ast.Call) and callee_tail(node) in JIT_WRAPPERS):
             continue
         name = callee_name(node) or ""
-        if not (name in JIT_WRAPPERS or name.startswith(("jax.", "compat."))):
+        if not (name in JIT_WRAPPERS or name.startswith("jax.")):
             continue  # someone else's jit/pmap attribute
         func = enclosing_function(module, node)
         scope = module.qualname(func) if func is not None else ""

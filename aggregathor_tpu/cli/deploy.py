@@ -53,7 +53,11 @@ def build_parser():
     )
     parser.add_argument(
         "--local-simulate", type=int, default=0, metavar="K",
-        help="fork K local CPU processes forming a cluster on localhost (single-machine parity)",
+        help="fork K local processes forming a cluster on localhost "
+             "(single-machine parity).  CPU-only by construction: every "
+             "child is started with JAX_PLATFORMS=cpu — an accelerator "
+             "belongs to one process at a time, so K processes cannot "
+             "share it",
     )
     parser.add_argument("--devices-per-process", type=int, default=1,
                         help="(--local-simulate only) virtual CPU devices "

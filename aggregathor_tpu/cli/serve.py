@@ -302,6 +302,10 @@ def main(argv=None):
     if args.platform:
         jax.config.update("jax_platforms", args.platform)
 
+    from ..utils.compile_cache import place_compile_cache
+
+    place_compile_cache()
+
     from .. import config, gars, models
     from ..obs import Checkpoints, SummaryWriter, trace
     from ..obs.summaries import make_run_id

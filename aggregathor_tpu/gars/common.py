@@ -8,10 +8,15 @@ strip this handling by using explicit ``isfinite`` masking rather than NaN
 comparisons.
 """
 
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
+from jax._src.interpreters.batching import BatchTracer
+
+from ..utils import info
+from ..utils.hw import on_tpu
 
 
 def nonfinite_to_inf(x):
@@ -19,79 +24,74 @@ def nonfinite_to_inf(x):
     return jnp.where(jnp.isfinite(x), x, jnp.inf)
 
 
-#: Column count above which the Pallas coordinate kernels serve a TPU block.
-#: Measured on the v5e (round 4, benchmarks/tpu_capture.jsonl pallas_check):
-#: at d=65k the Pallas rank-select already wins (averaged-median 1.4 ms vs
-#: 16.9 ms for the XLA sort path) and the gap widens with d (8.4M: median
-#: 8.2 ms vs 168 ms, averaged-median 16 ms vs 3871 ms); below ~16k columns a
-#: per-call pad+launch is not worth displacing one small fused sort.
+#: Column count above which the Pallas coordinate kernels serve a TPU block:
+#: below ~16k columns a per-call pad+launch is not worth displacing one small
+#: fused sort.  One constant for every rule; the per-rule crossover has not
+#: been measured on this machine (ROADMAP S5).
 PALLAS_MIN_COLUMNS = 16384
+
 
 def _is_batched_tracer(x):
     """True when ``x`` is being traced under ``jax.vmap`` (batching trace).
 
     Both engines call the rules under vmap on their bucketed paths
-    (engine._aggregate_per_leaf_bucketed, sharded_engine's per-bucket
-    loop); a vmapped ``pallas_call`` lowers through Pallas' batching rule,
-    which the CPU suite exercises only in interpret mode and which is
-    UNVALIDATED on real TPU silicon here (scripts/pallas_tpu_check.py's
-    ``*-vmap4`` rows are the armed proof).  Detecting the batching trace
-    centrally means no call site can forget an opt-out wrapper; the
-    explicit ``GRAFT_GAR_TIER=pallas`` force remains the one way to
-    exercise the vmapped Pallas path end to end.
-
-    Detection is isinstance-first against the real tracer class (imported
-    from its current `_src` home), with the class-NAME scan as fallback in
-    case the module moves in a future JAX — a false negative here would
-    silently re-enable the unproven path, so
-    ``tests/test_pallas.py::test_batched_tracer_detected_under_vmap``
-    fails loudly if neither detection fires under ``jax.vmap``.
+    (engine._aggregate_per_leaf_bucketed, the sharded per-bucket loop); a
+    vmapped ``pallas_call`` lowers through Pallas' batching rule.
+    Detecting the batching trace centrally means no call site can forget an
+    opt-out wrapper; the explicit ``GRAFT_GAR_TIER=pallas`` force remains
+    the one way to exercise the vmapped Pallas path end to end
+    (``tests/test_pallas.py::test_batched_tracer_detected_under_vmap`` fails
+    loudly if the tracer class moves and detection stops firing).
     """
-    if _BATCH_TRACER_CLS is not None and isinstance(x, _BATCH_TRACER_CLS):
-        return True
-    return any(c.__name__ == "BatchTracer" for c in type(x).__mro__)
+    return isinstance(x, BatchTracer)
 
 
-try:  # the canonical home today; the name-scan above covers a future move
-    from jax._src.interpreters.batching import BatchTracer as _BATCH_TRACER_CLS
-except ImportError:  # pragma: no cover
-    _BATCH_TRACER_CLS = None
-
-
-def use_pallas_coordinate_tier(block):
-    """Backend auto-dispatch for the coordinate-wise selection rules.
+def kernel_tier(block):
+    """Which tier serves a coordinate-wise / distance call on ``block``:
+    ``"pallas"``, ``"jnp"`` or ``"jnp (vmapped)"``.
 
     Mirrors the reference's tier policy — the C++ custom op serves the rule
     when loadable, the graph tier otherwise (aggregators/median.py:40-48) —
-    re-targeted at XLA: on TPU, large column blocks go to the hand-written
-    Pallas rank-selection kernels (ops/pallas_kernels.py), which make the
-    SAME selections as the jnp tier (same ranks, same tie-breaks) and agree
-    numerically to float tolerance — the summation order of averaged means
-    differs, so low bits can (asserted on NaN-poisoned inputs by
-    tests/test_pallas.py and on silicon by scripts/pallas_tpu_check.py).
-    ``GRAFT_GAR_TIER=jnp|pallas`` forces a tier (tests, A/B timing).
-
-    Gating note (ADVICE r4): unlike the vmapped path (suspended until its
-    armed silicon proof lands), the un-batched in-engine tier stays ON by
-    default even though its standalone-kernel silicon proof does not cover
-    the full shard_map/scan step — the kernels make the same selections as
-    the jnp tier by construction, the train_configs 2d/3d stages are armed
-    to exercise exactly this path on silicon, and ``GRAFT_GAR_TIER=jnp``
-    is the escape hatch if they surface a divergence.
+    re-targeted at XLA: on TPU (``utils.hw.on_tpu``, the same answer the
+    kernels' interpret switch uses), large column blocks go to the
+    hand-written Pallas rank-selection kernels (ops/pallas_kernels.py),
+    which make the SAME selections as the jnp tier (same ranks, same
+    tie-breaks) and agree numerically to float tolerance — the summation
+    order of averaged means differs, so low bits can (asserted on
+    NaN-poisoned inputs by tests/test_pallas.py and on the chip by
+    scripts/pallas_tpu_check.py).  A vmapped call stays on the jnp tier
+    (ROADMAP S3 lifts that).  ``GRAFT_GAR_TIER=jnp|pallas`` forces a tier
+    (tests, A/B timing); the ``pallas`` force outranks the vmap diversion —
+    it is the only way to exercise the vmapped Pallas path end to end.
     """
     forced = os.environ.get("GRAFT_GAR_TIER")
     if forced == "pallas":
-        return True  # explicit force outranks the vmap suspension: it is
-        # the only way to exercise/A-B the vmapped Pallas path end to end
+        return "pallas"
     if _is_batched_tracer(block):
-        return False  # vmapped call: see _is_batched_tracer
+        return "jnp (vmapped)"
     if forced == "jnp":
-        return False
-    return (
-        jax.default_backend() == "tpu"
-        and block.ndim == 2
-        and block.shape[1] >= PALLAS_MIN_COLUMNS
-    )
+        return "jnp"
+    if on_tpu() and block.ndim == 2 and block.shape[1] >= PALLAS_MIN_COLUMNS:
+        return "pallas"
+    return "jnp"
+
+
+@functools.lru_cache(maxsize=None)
+def _announce_tier(tier, shape):
+    info("GAR kernel tier for a %s block: %s" % ("x".join(map(str, shape)), tier))
+
+
+def use_pallas_coordinate_tier(block):
+    """True when the Pallas tier serves ``block`` (see ``kernel_tier``).
+
+    Called at trace time only; on a TPU each distinct (shape, tier)
+    decision is logged once, so a run's log says which tier served its rule
+    — including the silent ``jnp (vmapped)`` diversion of the leaf paths.
+    """
+    tier = kernel_tier(block)
+    if on_tpu():
+        _announce_tier(tier, tuple(block.shape))
+    return tier == "pallas"
 
 
 #: n²·d element budget above which ``centered_gram_sq_distances`` chunks its

@@ -168,8 +168,7 @@ def describe_abstract(args, kwargs=(), limit=12):
             out.append(type(leaf).__name__)
         else:
             out.append("%s[%s]" % (
-                jax.dtypes.canonicalize_dtype(dtype).name
-                if hasattr(jax.dtypes, "canonicalize_dtype") else str(dtype),
+                jax.dtypes.canonicalize_dtype(dtype).name,
                 ",".join(str(d) for d in shape),
             ))
     if len(leaves) > limit:

@@ -2,8 +2,9 @@
 
 The framework's counterpart of the reference's self-compiling native layer:
 sources in this directory are compiled into one shared library on first
-import, with an mtime-based incremental rebuild (reference:
-native/__init__.py:190-206, aggregators/deprecated_native/__init__.py:43-68).
+use, rebuilt whenever the sources' hash changes (the reference rebuilds on
+mtimes: native/__init__.py:190-206,
+aggregators/deprecated_native/__init__.py:43-68).
 The toolchain is plain ``c++ -std=c++17 -O3`` — no TF/TPU headers, because
 this tier is pure host code: the accelerator path is jnp/Pallas, and this
 library serves host-side aggregation, large-scale oracles, and CPU-only
@@ -18,6 +19,7 @@ plus ``available()`` / ``load()`` / ``build(force=...)`` and
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import tempfile
@@ -27,33 +29,37 @@ import numpy as np
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SOURCES = ("kernels.cpp", "auth.cpp", "io.cpp", "threadpool.hpp")
 _COMPILE_UNITS = ("kernels.cpp", "auth.cpp", "io.cpp")
-_LIBNAME = "libagtpu_host.so"
 
 _lib = None
 _load_error = None
 
 
+def _source_hash():
+    """Hash of every source the library is built from."""
+    digest = hashlib.sha256()
+    for src in _SOURCES:
+        with open(os.path.join(_DIR, src), "rb") as fd:
+            digest.update(src.encode() + b"\0" + fd.read() + b"\0")
+    return digest.hexdigest()[:16]
+
+
 def _lib_path():
-    return os.path.join(_DIR, _LIBNAME)
-
-
-def _must_rebuild():
-    """True when the library is absent or older than any source (mtime check)."""
-    target = _lib_path()
-    if not os.path.exists(target):
-        return True
-    built = os.path.getmtime(target)
-    return any(os.path.getmtime(os.path.join(_DIR, src)) > built for src in _SOURCES)
+    """The library's name records the hash of its sources, so a library no
+    commit's sources produced (a copied tree, a checkout with fresh mtimes)
+    is never loaded: a source change is a different file name."""
+    return os.path.join(_DIR, "libagtpu_host-%s.so" % _source_hash())
 
 
 def build(force=False):
-    """Compile the shared library if stale; returns its path.
+    """Compile the shared library unless the one these sources produce is
+    already there; returns its path.
 
     Atomic: compiles to a temp file in the same directory, then renames —
-    concurrent importers either see the old or the new complete library.
+    concurrent importers either see no library or the complete one.
+    A library of another source hash is simply never loaded again.
     """
     target = _lib_path()
-    if not force and not _must_rebuild():
+    if not force and os.path.exists(target):
         return target
     compiler = os.environ.get("AGTPU_NATIVE_CXX", "c++")
     fd, tmp = tempfile.mkstemp(suffix=".so", prefix=".build-", dir=_DIR)

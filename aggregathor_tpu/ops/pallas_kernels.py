@@ -43,9 +43,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from ..utils.hw import on_tpu
+
 
 def _interpret():
-    return jax.default_backend() != "tpu"
+    return not on_tpu()
 
 
 def _pad_axis(x, axis, multiple, value=0.0):
@@ -94,7 +96,7 @@ def _pick_block_coord(n, d, vmem_budget=1 << 21):
 #: compare+accumulate loop to a ``fori_loop``: at n=512 the unrolled form
 #: emits 512 fused passes into the kernel body — a compile-time blowup —
 #: while the rolled loop compiles one pass.  The unrolled tier stays the
-#: default at small n (the silicon-proven path, scripts/pallas_tpu_check.py).
+#: default at small n (one pass per comparator, no row extraction).
 RANK_UNROLL_MAX = 64
 
 
@@ -102,9 +104,13 @@ def _ranks(key, n):
     """rank[i, :] = #{j : key_j < key_i, ties to lower j}, per coordinate.
 
     n VPU passes of compare+accumulate over the (n, blk) slab; memory stays
-    O(n·blk).  Statically unrolled up to ``RANK_UNROLL_MAX`` comparators,
-    a ``fori_loop`` with a dynamic row slice beyond (identical selections:
-    the loop body is the same compare+accumulate either way).
+    O(n·blk).  Statically unrolled up to ``RANK_UNROLL_MAX`` comparators, a
+    ``fori_loop`` beyond (identical selections: the loop body is the same
+    compare+accumulate either way).  The rolled loop picks comparator row j
+    with a masked max over the rows — Mosaic lowers no ``dynamic_slice`` of
+    a VALUE (found on the chip at PR 21: "Unimplemented primitive in Pallas
+    TPU lowering: dynamic_slice"), and ``key`` holds no NaN (non-finite is
+    keyed +inf), so the max over one unmasked row is exactly that row.
     """
     row = jax.lax.broadcasted_iota(jnp.int32, key.shape, 0)
     if n <= RANK_UNROLL_MAX:
@@ -115,7 +121,7 @@ def _ranks(key, n):
         return ranks
 
     def body(j, ranks):
-        kj = jax.lax.dynamic_slice_in_dim(key, j, 1, axis=0)  # (1, blk)
+        kj = jnp.max(jnp.where(row == j, key, -jnp.inf), axis=0, keepdims=True)  # (1, blk)
         return ranks + jnp.where((kj < key) | ((kj == key) & (j < row)), 1, 0)
 
     return jax.lax.fori_loop(0, n, body, jnp.zeros(key.shape, jnp.int32))

@@ -46,9 +46,16 @@ def make_mesh(nb_workers=None, model_parallelism=1, pipeline_parallelism=1, devi
             "Mesh needs %d devices (%d workers x %d pipe x %d model) but only %d are available"
             % (need, nb_workers, pipeline_parallelism, model_parallelism, len(devices))
         )
+    # Auto axes, on purpose: every collective in the engine is hand-placed
+    # under ``shard_map(check_vma=False)`` and nothing reasons about
+    # sharding-in-types.  ``jax.make_mesh`` defaults to Explicit axes, under
+    # which avals carry ``@worker`` — an extra steady-state compile of the
+    # bounded-wait aggregate and a ShardingTypeError out of ``jnp.nanmedian``
+    # (tests/test_engine.py::test_mesh_axes_are_auto pins the choice).
     return jax.make_mesh(
         (nb_workers, pipeline_parallelism, model_parallelism),
         (worker_axis, pipe_axis, model_axis),
+        axis_types=(jax.sharding.AxisType.Auto,) * 3,
         devices=devices[:need],
     )
 

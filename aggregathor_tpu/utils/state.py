@@ -1,10 +1,10 @@
-"""Tiny atomic JSON state files, shared by the capture/benchmark harnesses.
+"""Tiny atomic JSON state files, shared by the resumable benchmark harnesses.
 
-One load/save pair instead of three copies (watcher stage state, per-cell
-robustness resume, per-config train_configs resume): load tolerates a
+One load/save pair instead of copies (per-cell robustness resume,
+per-config train_configs resume, the sweeps): load tolerates a
 missing/corrupt/non-dict file by returning the default, save goes through a
 tmp file + os.replace so a kill mid-write can never leave a half-written
-state behind (the watcher's children are routinely killed by watchdogs).
+state behind.
 """
 
 import json

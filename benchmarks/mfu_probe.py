@@ -34,10 +34,7 @@ import time
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, REPO)
 
-from aggregathor_tpu.utils.hw import (  # noqa: E402
-    V5E_HBM_BYTES_PER_S as HBM_BW,
-    V5E_PEAK_BF16_FLOPS as PEAK_BF16,
-)
+from aggregathor_tpu.utils.hw import peaks  # noqa: E402
 
 
 def main():
@@ -104,8 +101,7 @@ def _measure(args, batch):
     }
     platform = None
     try:
-        # inside the try: backend init is this environment's documented
-        # failure mode, and the contract is ONE JSON line no matter what
+        # inside the try: the contract is ONE JSON line, errors included
         platform = row["platform"] = jax.devices()[0].platform
         exp = models.instantiate(
             "slim-resnet_v1_50-imagenet",
@@ -149,23 +145,23 @@ def _measure(args, batch):
         t1 = time.perf_counter()
         for _ in range(n_dispatch):
             state, m = multi(state, data)
-        final_loss = sync(m)  # host fetch = the only real device sync
+        final_loss = sync(m)  # host fetch: the timing fence
         rate = n_dispatch * args.unroll / (time.perf_counter() - t1)
         row["value"] = round(rate, 3)
         row["timed_steps"] = n_dispatch * args.unroll
         row["final_loss"] = final_loss
         if row.get("flops_per_step") and platform == "tpu":
-            row["mfu_pct"] = round(100.0 * row["flops_per_step"] * rate / PEAK_BF16, 2)
+            chip = peaks(jax.devices()[0])  # by device_kind; unknown raises
+            row["device_kind"] = jax.devices()[0].device_kind
+            row["mfu_pct"] = round(
+                100.0 * row["flops_per_step"] * rate / chip.bf16_flops, 2)
             if row.get("bytes_per_step"):
                 row["pct_of_hbm_roofline"] = round(
-                    100.0 * row["bytes_per_step"] * rate / HBM_BW, 1)
+                    100.0 * row["bytes_per_step"] * rate / chip.hbm_bytes_per_s, 1)
     except Exception as exc:
         row["error"] = "%s: %s" % (type(exc).__name__, str(exc)[:300])
     return row
 
 
 if __name__ == "__main__":
-    from aggregathor_tpu.utils.proc import graceful_sigterm
-
-    graceful_sigterm()
     main()

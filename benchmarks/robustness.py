@@ -61,6 +61,9 @@ def run_cell(rule, attack, steps, batch, platform, timeout, experiment, extra_ar
     cmd += list(extra_args)
     if platform == "cpu":
         env.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=8")
+    # One process per chip: this parent is stdlib-only (it never imports
+    # JAX), so the runner child is the only process that touches the device;
+    # cells run one after another.  Keep it so.
     try:
         proc = subprocess.run(
             cmd, cwd=REPO, env=env, capture_output=True, text=True, timeout=timeout
@@ -187,10 +190,4 @@ def main():
 
 
 if __name__ == "__main__":
-    # TERM must unwind the interpreter so the backend client closes
-    # cleanly — the capture watcher escalates TERM-before-KILL.
-    sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
-    from aggregathor_tpu.utils.proc import graceful_sigterm
-
-    graceful_sigterm()
     main()

@@ -198,6 +198,13 @@ def _free_port():
 
 def main(argv=None):
     args = build_parser().parse_args(argv)
+    if args.platform == "tpu":
+        # One process per chip: this harness is a fleet of concurrent JAX
+        # processes (and the parent itself imports JAX), so it cannot share
+        # one — it measures the host planes, on the CPU.
+        raise SystemExit("%s spawns several JAX processes; a TPU belongs to "
+                         "one process at a time — run it with --platform cpu"
+                         % os.path.basename(__file__))
     if args.platform:
         os.environ["JAX_PLATFORMS"] = args.platform
 

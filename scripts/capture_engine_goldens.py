@@ -1,8 +1,10 @@
 """Capture fixed-seed golden outputs of the training engines.
 
-Run BEFORE an engine refactor to freeze the current numerics, then assert
-the refactored engine reproduces them bit-exactly
-(tests/test_engine.py::test_unified_engine_bit_identical_to_goldens).
+A regression pin ON ONE INSTALLATION: the digests are exact float bits, so
+they hold only under the JAX that captured them (stamped into the file as
+``jax_version``).  Re-run after an intended numerics change or a JAX
+upgrade; tests/test_engine.py::test_unified_engine_bit_identical_to_*
+assert the engine still reproduces them bit-exactly.
 
 Writes tests/data/golden_engine.json: per-step losses/grad norms as float
 hex strings (lossless) and a SHA-256 over the final parameter bytes.
@@ -99,6 +101,7 @@ def run_sharded(granularity, l1=None, l2=None, momentum=None, gar_name="krum",
 
 def main():
     goldens = {
+        "jax_version": jax.__version__,
         "flat_vector_rich": run_flat(
             "vector", secure=True, momentum=0.9, attack_name="signflip",
             worker_metrics=True, reputation_decay=0.9),
@@ -113,8 +116,9 @@ def main():
         json.dump(goldens, fd, indent=2, sort_keys=True)
     print("goldens -> %s" % out)
     for name, doc in goldens.items():
-        print("  %s: %d losses, params %s..." % (
-            name, len(doc["losses"]), doc["params_sha256"][:16]))
+        if name != "jax_version":
+            print("  %s: %d losses, params %s..." % (
+                name, len(doc["losses"]), doc["params_sha256"][:16]))
 
 
 if __name__ == "__main__":

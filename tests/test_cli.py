@@ -685,3 +685,19 @@ def test_runner_trace_ops_narrative(tmp_path):
     out = proc.stdout + proc.stderr
     for phase in ("losses+gradients done", "aggregate done", "apply done"):
         assert out.count(phase) >= 2, (phase, out[-1500:])
+
+
+def test_chip_smoke_refuses_a_cpu():
+    """chip_smoke.py needs the chip: on a CPU it exits non-zero at once,
+    names the platform it found, and prints no result line."""
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    proc = subprocess.run(
+        [sys.executable, os.path.join(repo, "chip_smoke.py")],
+        capture_output=True, text=True, timeout=120, cwd=repo,
+        env=dict(os.environ, JAX_PLATFORMS="cpu"),
+    )
+    assert proc.returncode != 0
+    assert "platform=cpu" in proc.stdout
+    assert "needs a TPU" in proc.stderr and "'cpu'" in proc.stderr
+    assert '"ok"' not in proc.stdout
+

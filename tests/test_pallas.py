@@ -226,8 +226,7 @@ def test_use_pallas_tier_env_force(monkeypatch):
 def test_use_pallas_tier_suspends_under_vmap(monkeypatch):
     """The auto-dispatch detects a batching trace centrally: even on a
     'tpu' backend with a large block, a vmapped rule call stays on the
-    jnp tier (vmapped pallas_call is unproven on silicon) — while the
-    same call outside vmap dispatches."""
+    jnp tier — while the same call outside vmap dispatches."""
     import jax
 
     from aggregathor_tpu.gars import common
@@ -246,27 +245,40 @@ def test_use_pallas_tier_suspends_under_vmap(monkeypatch):
 
 
 def test_batched_tracer_detected_under_vmap():
-    """ADVICE r4: the vmap suspension must not silently die with a JAX
-    upgrade.  The isinstance path must be LIVE (the tracer class resolves
-    from its current home) and _is_batched_tracer must fire under vmap by
-    isinstance alone, not only by the class-name fallback."""
+    """The vmap diversion must not silently die with a JAX upgrade:
+    _is_batched_tracer fires under vmap (an import failure of the tracer
+    class already fails the whole module at collection)."""
     import jax
 
     from aggregathor_tpu.gars import common
 
-    assert common._BATCH_TRACER_CLS is not None, (
-        "BatchTracer moved: update the import in gars/common.py or the "
-        "vmapped-Pallas suspension rests on the name-scan fallback alone")
     seen = []
 
     def probe(x):
-        seen.append((common._is_batched_tracer(x),
-                     isinstance(x, common._BATCH_TRACER_CLS)))
+        seen.append(common._is_batched_tracer(x))
         return x
 
     jax.vmap(probe)(np.zeros((2, 4), np.float32))
     probe(np.zeros((4,), np.float32))
-    assert seen == [(True, True), (False, False)]
+    assert seen == [True, False]
+
+
+def test_kernel_tier_names_the_served_tier(monkeypatch):
+    """One decision, three answers: pallas on a TPU-sized block, jnp below
+    the column threshold or off-TPU, and 'jnp (vmapped)' where the batching
+    trace diverts — the string the runner's log line carries."""
+    import jax
+
+    from aggregathor_tpu.gars import common
+
+    big = np.zeros((8, common.PALLAS_MIN_COLUMNS), np.float32)
+    assert common.kernel_tier(big) == "jnp"  # CPU backend
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert common.kernel_tier(big) == "pallas"
+    assert common.kernel_tier(big[:, :128]) == "jnp"
+    tiers = []
+    jax.vmap(lambda x: tiers.append(common.kernel_tier(x)) or x.sum())(big[None])
+    assert tiers == ["jnp (vmapped)"]
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -287,3 +299,31 @@ def test_coordinate_trimmed_mean_poisoned_band():
     ref = oracle.trimmed_mean(g, 2)
     assert np.isnan(out[7]) and np.isnan(ref[7])
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6, equal_nan=True)
+
+
+def test_pallas_tpu_check_callable_in_interpret_mode(monkeypatch):
+    """scripts/pallas_tpu_check.run_check — chip_smoke's leg C — run off-TPU
+    at a tiny d: every row comes back parity-ok through ``emit``, the jnp
+    pin is restored, and without --allow-interpret it refuses to interpret."""
+    import os
+    import sys
+
+    scripts = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "scripts")
+    monkeypatch.syspath_prepend(scripts)
+    import pallas_tpu_check
+
+    monkeypatch.delenv("GRAFT_GAR_TIER", raising=False)
+    rows = []
+    failed = pallas_tpu_check.run_check(
+        8, 2, [256], rules=("median", "krum"), reps=1,
+        allow_interpret=True, emit=rows.append)
+    assert failed == []
+    assert [r["rule"] for r in rows] == [
+        "median", "krum", "median-vmap4", "averaged-median-vmap4",
+        "trimmed-mean-vmap4", "pairwise-dist-vmap4"]
+    assert all(r["parity"] == "ok" for r in rows)
+    assert "GRAFT_GAR_TIER" not in os.environ
+    with pytest.raises(RuntimeError, match="needs a TPU"):
+        pallas_tpu_check.run_check(8, 2, [256], rules=("median",), reps=1)
+    sys.modules.pop("pallas_tpu_check", None)
+

@@ -16,7 +16,6 @@ from aggregathor_tpu import config, gars
 from aggregathor_tpu.models import transformer as tfm
 from aggregathor_tpu.parallel import ShardedRobustEngine
 from aggregathor_tpu.parallel.mesh import factor_devices, make_mesh
-from aggregathor_tpu.utils import compat
 
 CFG = tfm.TransformerConfig(vocab_size=17, d_model=16, n_heads=2, n_layers=4)
 
@@ -62,7 +61,7 @@ def test_ring_attention_matches_dense(rng):
 
     spec = P(None, config.model_axis, None, None)
     ringed = jax.jit(
-        compat.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
+        jax.shard_map(body, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False)
     )(q, k, v)
     np.testing.assert_allclose(np.asarray(ringed), np.asarray(dense), rtol=2e-5, atol=2e-5)
 
@@ -79,7 +78,7 @@ def test_pipeline_loss_matches_dense(rng):
         return jax.lax.psum(loss_fn(p, b), (config.pipe_axis, config.model_axis))
 
     sharded = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             body,
             mesh=mesh,
             in_specs=(tfm.param_specs(CFG), P()),

@@ -32,9 +32,9 @@ def test_can_access_write_only_check(tmp_path):
 
 
 def test_state_json_roundtrip_and_corruption(tmp_path):
-    """utils/state: atomic save + tolerant load (the watcher's children are
-    routinely killed mid-write; a half-written or non-dict file must read
-    as the default, never raise)."""
+    """utils/state: atomic save + tolerant load (a resumable benchmark can
+    be killed mid-write; a half-written or non-dict file must read as the
+    default, never raise)."""
     from aggregathor_tpu.utils.state import load_json, save_json_atomic
 
     path = str(tmp_path / "s.json")
@@ -50,16 +50,48 @@ def test_state_json_roundtrip_and_corruption(tmp_path):
     assert load_json(path, default={"done": []}) == {"done": []}
 
 
-def test_capture_completeness_predicate():
-    """utils/capture: the shared stage-retirement / banked-row predicate."""
-    from aggregathor_tpu.utils.capture import is_complete_tpu_datum
+def test_hw_peaks_keyed_by_device_kind():
+    """utils/hw: one sourced table keyed by device_kind; a kind that is not
+    in it raises and names itself — never another chip's peak."""
+    import types
 
-    assert is_complete_tpu_datum(
-        {"metric": "cnnet_cifar10_multikrum_x", "detail": {
-            "platform": "tpu", "bfloat16": {"steps_per_s_resident_batch": 4.0}}})
-    assert not is_complete_tpu_datum(
-        {"metric": "cnnet_cifar10_multikrum_x", "detail": {"platform": "tpu"}})
-    assert not is_complete_tpu_datum({"platform": "tpu", "error": "timed out"})
-    assert is_complete_tpu_datum({"platform": "tpu", "value": 1.0})
-    assert is_complete_tpu_datum({"tier": "pallas", "value": 1.0})
-    assert not is_complete_tpu_datum({"tier": "native", "value": 1.0})
+    import pytest
+
+    from aggregathor_tpu.utils import hw
+
+    v5e = hw.peaks(types.SimpleNamespace(device_kind="TPU v5 lite", platform="tpu"))
+    assert v5e.bf16_flops == 1.97e14 and v5e.hbm_bytes_per_s == 8.19e11
+    assert "TPU v5e" in v5e.source
+    with pytest.raises(KeyError, match="cpu-kind-nobody-listed"):
+        hw.peaks(types.SimpleNamespace(device_kind="cpu-kind-nobody-listed",
+                                       platform="cpu"))
+
+
+def test_compile_cache_is_placed_from_outside(monkeypatch):
+    """utils/compile_cache: with JAX_COMPILATION_CACHE_DIR in the environment
+    the directory setting is left alone (JAX reads it); without it the cache
+    goes to the fixed <checkout>/.jax_cache; on a CPU backend nothing is
+    placed at all."""
+    import jax
+
+    from aggregathor_tpu.utils import compile_cache
+
+    updates = []
+    monkeypatch.setattr(jax.config, "update", lambda name, value: updates.append((name, value)))
+
+    def placed_dirs():
+        return [value for name, value in updates if name.endswith("cache_dir")]
+
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    assert compile_cache.place_compile_cache() is None  # the suite runs on CPU
+    assert updates == []
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    assert compile_cache.place_compile_cache() == os.path.join(repo, ".jax_cache")
+    assert placed_dirs() == [os.path.join(repo, ".jax_cache")]
+
+    del updates[:]
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/some/dir")
+    assert compile_cache.place_compile_cache() == "/some/dir"
+    assert placed_dirs() == []
