@@ -1,0 +1,7 @@
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+# four CPU devices, so that the four-chip cell's mesh can be built and traced
+os.environ.setdefault("XLA_FLAGS", "--xla_force_host_platform_device_count=4")
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
