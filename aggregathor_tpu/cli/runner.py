@@ -367,8 +367,8 @@ def build_parser():
              "backoff:B, recover:N, ladder:RUNG,RUNG,... — see "
              "docs/guardian.md for the ladder grammar)",
     )
-    parser.add_argument("--trace", action="store_true", help="capture a jax.profiler trace of a few steps")
-    parser.add_argument("--trace-dir", default="trace", help="profiler trace output directory")
+    parser.add_argument("--trace-dir", default="trace",
+                        help="where --xprof writes its jax.profiler capture")
     parser.add_argument(
         "--trace-file", default=None, metavar="PATH",
         help="whole-run HOST span trace (obs/trace): dispatch / block / "
@@ -429,9 +429,11 @@ def build_parser():
         help="programmatic jax.profiler device capture over steps [A, B) "
              "into --trace-dir (obs/profiler.py): dispatches inside the "
              "window carry StepTraceAnnotations so the host span trace "
-             "joins the device timeline per step; under --unroll the "
-             "window lands on chunk boundaries (mutually exclusive with "
-             "--trace)",
+             "joins the device timeline per step, and every obs/trace span "
+             "of the program lands in the capture under its own name; "
+             "under --unroll the window lands on chunk boundaries.  Cut "
+             "the capture by step phase with obs.profiler.phase_table "
+             "(docs/observability.md)",
     )
     parser.add_argument(
         "--live-port", type=int, default=None, metavar="PORT",
@@ -474,11 +476,6 @@ def build_parser():
              "the forensics report so the streams join after the fact "
              "(default: generated)",
     )
-    parser.add_argument("--trace-ops", action="store_true",
-                        help="per-op terminal narrative: print a marker after "
-                             "each phase of the step body (gradients, "
-                             "aggregate, apply) — the reference's op-bracket "
-                             "trace (tools/tf.py:41-58); debug cadence only")
     # Mesh (replaces cluster/job flags, reference: runner.py:81-93, 220-231)
     parser.add_argument("--nb-devices", type=int, default=None, help="devices on the worker mesh axis")
     parser.add_argument("--platform", default=None,
@@ -659,10 +656,6 @@ def main(argv=None):
         raise UserException("--live-ready-file needs --live-port")
     if args.slo_verdict and not args.slo_baseline:
         raise UserException("--slo-verdict needs --slo-baseline")
-    if args.xprof and args.trace:
-        raise UserException(
-            "--xprof and --trace both drive the jax.profiler; pick one"
-        )
     # Sentinel baseline loads AT STARTUP: a missing/garbled document must
     # fail before an hour of training, not at the verdict.
     sentinel = obs_slo.Sentinel(args.slo_baseline) if args.slo_baseline else None
@@ -931,11 +924,6 @@ def main(argv=None):
                 warning(
                     "--leaf-bucketing applies to the flat engine's leaf path "
                     "only; the sharded engine always aggregates per bucket"
-                )
-            if args.trace_ops:
-                warning(
-                    "--trace-ops narrates the flat engine's step body only; "
-                    "ignored under --mesh (use --trace for a profiler window)"
                 )
         else:
             if args.granularity in ("layer", "global"):
@@ -1279,7 +1267,6 @@ def main(argv=None):
                     quarantine_threshold=ov.quarantine_threshold,
                     granularity=args.granularity,
                     leaf_bucketing={"auto": "auto", "on": True, "off": False}[args.leaf_bucketing],
-                    trace_ops=args.trace_ops,
                     # under bounded-wait the straggler schedule moved to the
                     # HOST clock (straggler_model); in-graph chaos is off
                     chaos=None if bounded_wait else chaos,
@@ -1653,7 +1640,7 @@ def main(argv=None):
                 prefetcher = DevicePrefetcher(
                     train_iter, ts.engine.shard_batch, depth=args.prefetch
                 )
-            elif not args.trace:
+            else:
                 # The three-stage chunk pipeline (docs/input_pipeline.md):
                 # parallel sharded gather into ping-pong host buffers,
                 # sliced async transfer, device-side assemble — overlap is
@@ -1666,9 +1653,7 @@ def main(argv=None):
                 # sample stream ahead nondeterministically.  By the time the
                 # per-step tail starts, all chunks were consumed, so the
                 # producer has exhausted its iterator and exited — the tail's
-                # direct train_iter use cannot race the daemon.  (--trace runs
-                # interleave per-step and unrolled dispatches, breaking the
-                # chunk count: they keep the synchronous path.)
+                # direct train_iter use cannot race the daemon.
                 chunks_total = max(0, (max_step - start_step)) // unroll
                 if chunks_total > 0 and supports_buffered_next_many(train_iter):
                     chunk_pipeline = ChunkPipeline(
@@ -1859,7 +1844,6 @@ def main(argv=None):
     diverged = False
     with Context("train"):
         step = offstep
-        trace_ctx = None
         # NaN divergence is checked with a ONE-STEP LAG: blocking on the
         # current step's loss every iteration would serialize host and device
         # and defeat async dispatch; checking the previous step's (by now
@@ -2344,18 +2328,13 @@ def main(argv=None):
                         continue
                     check_divergence()
                     break
-                if args.trace and step == offstep + 2:  # skip compile + warmup step
-                    import jax.profiler
-
-                    trace_ctx = jax.profiler.trace(args.trace_dir)
-                    trace_ctx.__enter__()
                 if xprof is not None:
                     # programmatic device capture over an explicit step
                     # window; under --unroll the boundary lands on the
                     # chunk boundary (a compiled scan is never split)
                     xprof.maybe_start(step)
                 chunk = 1
-                if ts.multi_fn is not None and max_step - step >= unroll and trace_ctx is None:
+                if ts.multi_fn is not None and max_step - step >= unroll:
                     # Unrolled dispatch: K distinct batches, one executable
                     # (device-sampled: the resident dataset IS the input and
                     # the trainer draws its own fresh per-step batches)
@@ -2382,15 +2361,13 @@ def main(argv=None):
                     pending_start = step
                 elif ts.sampled_tail is not None:
                     # Device-sampled tail: the final (max_step - step) <
-                    # unroll steps — and --trace windows, one step per
-                    # dispatch so the profiler window sees step boundaries —
-                    # run through a tail-sized SAMPLED executable.  Every
+                    # unroll steps run through a tail-sized SAMPLED executable.  Every
                     # step of a device-input run is device-sampled; no
                     # host-batch fallback remains.  The tail length is a
                     # pure function of (max_step, offstep, unroll), so the
                     # executable compiles once per run (asserted by
                     # tests/test_input_pipeline.py's compile-count test).
-                    nb_steps = 1 if trace_ctx is not None else max_step - step
+                    nb_steps = max_step - step
                     tail_fn = ts.sampled_tail(nb_steps)
                     gap_close()
                     perf.step_begin()
@@ -2442,10 +2419,6 @@ def main(argv=None):
                             "regime": regime_now,
                             "spec": chaos.describe(regime_now),
                         })
-                if trace_ctx is not None and step >= offstep + 5:
-                    trace_ctx.__exit__(None, None, None)
-                    trace_ctx = None
-                    info("Profiler trace written to %r" % args.trace_dir)
                 if eval_trigger.should_fire(step):
                     check_divergence()
                     run_eval(step)
@@ -2473,8 +2446,6 @@ def main(argv=None):
         finally:
             for signum, handler in previous_handlers.items():
                 signal.signal(signum, handler)
-            if trace_ctx is not None:
-                trace_ctx.__exit__(None, None, None)
             if xprof is not None:
                 xprof.close()
             aborting = sys.exc_info()[0] is not None

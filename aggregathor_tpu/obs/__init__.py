@@ -31,6 +31,7 @@ The device-side layer (docs/observability.md "Device-side observability"):
   lanes written inside the jitted scan, fetched once per summary fire,
   dumped post-mortem (schema ``aggregathor.obs.flight.v1``)
 - ``profiler``        step-windowed ``jax.profiler`` captures (``--xprof``),
+  ``phase_table`` (the compiled step cut by its ``step.<phase>`` scopes),
   compile-cache-miss observability, device memory gauges
 - ``live``            ``LiveExporter`` — the training run's own
   ``/metrics`` + ``/status`` HTTP endpoint

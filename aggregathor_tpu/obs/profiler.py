@@ -26,12 +26,202 @@ zero-recompile discipline):
   backends that do not report, e.g. XLA:CPU).
 """
 
+import collections
 import contextlib
+import re
 import threading
 
 import jax
 
 from ..utils import UserException, info
+
+#: what every step phase's ``jax.named_scope`` starts with (parallel/engine.py
+#: ``phase``): keeps a phase apart from a flax module's or JAX's own
+#: ``jvp(...)`` / ``transpose(...)`` elements of an ``op_name`` path
+PHASE_PREFIX = "step."
+
+
+# --------------------------------------------------------------------- #
+# the compiled program, cut by phase
+
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_INSTRUCTION = re.compile(r"^\s+(ROOT )?%?([\w.\-]+) = ")
+_OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
+_CALLS = re.compile(r"\bcalls=%?([\w.\-]+)")
+_CALLED = re.compile(r"\b(?:calls|body|condition|to_apply|true_computation|false_computation)=%?([\w.\-]+)")
+#: what the compiler inserts so that a LATER instruction finds its operand in
+#: the layout or memory space it wants: booked to what it feeds
+_RELAYOUTS = ("copy", "copy-start", "copy-done", "while")
+#: what takes no time on the device: looked through, never booked
+_PLUMBING = ("get-tuple-element", "tuple", "bitcast", "parameter", "constant")
+_ELEMENT = re.compile(r" get-tuple-element\(.*\), index=(\d+)")
+_BODY = re.compile(r" while\(.*\bbody=%?([\w.\-]+)")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+_OP_NAME = re.compile(r'\bop_name="([^"]*)"')
+_PHASE = re.compile(r"(?:^|[/(])%s([A-Za-z_]+)(?=[/)]|$)" % re.escape(PHASE_PREFIX))
+
+
+def phase_of(op_name):
+    """The innermost step phase on an ``op_name`` path, or None:
+    ``jit(many)/while/body/step.grad/vmap(jvp(conv))/mul`` -> ``grad``."""
+    found = _PHASE.findall(op_name or "")
+    return found[-1] if found else None
+
+
+def _computations(hlo_text):
+    """{computation: [(instruction, is root, its line)]} of an HLO text."""
+    computations, current = {}, None
+    for line in hlo_text.splitlines():
+        if current is None:
+            header = _COMPUTATION.match(line)
+            if header:
+                current = computations.setdefault(header.group(1), [])
+        elif line.startswith("}"):
+            current = None
+        else:
+            found = _INSTRUCTION.match(line)
+            if found:
+                current.append((found.group(2), bool(found.group(1)), line))
+    return computations
+
+
+def phase_table(hlo_text):
+    """``({instruction name: phase or None}, notes)`` of a compiled program's
+    text (``compiled.as_text()``, ``TracedCallable.compiled_text()``).
+
+    An instruction's phase is the innermost ``step.<phase>`` scope of its
+    ``metadata={op_name="..."}`` (parallel/engine.py ``phase``); a profiler
+    capture names each device event after its instruction, so the table cuts
+    any ``--xprof`` capture of the program by phase.  Two kinds of entry rest
+    on more than the instruction's own metadata, and ``notes`` lists both so
+    that a reader can say how much of its split is soft:
+
+    - ``notes["soft"]``: fusions whose fused computation holds instructions of
+      more than one phase.  A fusion takes the phase of its own metadata, else
+      of its fused computation's root, else of most of its fused instructions,
+      and its time is booked whole to that one phase.
+    - ``notes["inherited"]``: instructions the compiler made and gave no
+      ``op_name`` (layout copies, the ``dynamic-update-slice`` chain a
+      ``concatenate`` becomes, the loop a relayout becomes).  A copy or a
+      loop, put there so that a later instruction finds its operand laid out
+      as it wants, takes the phase of the nearest instruction it feeds, else
+      of the nearest that feeds it; any other takes the phase of the nearest
+      instruction that feeds it, else of the nearest it feeds; the inside of a
+      bare loop takes the loop's.
+
+    ``None`` is left for an instruction whose ``op_name`` names no phase (the
+    scan's ``while`` itself, its counter) and for a bare one next to none.
+    Raises ``ValueError`` when no instruction is under any phase: JAX's
+    persistent compilation cache leaves metadata out of its key, so a program
+    loaded from a cache directory that a build WITHOUT the scopes filled is
+    that build's program, and carries none."""
+    computations = _computations(hlo_text)
+
+    def own_phase(line):
+        found = _OP_NAME.search(line)
+        return phase_of(found.group(1)) if found else None
+
+    table, soft, operands, bare, moved, plumbing = {}, [], {}, set(), set(), set()
+    home, caller = {}, {}  # instruction -> its computation -> the instruction that calls it
+    roots, loops, element = {}, {}, {}  # computation -> root; while -> body; get-tuple-element -> index
+    for computation, instructions in computations.items():
+        for name, is_root, line in instructions:
+            home[name] = computation
+            if is_root:
+                roots[computation] = name
+            index = _ELEMENT.search(line)
+            if index:
+                element[name] = int(index.group(1))
+            body_of = _BODY.search(line)
+            if body_of:
+                loops[name] = body_of.group(1)
+            for called_name in _CALLED.findall(line):
+                caller[called_name] = name
+            phase = own_phase(line)
+            body = line.split(" = ", 1)[1]
+            opcode = _OPCODE.search(body)
+            called = _CALLS.search(line)
+            if opcode and opcode.group(1) == "fusion" and called:
+                fused = computations.get(called.group(1), [])
+                inside = [own_phase(fused_line) for _n, _r, fused_line in fused]
+                held = collections.Counter(p for p in inside if p is not None)
+                if len(held) > 1:
+                    soft.append(name)
+                if phase is None:
+                    at_root = [p for (_n, at, _l), p in zip(fused, inside) if at]
+                    phase = at_root[0] if at_root and at_root[0] is not None else (
+                        held.most_common(1)[0][0] if held else None)
+            table[name] = phase
+            if phase is None and not _OP_NAME.search(line):
+                bare.add(name)  # the compiler's own: it may inherit
+                if opcode and opcode.group(1) in _RELAYOUTS:
+                    moved.add(name)
+                elif opcode and opcode.group(1) in _PLUMBING:
+                    plumbing.add(name)
+            if opcode:
+                # the operand list ends at the first ")" that a "," or the line's end follows
+                listed = re.match(r"[^)]*(?:\)(?!,|$)[^)]*)*", body[opcode.end():]).group(0)
+                operands[name] = _OPERAND.findall(listed)
+    if not any(phase is not None for phase in table.values()):
+        raise ValueError(
+            "none of the program's %d instructions is under a %s<phase> scope: either the "
+            "program is not a step the engine built, or it was loaded from a persistent "
+            "compilation cache (JAX_COMPILATION_CACHE_DIR, <checkout>/.jax_cache) that a "
+            "build without the scopes filled - the cache key leaves metadata out, so the "
+            "cached program is that build's; clear the directory or point at a fresh one"
+            % (len(table), PHASE_PREFIX))
+
+    users = collections.defaultdict(list)
+    for name, feeds in operands.items():
+        for operand in feeds:
+            users[operand].append(name)
+    # a loop hands on, untouched, what its body only passes through: those
+    # elements' readers are not the loop's to be booked to
+    for loop, body in loops.items():
+        root = operands.get(roots.get(body), [])
+        passed = {index for index, fed in enumerate(root)
+                  if element.get(fed) == index and not operands.get(operands[fed][0])}
+        users[loop] = [user for user in users[loop] if element.get(user) not in passed]
+
+    def nearest(start, neighbours):
+        """Breadth-first from ``start`` through the compiler's own
+        instructions to the first instruction that has a phase."""
+        seen, queue = {start}, collections.deque([start])
+        while queue:
+            for other in neighbours.get(queue.popleft(), ()):
+                if other in seen:
+                    continue
+                if table.get(other) is not None:
+                    return table[other]
+                if other in bare:
+                    seen.add(other)
+                    queue.append(other)
+        return None
+
+    inherited = {}
+    while True:
+        found = {}
+        # pieces of a decomposed operation first, by what feeds them; then the
+        # relayouts, by what they feed (which may be such a piece)
+        for names, first, second in ((bare - moved - plumbing, operands, users),
+                                     (moved, users, operands)):
+            resolved = {}
+            for name in names:
+                if table[name] is None:
+                    near = nearest(name, first) or nearest(name, second)
+                    if near is not None:
+                        resolved[name] = near
+            table.update(resolved)
+            found.update(resolved)
+        # what is still bare inside a called computation (a loop the compiler
+        # made of a relayout, say) is its caller's
+        for name in bare - plumbing:
+            if table[name] is None and table.get(caller.get(home[name])) is not None:
+                found[name] = table[name] = table[caller[home[name]]]
+        if not found:
+            break
+        inherited.update(found)
+    return table, {"soft": soft, "inherited": sorted(inherited)}
 
 
 # --------------------------------------------------------------------- #

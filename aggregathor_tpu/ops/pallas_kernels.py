@@ -172,11 +172,13 @@ def _trimmed_mean_kernel(n, trim, keep, x_ref, out_ref):
     _store_row(out_ref, jnp.where(jnp.isfinite(mean), mean, jnp.nan))
 
 
-def _coordinate_call(kernel, x, block_d=None):
+def _coordinate_call(name, kernel, x, block_d=None):
     """Run a (n, blk) -> row coordinate kernel over column blocks.
 
-    Rank thresholds inside ``kernel`` use the REAL n; the slab rows are
-    padded to the f32 sublane multiple with NaN (neutral, module docstring).
+    ``name`` is the public function's: what the ``pallas_call`` is called in
+    a compiled program and a device trace.  Rank thresholds inside ``kernel``
+    use the REAL n; the slab rows are padded to the f32 sublane multiple with
+    NaN (neutral, module docstring).
     """
     n, d = x.shape
     rows = n + (-n) % 8  # the slab the kernel actually holds is padded
@@ -191,6 +193,7 @@ def _coordinate_call(kernel, x, block_d=None):
         out_specs=pl.BlockSpec((8, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((8, xp.shape[1]), jnp.float32),
         interpret=_interpret(),
+        name=name,
     )(xp)
     return out[0, :d]
 
@@ -198,13 +201,15 @@ def _coordinate_call(kernel, x, block_d=None):
 def coordinate_median(x, block_d=None):
     """(d,) upper median per column of an (n, d) matrix, non-finite last."""
     n = x.shape[0]
-    return _coordinate_call(functools.partial(_median_kernel, n), x, block_d)
+    return _coordinate_call(
+        "coordinate_median", functools.partial(_median_kernel, n), x, block_d)
 
 
 def coordinate_averaged_median(x, beta, block_d=None):
     """(d,) per-column mean of the ``beta`` values closest to the median."""
     n = x.shape[0]
     return _coordinate_call(
+        "coordinate_averaged_median",
         functools.partial(_averaged_median_kernel, n, int(beta)), x, block_d
     )
 
@@ -214,6 +219,7 @@ def coordinate_trimmed_mean(x, trim, keep, block_d=None):
     with non-finite mapped to +inf; NaN where the kept band is poisoned."""
     n = x.shape[0]
     return _coordinate_call(
+        "coordinate_trimmed_mean",
         functools.partial(_trimmed_mean_kernel, n, int(trim), int(keep)), x, block_d
     )
 
@@ -228,7 +234,7 @@ def average_nan_columns(x, block_d=None):
         count = jnp.sum(finite.astype(jnp.float32), axis=0)
         _store_row(out_ref, jnp.where(count > 0, total / jnp.maximum(count, 1.0), 0.0))
 
-    return _coordinate_call(kernel, x, block_d)
+    return _coordinate_call("average_nan_columns", kernel, x, block_d)
 
 
 # --------------------------------------------------------------------------- #
@@ -315,6 +321,7 @@ def pairwise_sq_distances(x, block_d=None, use_mxu=None, row_tile=None):
         out_specs=pl.BlockSpec((tile, tile), lambda i, j, k: (i, j), memory_space=pltpu.VMEM),
         out_shape=jax.ShapeDtypeStruct((rows_p, rows_p), jnp.float32),
         interpret=_interpret(),
+        name="pairwise_sq_distances",
     )(xp, xp)
     out = out[:n, :n]
     # Column padding contributes zero to every distance.  The Gram form can

@@ -42,6 +42,7 @@ from ..core.flatten import FlatMap
 from ..core.train_state import TrainState
 from ..gars.common import centered_gram_sq_distances
 from ..obs import trace
+from ..obs.profiler import PHASE_PREFIX
 from ..utils import UserException
 from ..utils.hw import on_tpu
 from .mesh import model_axis, pipe_axis, worker_axis
@@ -50,6 +51,40 @@ from .mesh import model_axis, pipe_axis, worker_axis
 #: leafwise-sharded mode — collectives over these complete replicated-leaf
 #: gradients and per-bucket distances; both are size 1 in flat mode
 _IN_GROUP_AXES = (pipe_axis, model_axis)
+
+#: The step's phases, in the order a step runs them.  Each is a
+#: ``jax.named_scope`` (``step.<phase>``) round its part of every step body
+#: below: a scope writes ``op_name`` metadata and nothing else, so the
+#: compiled program is the same program, and ``obs.profiler.phase_table``
+#: reads from its text which instruction belongs to which phase — what cuts
+#: a device trace of the step by phase (docs/observability.md).
+PHASES = ("sample", "augment", "grad", "flatten", "perturb", "reshard", "gar",
+          "gather", "apply", "epilogue")
+
+
+_SCOPE_OF = {name: PHASE_PREFIX + name for name in PHASES}
+
+#: Revision of where the scopes sit, carried in the name of every program with
+#: phases that the engine jits (``jit_many_p1``).  JAX's persistent compilation
+#: cache keys a program on its name and operations and leaves ``op_name``
+#: metadata out of the key: under one name, a build that adds or moves a scope
+#: is handed the program that an earlier build left in a shared cache directory
+#: (an exported ``JAX_COMPILATION_CACHE_DIR``), with THAT build's scopes or none,
+#: and ``phase_table`` would cut a trace by them.  Bump it whenever a
+#: ``phase(...)`` is added, moved or renamed (tests/test_phases.py holds the
+#: list of call sites against it).
+PHASES_REVISION = 1
+
+
+def _revised(fn):
+    """``fn`` renamed to carry ``PHASES_REVISION``, for ``jax.jit``."""
+    fn.__name__ = "%s_p%d" % (fn.__name__, PHASES_REVISION)
+    return fn
+
+
+def phase(name):
+    """The named scope of one of ``PHASES`` (another name is a ``KeyError``)."""
+    return jax.named_scope(_SCOPE_OF[name])
 
 
 def _is_spec(x):
@@ -210,7 +245,7 @@ class RobustEngine:
     def __init__(self, mesh, gar, nb_workers=None, nb_real_byz=0, attack=None, lossy_link=None,
                  exchange_dtype=None, exchange=None, worker_momentum=None, batch_transform=None,
                  worker_metrics=False, reputation_decay=None, quarantine_threshold=0.0,
-                 granularity=None, leaf_bucketing="auto", trace_ops=False, chaos=None,
+                 granularity=None, leaf_bucketing="auto", chaos=None,
                  health_probe=True, secure=False, flight=None,
                  l1_regularize=None, l2_regularize=None, sharding=None):
         self.mesh = mesh
@@ -240,11 +275,6 @@ class RobustEngine:
                 raise UserException(
                     "batch_transform is a flat-engine feature (the sharded "
                     "batches flow through the pipeline stages)"
-                )
-            if trace_ops:
-                raise UserException(
-                    "trace_ops narrates the flat step body only; use --trace "
-                    "for a profiler window on the sharded engine"
                 )
         else:
             if granularity not in ("vector", "leaf"):
@@ -277,13 +307,6 @@ class RobustEngine:
         # independent of nb_workers/device placement — the same discipline
         # as the host tier (models/preprocessing.py).
         self.batch_transform = batch_transform
-        # Per-op terminal narrative (the reference's --trace brackets every
-        # loss/gradient/aggregate op with begin/end prints, tools/tf.py:41-58;
-        # its graph-level equivalent here is a runtime jax.debug.print after
-        # each phase of the step body, value-anchored so the callback sits at
-        # the phase boundary in the compiled program).  Debug-cadence only —
-        # each device narrates, and the host callback costs real time.
-        self.trace_ops = bool(trace_ops)
         # Opt-in per-worker suspicion diagnostics (worker_sq_dist / worker_
         # participation metrics); off by default — the extra O(n·d) pass is
         # a measurable HBM tax at scale.
@@ -467,10 +490,13 @@ class RobustEngine:
             loss, grads = jax.value_and_grad(loss_fn)(params, worker_batch)
             return loss, grads
 
-        losses, grads = jax.vmap(one)(batch_shard)
+        with phase("grad"):
+            losses, grads = jax.vmap(one)(batch_shard)
         k = self.workers_per_device
         leaves = jax.tree_util.tree_leaves(grads)
-        gvecs = jnp.concatenate([leaf.reshape(k, -1).astype(jnp.float32) for leaf in leaves], axis=1)
+        with phase("flatten"):
+            gvecs = jnp.concatenate(
+                [leaf.reshape(k, -1).astype(jnp.float32) for leaf in leaves], axis=1)
         flatmap = FlatMap(jax.tree_util.tree_map(lambda g: g[0], grads))
         return losses, gvecs, flatmap
 
@@ -976,15 +1002,6 @@ class RobustEngine:
         W = self.nb_devices
 
         def body(state, batch):
-            def mark(fmt, **kw):
-                # Anchored on the values it prints, so the callback cannot be
-                # hoisted across the phase it brackets (XLA preserves the
-                # data dependency; pure prints could reorder freely).
-                if self.trace_ops:
-                    jax.debug.print(
-                        "TRACE step {step} dev {dev} " + fmt,
-                        step=state.step, dev=jax.lax.axis_index(worker_axis), **kw)
-
             key = jax.random.fold_in(state.rng, state.step)
             # Active chaos regime for THIS step: a traced array index into
             # the schedule's compiled knob vectors, so regime switches land
@@ -1000,105 +1017,110 @@ class RobustEngine:
                     wkey = jax.random.fold_in(jax.random.fold_in(key, didx * k + j), 3)
                     return self.batch_transform(worker_batch, wkey)
 
-                batch = jax.vmap(aug_one)(batch, jnp.arange(k))
+                with phase("augment"):
+                    batch = jax.vmap(aug_one)(batch, jnp.arange(k))
             losses, gvecs, flatmap = self._worker_gradients(state.params, batch, loss_fn)
             if self.codec is not None:
                 # the codec budget is validated at the first trace, which
                 # is also every guardian-escalation rebuild
                 self.codec.validate_d(gvecs.shape[-1])
-            mark("losses+gradients done: local loss sum {l}", l=jnp.sum(losses))
             new_momentum, new_momentum_steps = None, None
-            if self.worker_momentum is not None:
-                # Honest workers send momenta (computed BEFORE the attack:
-                # attackers forge what they transmit, not what honest peers
-                # remember).  Bias-corrected like Adam so early steps are not
-                # (1-beta)-scaled relative to plain gradients; the correction
-                # counts momentum updates, NOT the global step — the buffer
-                # re-zeroes on restore and its warmup must restart with it.
-                beta = self.worker_momentum
-                new_momentum = beta * state.momentum + (1.0 - beta) * gvecs
-                new_momentum_steps = state.momentum_steps + 1
-                gvecs = new_momentum / (1.0 - beta ** new_momentum_steps.astype(jnp.float32))
-            gvecs, new_carry, secure_info, new_ef = self._perturb_local(
-                gvecs, key, carry=state.carry, ridx=ridx,
-                ef=state.ef if self.carries_ef else None,
-            )
+            with phase("perturb"):
+                if self.worker_momentum is not None:
+                    # Honest workers send momenta (computed BEFORE the attack:
+                    # attackers forge what they transmit, not what honest peers
+                    # remember).  Bias-corrected like Adam so early steps are not
+                    # (1-beta)-scaled relative to plain gradients; the correction
+                    # counts momentum updates, NOT the global step — the buffer
+                    # re-zeroes on restore and its warmup must restart with it.
+                    beta = self.worker_momentum
+                    new_momentum = beta * state.momentum + (1.0 - beta) * gvecs
+                    new_momentum_steps = state.momentum_steps + 1
+                    gvecs = new_momentum / (1.0 - beta ** new_momentum_steps.astype(jnp.float32))
+                gvecs, new_carry, secure_info, new_ef = self._perturb_local(
+                    gvecs, key, carry=state.carry, ridx=ridx,
+                    ef=state.ef if self.carries_ef else None,
+                )
             d = gvecs.shape[-1]
             if self.granularity == "leaf":
-                agg, participation, wdist, rep_dist = self._aggregate_per_leaf(
-                    gvecs, flatmap, key, state.reputation, ridx=ridx
-                )
-            else:
-                block = self._reshard_to_blocks(gvecs, d)
-                if self.exchange_dtype is not None:
-                    block = block.astype(jnp.float32)  # GAR math always in f32
-                agg_block, participation, seen_block, raw_block = self._aggregate_block(
-                    block, key, reputation=state.reputation, ridx=ridx
-                )
-                if self.exchange_dtype is not None:
-                    agg_block = agg_block.astype(self.exchange_dtype)  # wire, leg 2
-                if W > 1:
-                    agg = jax.lax.all_gather(agg_block, worker_axis, axis=0).reshape(-1)[:d]
-                else:
-                    agg = agg_block[:d]
-                agg = agg.astype(jnp.float32)
-                wdist = rep_dist = None
-                if self.worker_metrics:
-                    # distances over what the aggregator actually saw
-                    # (post-attack, post-lossy, post-quarantine)
-                    diff = seen_block - agg_block[None, :]
-                    wdist = jnp.sum(diff * diff, axis=1)
-                    if W > 1:
-                        wdist = jax.lax.psum(wdist, worker_axis)
-                if self.reputation_decay is not None:
-                    rdiff = raw_block - agg_block.astype(jnp.float32)[None, :]
-                    rep_dist = jnp.sum(rdiff * rdiff, axis=1)
-                    if W > 1:
-                        rep_dist = jax.lax.psum(rep_dist, worker_axis)
-            mark("aggregate done: |agg| {g}", g=jnp.linalg.norm(agg))
-            agg_tree = flatmap.inflate(agg)
-            updates, opt_state = tx.update(agg_tree, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-            mark("apply done: |p0| {p}",
-                 p=jnp.linalg.norm(jax.tree_util.tree_leaves(params)[0]))
-            total_loss = jax.lax.psum(jnp.sum(losses), worker_axis) if W > 1 else jnp.sum(losses)
-            worker_nan = None
-            if self.health_probe:
-                # Per-worker NaN-row flags measure the POST-TRANSPORT
-                # submissions (what the aggregation actually received:
-                # lossy NaN infill, dropped stragglers, inf attacks) —
-                # distinct from loss_finite, which measures model health.
-                local_bad = jnp.any(~jnp.isfinite(gvecs), axis=1)  # (k,)
-                if W > 1:
-                    worker_nan = jax.lax.all_gather(local_bad, worker_axis).reshape(
-                        self.nb_workers
+                with phase("gar"):
+                    agg, participation, wdist, rep_dist = self._aggregate_per_leaf(
+                        gvecs, flatmap, key, state.reputation, ridx=ridx
                     )
-                else:
-                    worker_nan = local_bad
-            secure_metrics = None
-            if secure_info is not None:
-                # Submission authentication material for the host-side
-                # sign/verify (secure/submit.py): per-worker digests of what
-                # was submitted vs received, plus the forge/reject verdicts.
-                # Gathered worker-major like the probe's NaN flags.
-                def gather_workers(local):
+            else:
+                with phase("reshard"):
+                    block = self._reshard_to_blocks(gvecs, d)
+                    if self.exchange_dtype is not None:
+                        block = block.astype(jnp.float32)  # GAR math always in f32
+                with phase("gar"):
+                    agg_block, participation, seen_block, raw_block = self._aggregate_block(
+                        block, key, reputation=state.reputation, ridx=ridx
+                    )
+                with phase("gather"):
+                    if self.exchange_dtype is not None:
+                        agg_block = agg_block.astype(self.exchange_dtype)  # wire, leg 2
                     if W > 1:
-                        gathered = jax.lax.all_gather(local, worker_axis)
-                        return gathered.reshape((self.nb_workers,) + local.shape[1:])
-                    return local
+                        agg = jax.lax.all_gather(agg_block, worker_axis, axis=0).reshape(-1)[:d]
+                    else:
+                        agg = agg_block[:d]
+                    agg = agg.astype(jnp.float32)
+                wdist = rep_dist = None
+                with phase("epilogue"):
+                    if self.worker_metrics:
+                        # distances over what the aggregator actually saw
+                        # (post-attack, post-lossy, post-quarantine)
+                        diff = seen_block - agg_block[None, :]
+                        wdist = jnp.sum(diff * diff, axis=1)
+                        if W > 1:
+                            wdist = jax.lax.psum(wdist, worker_axis)
+                    if self.reputation_decay is not None:
+                        rdiff = raw_block - agg_block.astype(jnp.float32)[None, :]
+                        rep_dist = jnp.sum(rdiff * rdiff, axis=1)
+                        if W > 1:
+                            rep_dist = jax.lax.psum(rep_dist, worker_axis)
+            with phase("apply"):
+                agg_tree = flatmap.inflate(agg)
+                updates, opt_state = tx.update(agg_tree, state.opt_state, state.params)
+                params = optax.apply_updates(state.params, updates)
+            with phase("epilogue"):
+                total_loss = jax.lax.psum(jnp.sum(losses), worker_axis) if W > 1 else jnp.sum(losses)
+                worker_nan = None
+                if self.health_probe:
+                    # Per-worker NaN-row flags measure the POST-TRANSPORT
+                    # submissions (what the aggregation actually received:
+                    # lossy NaN infill, dropped stragglers, inf attacks) —
+                    # distinct from loss_finite, which measures model health.
+                    local_bad = jnp.any(~jnp.isfinite(gvecs), axis=1)  # (k,)
+                    if W > 1:
+                        worker_nan = jax.lax.all_gather(local_bad, worker_axis).reshape(
+                            self.nb_workers
+                        )
+                    else:
+                        worker_nan = local_bad
+                secure_metrics = None
+                if secure_info is not None:
+                    # Submission authentication material for the host-side
+                    # sign/verify (secure/submit.py): per-worker digests of what
+                    # was submitted vs received, plus the forge/reject verdicts.
+                    # Gathered worker-major like the probe's NaN flags.
+                    def gather_workers(local):
+                        if W > 1:
+                            gathered = jax.lax.all_gather(local, worker_axis)
+                            return gathered.reshape((self.nb_workers,) + local.shape[1:])
+                        return local
 
-                secure_metrics = {
-                    name: gather_workers(value)
-                    for name, value in secure_info.items()
-                }
-            return self._finalize_step(
-                state, params=params, opt_state=opt_state, new_carry=new_carry,
-                new_momentum=new_momentum, new_momentum_steps=new_momentum_steps,
-                total_loss=total_loss, update_norm=jnp.linalg.norm(agg),
-                worker_nan=worker_nan, rep_dist=rep_dist, wdist=wdist,
-                participation=participation, secure_metrics=secure_metrics,
-                ridx=ridx, new_ef=new_ef,
-            )
+                    secure_metrics = {
+                        name: gather_workers(value)
+                        for name, value in secure_info.items()
+                    }
+                return self._finalize_step(
+                    state, params=params, opt_state=opt_state, new_carry=new_carry,
+                    new_momentum=new_momentum, new_momentum_steps=new_momentum_steps,
+                    total_loss=total_loss, update_norm=jnp.linalg.norm(agg),
+                    worker_nan=worker_nan, rep_dist=rep_dist, wdist=wdist,
+                    participation=participation, secure_metrics=secure_metrics,
+                    ridx=ridx, new_ef=new_ef,
+                )
 
         return body
 
@@ -1126,7 +1148,7 @@ class RobustEngine:
         # (``_cache_size``) falls through to the jit.
         return trace.traced(
             "train_step.dispatch",
-            jax.jit(sharded, donate_argnums=(0,),
+            jax.jit(_revised(sharded), donate_argnums=(0,),
                     out_shardings=self._flat_out_shardings()),
             cat="train",
         )
@@ -1172,7 +1194,7 @@ class RobustEngine:
         )
         return trace.traced(
             "train_multi_step.dispatch",
-            jax.jit(sharded, donate_argnums=(0,),
+            jax.jit(_revised(sharded), donate_argnums=(0,),
                     out_shardings=self._flat_out_shardings()),
             cat="train",
         )
@@ -1223,7 +1245,8 @@ class RobustEngine:
                     idx = jax.random.randint(wkey, (batch_size,), 0, nb_examples)
                     return jax.tree_util.tree_map(lambda a: a[idx], data)
 
-                batch = jax.vmap(draw)(jnp.arange(k))
+                with phase("sample"):
+                    batch = jax.vmap(draw)(jnp.arange(k))
                 return step_body(s, batch)
 
             return jax.lax.scan(sampled_body, state, None, length=nb_steps)
@@ -1237,7 +1260,7 @@ class RobustEngine:
         )
         return trace.traced(
             "train_sampled_multi_step.dispatch",
-            jax.jit(sharded, donate_argnums=(0,),
+            jax.jit(_revised(sharded), donate_argnums=(0,),
                     out_shardings=self._flat_out_shardings()),
             cat="train",
         )
@@ -1743,276 +1766,284 @@ class RobustEngine:
                         )
                         for j in range(k)
                     ]
-            if k == 1:
-                # one logical worker per submesh: the historical (and
-                # bit-proven) unvmapped path — keep it byte-for-byte
-                local = jax.tree.map(lambda x: x[0], batch)  # strip block dim
-                loss, grads = jax.value_and_grad(loss_fn)(state.params, local)
-                losses = loss[None]
-                grads = jax.tree.map(lambda g: g[None], grads)
-            else:
-                # k logical workers per submesh (the large-n regime): vmap
-                # the per-worker loss/grad — every leaf leads with k
-                losses, grads = jax.vmap(
-                    lambda b: jax.value_and_grad(loss_fn)(state.params, b)
-                )(batch)
+            with phase("grad"):
+                if k == 1:
+                    # one logical worker per submesh: the historical (and
+                    # bit-proven) unvmapped path — keep it byte-for-byte
+                    local = jax.tree.map(lambda x: x[0], batch)  # strip block dim
+                    loss, grads = jax.value_and_grad(loss_fn)(state.params, local)
+                    losses = loss[None]
+                    grads = jax.tree.map(lambda g: g[None], grads)
+                else:
+                    # k logical workers per submesh (the large-n regime): vmap
+                    # the per-worker loss/grad — every leaf leads with k
+                    losses, grads = jax.vmap(
+                        lambda b: jax.value_and_grad(loss_fn)(state.params, b)
+                    )(batch)
 
-            g_leaves, treedef = jax.tree_util.tree_flatten(grads)
-            s_leaves = treedef.flatten_up_to(param_specs)
+                g_leaves, treedef = jax.tree_util.tree_flatten(grads)
+                s_leaves = treedef.flatten_up_to(param_specs)
 
-            # (2) complete replicated-leaf grads within the worker group
-            g_leaves = [
-                jax.lax.psum(g, _replication_axes(s)) if _replication_axes(s) else g
-                for g, s in zip(g_leaves, s_leaves)
-            ]
-            # (2a) l1/l2 regularization, analytically on the completed grads
-            # (see __init__): part of every worker's HONEST gradient, so it
-            # lands before momentum and before the Byzantine perturbation —
-            # the flat dataflow's in-loss placement, same math.
-            l1, l2 = self.l1_regularize, self.l2_regularize
-            if l1 or l2:
-                p_leaves = jax.tree_util.tree_leaves(state.params)
-                reg = jnp.float32(0.0)
-                for i, (p, s) in enumerate(zip(p_leaves, s_leaves)):
-                    p32 = p.astype(jnp.float32)
-                    delta = jnp.zeros_like(p32)
-                    if l1:
-                        delta = delta + l1 * jnp.sign(p32)
-                        reg = reg + l1 * jnp.sum(jnp.abs(p32)) * self._replication_scale(s)
-                    if l2:
-                        delta = delta + 2.0 * l2 * p32
-                        reg = reg + l2 * jnp.sum(p32 * p32) * self._replication_scale(s)
-                    g_leaves[i] = g_leaves[i] + delta.astype(g_leaves[i].dtype)
-                # scaled per-leaf partials psum exactly like the data loss:
-                # the in-group psum in `metrics` then counts the norm once
-                # (every logical worker's loss carries the reg term, the flat
-                # dataflow's per-worker in-loss placement)
-                losses = losses + reg
-            # (2b) honest worker momentum (pre-attack, like the flat body):
-            # send bias-corrected momenta, carry the uncorrected buffer
-            new_momentum, new_momentum_steps = state.momentum, state.momentum_steps
-            if self.worker_momentum is not None:
-                beta = self.worker_momentum
-                # momentum buffers are worker-sharded: local block (k, ...)
-                m_leaves, _ = jax.tree_util.tree_flatten(state.momentum)
-                new_momentum_steps = state.momentum_steps + 1
-                corr = 1.0 - beta ** new_momentum_steps.astype(jnp.float32)
-                m_new = [beta * m + (1.0 - beta) * g for m, g in zip(m_leaves, g_leaves)]
-                g_leaves = [m / corr for m in m_new]
-                new_momentum = jax.tree_util.tree_unflatten(treedef, m_new)
-            # (3) per-worker perturbation of each logical worker's own shards
-            # (skipped entirely when no adversity is configured — at k
-            # workers per submesh the k-fold loop would otherwise pay trace
-            # size for an identity transform)
-            carry_leaves = None
-            if self.carries_gradients:
-                carry_leaves = jax.tree_util.tree_leaves(state.carry)  # (k, ...)
-            new_carry = state.carry
-            if (self.attack is not None or self.lossy_link is not None
-                    or self.chaos is not None):
-                post_leaves = []
-                for i, (g, s) in enumerate(zip(g_leaves, s_leaves)):
-                    outs, posts = [], []
-                    for j in range(k):
-                        widx = gidx * k + j
-                        out, post = self._perturb(
-                            g[j], s,
-                            jax.random.fold_in(jax.random.fold_in(key, widx), i),
-                            widx,
-                            previous=(
-                                carry_leaves[i][j]
-                                if carry_leaves is not None else None
-                            ),
-                            ridx=ridx, late=lates[j],
-                        )
-                        outs.append(out)
-                        posts.append(post)
-                    g_leaves[i] = jnp.stack(outs)
-                    post_leaves.append(jnp.stack(posts))
+                # (2) complete replicated-leaf grads within the worker group
+                g_leaves = [
+                    jax.lax.psum(g, _replication_axes(s)) if _replication_axes(s) else g
+                    for g, s in zip(g_leaves, s_leaves)
+                ]
+                # (2a) l1/l2 regularization, analytically on the completed grads
+                # (see __init__): part of every worker's HONEST gradient, so it
+                # lands before momentum and before the Byzantine perturbation —
+                # the flat dataflow's in-loss placement, same math.
+                l1, l2 = self.l1_regularize, self.l2_regularize
+                if l1 or l2:
+                    p_leaves = jax.tree_util.tree_leaves(state.params)
+                    reg = jnp.float32(0.0)
+                    for i, (p, s) in enumerate(zip(p_leaves, s_leaves)):
+                        p32 = p.astype(jnp.float32)
+                        delta = jnp.zeros_like(p32)
+                        if l1:
+                            delta = delta + l1 * jnp.sign(p32)
+                            reg = reg + l1 * jnp.sum(jnp.abs(p32)) * self._replication_scale(s)
+                        if l2:
+                            delta = delta + 2.0 * l2 * p32
+                            reg = reg + l2 * jnp.sum(p32 * p32) * self._replication_scale(s)
+                        g_leaves[i] = g_leaves[i] + delta.astype(g_leaves[i].dtype)
+                    # scaled per-leaf partials psum exactly like the data loss:
+                    # the in-group psum in `metrics` then counts the norm once
+                    # (every logical worker's loss carries the reg term, the flat
+                    # dataflow's per-worker in-loss placement)
+                    losses = losses + reg
+            with phase("perturb"):
+                # (2b) honest worker momentum (pre-attack, like the flat body):
+                # send bias-corrected momenta, carry the uncorrected buffer
+                new_momentum, new_momentum_steps = state.momentum, state.momentum_steps
+                if self.worker_momentum is not None:
+                    beta = self.worker_momentum
+                    # momentum buffers are worker-sharded: local block (k, ...)
+                    m_leaves, _ = jax.tree_util.tree_flatten(state.momentum)
+                    new_momentum_steps = state.momentum_steps + 1
+                    corr = 1.0 - beta ** new_momentum_steps.astype(jnp.float32)
+                    m_new = [beta * m + (1.0 - beta) * g for m, g in zip(m_leaves, g_leaves)]
+                    g_leaves = [m / corr for m in m_new]
+                    new_momentum = jax.tree_util.tree_unflatten(treedef, m_new)
+                # (3) per-worker perturbation of each logical worker's own shards
+                # (skipped entirely when no adversity is configured — at k
+                # workers per submesh the k-fold loop would otherwise pay trace
+                # size for an identity transform)
+                carry_leaves = None
                 if self.carries_gradients:
-                    new_carry = jax.tree_util.tree_unflatten(treedef, post_leaves)
+                    carry_leaves = jax.tree_util.tree_leaves(state.carry)  # (k, ...)
+                new_carry = state.carry
+                if (self.attack is not None or self.lossy_link is not None
+                        or self.chaos is not None):
+                    post_leaves = []
+                    for i, (g, s) in enumerate(zip(g_leaves, s_leaves)):
+                        outs, posts = [], []
+                        for j in range(k):
+                            widx = gidx * k + j
+                            out, post = self._perturb(
+                                g[j], s,
+                                jax.random.fold_in(jax.random.fold_in(key, widx), i),
+                                widx,
+                                previous=(
+                                    carry_leaves[i][j]
+                                    if carry_leaves is not None else None
+                                ),
+                                ridx=ridx, late=lates[j],
+                            )
+                            outs.append(out)
+                            posts.append(post)
+                        g_leaves[i] = jnp.stack(outs)
+                        post_leaves.append(jnp.stack(posts))
+                    if self.carries_gradients:
+                        new_carry = jax.tree_util.tree_unflatten(treedef, post_leaves)
 
-            # (3b) submission forgery + authentication digests (secure/):
-            # impersonated/tampered submissions, sender/receiver checksums
-            # over every leaf shard, reject-to-NaN under ``secure``
-            g_leaves, secure_local = self._submission_pipeline(
-                g_leaves, key, gidx, ridx
-            )
+                # (3b) submission forgery + authentication digests (secure/):
+                # impersonated/tampered submissions, sender/receiver checksums
+                # over every leaf shard, reject-to-NaN under ``secure``
+                g_leaves, secure_local = self._submission_pipeline(
+                    g_leaves, key, gidx, ridx
+                )
 
             # (4/5) per-bucket robust aggregation over the worker axis
             all_rows = []
             for i, (g, s) in enumerate(zip(g_leaves, s_leaves)):
-                rows = self._gather_rows(self._leaf_buckets(g, s))
-                rows = self._apply_omniscient(rows, jax.random.fold_in(key, 10_000 + i), ridx=ridx)
+                with phase("reshard"):
+                    rows = self._gather_rows(self._leaf_buckets(g, s))
+                with phase("gar"):
+                    rows = self._apply_omniscient(
+                        rows, jax.random.fold_in(key, 10_000 + i), ridx=ridx)
                 all_rows.append(rows)
 
-            # Quarantine BEFORE any distance computation (incl. the global
-            # path below): masked rows must read +inf-distant to selection
-            # rules, never finite-distant-but-NaN-valued.  raw rows are kept
-            # for the reputation signal.
-            raw_all_rows = all_rows
-            if self.quarantine_threshold:
-                qmask = quarantine_mask(
-                    state.reputation, self.quarantine_threshold, gar.nb_byz_workers
-                )
-                all_rows = [
-                    jnp.where(qmask[None, :, None], jnp.nan, rows) for rows in all_rows
-                ]
-
-            global_dist2 = None
-            if self.granularity == "global" and gar.needs_distances:
-                acc = jnp.zeros((self.nb_workers, self.nb_workers), jnp.float32)
-                for rows, s in zip(all_rows, s_leaves):
-                    partial = centered_gram_sq_distances(
-                        rows.reshape(self.nb_workers, -1).astype(jnp.float32)
+            with phase("gar"):
+                # Quarantine BEFORE any distance computation (incl. the global
+                # path below): masked rows must read +inf-distant to selection
+                # rules, never finite-distant-but-NaN-valued.  raw rows are kept
+                # for the reputation signal.
+                raw_all_rows = all_rows
+                if self.quarantine_threshold:
+                    qmask = quarantine_mask(
+                        state.reputation, self.quarantine_threshold, gar.nb_byz_workers
                     )
-                    acc = acc + partial * self._replication_scale(s)
-                global_dist2 = jnp.maximum(jax.lax.psum(acc, _IN_GROUP_AXES), 0.0)
+                    all_rows = [
+                        jnp.where(qmask[None, :, None], jnp.nan, rows) for rows in all_rows
+                    ]
 
-            agg_leaves = []
-            # Suspicion accumulators (worker_metrics): whole-model per-worker
-            # squared distance to the aggregate — per-leaf partials scaled by
-            # the replication factor exactly like grad_norm's, psum-completed
-            # below — and the mean per-bucket participation.  Participation
-            # values are identical on every in-group device EXCEPT along the
-            # pipe axis of stage-stacked leaves (distinct buckets), so each
-            # contribution is scaled by 1/(replicating axes' size) and the
-            # in-group psum then counts every distinct bucket exactly once.
-            wdist = jnp.zeros((self.nb_workers,), jnp.float32)
-            part_sum = jnp.zeros((self.nb_workers,), jnp.float32)
-            part_count = 0.0  # global distinct-bucket count (static)
-            rep_dist = jnp.zeros((self.nb_workers,), jnp.float32)
-            # (vmapped rule calls below: the Pallas auto-tier detects the
-            # batching trace centrally and stays on jnp — gars/common.py
-            # _is_batched_tracer)
-            for rows, raw_rows, g, s in zip(all_rows, raw_all_rows, g_leaves, s_leaves):
-                participation = None
-                if gar.needs_distances:
-                    if global_dist2 is not None:
-                        dist2 = jnp.broadcast_to(global_dist2, rows.shape[:1] + global_dist2.shape)
-                    else:
-                        dist2 = self._bucket_distances(rows, s)
-                    if self.worker_metrics:
-                        # One pass: the memoized selection graph serves both
-                        # the aggregate and the participation (two separate
-                        # vmaps would trace it twice per leaf).
-                        agg, participation = jax.vmap(
-                            gar.aggregate_block_and_participation
-                        )(rows, dist2)
-                    else:
-                        agg = jax.vmap(gar.aggregate_block)(rows, dist2)
-                elif gar.uses_axis or gar.uses_key:
-                    # Iterative rules' row norms complete over the model axis
-                    # when this leaf's dimensions are sharded across it —
-                    # exactly _bucket_distances' discipline — so every shard
-                    # derives identical weights and the result matches dense.
-                    # Randomized meta-rules get the replicated step key (one
-                    # permutation per step, same on every device and leaf).
-                    axis = model_axis if model_axis in _spec_axis_names(s) else None
-                    from ..gars import GAR_KEY_TAG
+                global_dist2 = None
+                if self.granularity == "global" and gar.needs_distances:
+                    acc = jnp.zeros((self.nb_workers, self.nb_workers), jnp.float32)
+                    for rows, s in zip(all_rows, s_leaves):
+                        partial = centered_gram_sq_distances(
+                            rows.reshape(self.nb_workers, -1).astype(jnp.float32)
+                        )
+                        acc = acc + partial * self._replication_scale(s)
+                    global_dist2 = jnp.maximum(jax.lax.psum(acc, _IN_GROUP_AXES), 0.0)
 
-                    gkey = jax.random.fold_in(key, GAR_KEY_TAG)
+                agg_leaves = []
+                # Suspicion accumulators (worker_metrics): whole-model per-worker
+                # squared distance to the aggregate — per-leaf partials scaled by
+                # the replication factor exactly like grad_norm's, psum-completed
+                # below — and the mean per-bucket participation.  Participation
+                # values are identical on every in-group device EXCEPT along the
+                # pipe axis of stage-stacked leaves (distinct buckets), so each
+                # contribution is scaled by 1/(replicating axes' size) and the
+                # in-group psum then counts every distinct bucket exactly once.
+                wdist = jnp.zeros((self.nb_workers,), jnp.float32)
+                part_sum = jnp.zeros((self.nb_workers,), jnp.float32)
+                part_count = 0.0  # global distinct-bucket count (static)
+                rep_dist = jnp.zeros((self.nb_workers,), jnp.float32)
+                # (vmapped rule calls below: the Pallas auto-tier detects the
+                # batching trace centrally and stays on jnp — gars/common.py
+                # _is_batched_tracer)
+                for rows, raw_rows, g, s in zip(all_rows, raw_all_rows, g_leaves, s_leaves):
+                    participation = None
+                    if gar.needs_distances:
+                        if global_dist2 is not None:
+                            dist2 = jnp.broadcast_to(global_dist2, rows.shape[:1] + global_dist2.shape)
+                        else:
+                            dist2 = self._bucket_distances(rows, s)
+                        if self.worker_metrics:
+                            # One pass: the memoized selection graph serves both
+                            # the aggregate and the participation (two separate
+                            # vmaps would trace it twice per leaf).
+                            agg, participation = jax.vmap(
+                                gar.aggregate_block_and_participation
+                            )(rows, dist2)
+                        else:
+                            agg = jax.vmap(gar.aggregate_block)(rows, dist2)
+                    elif gar.uses_axis or gar.uses_key:
+                        # Iterative rules' row norms complete over the model axis
+                        # when this leaf's dimensions are sharded across it —
+                        # exactly _bucket_distances' discipline — so every shard
+                        # derives identical weights and the result matches dense.
+                        # Randomized meta-rules get the replicated step key (one
+                        # permutation per step, same on every device and leaf).
+                        axis = model_axis if model_axis in _spec_axis_names(s) else None
+                        from ..gars import GAR_KEY_TAG
+
+                        gkey = jax.random.fold_in(key, GAR_KEY_TAG)
+                        if self.worker_metrics:
+                            agg, participation = jax.vmap(
+                                lambda r, axis=axis: gar.aggregate_block_and_participation(
+                                    r, None, axis_name=axis, key=gkey
+                                )
+                            )(rows)
+                        else:
+                            agg = jax.vmap(
+                                lambda r, axis=axis: gar._call_aggregate(
+                                    r, None, axis_name=axis, key=gkey)
+                            )(rows)
+                    else:
+                        agg = jax.vmap(lambda r: gar.aggregate_block(r, None))(rows)
+                    if self.reputation_decay is not None:
+                        rdiff = raw_rows.astype(jnp.float32) - agg.astype(jnp.float32)[:, None, :]
+                        rep_dist = rep_dist + jnp.sum(rdiff * rdiff, axis=(0, 2)) * self._replication_scale(s)
                     if self.worker_metrics:
-                        agg, participation = jax.vmap(
-                            lambda r, axis=axis: gar.aggregate_block_and_participation(
-                                r, None, axis_name=axis, key=gkey
+                        diff = rows.astype(jnp.float32) - agg.astype(jnp.float32)[:, None, :]
+                        wdist = wdist + jnp.sum(diff * diff, axis=(0, 2)) * self._replication_scale(s)
+                        if participation is not None:
+                            stacked = (
+                                self.granularity == "layer" and s is not None
+                                and len(s) >= 2 and s[0] == pipe_axis
                             )
-                        )(rows)
-                    else:
-                        agg = jax.vmap(
-                            lambda r, axis=axis: gar._call_aggregate(
-                                r, None, axis_name=axis, key=gkey)
-                        )(rows)
-                else:
-                    agg = jax.vmap(lambda r: gar.aggregate_block(r, None))(rows)
-                if self.reputation_decay is not None:
-                    rdiff = raw_rows.astype(jnp.float32) - agg.astype(jnp.float32)[:, None, :]
-                    rep_dist = rep_dist + jnp.sum(rdiff * rdiff, axis=(0, 2)) * self._replication_scale(s)
-                if self.worker_metrics:
-                    diff = rows.astype(jnp.float32) - agg.astype(jnp.float32)[:, None, :]
-                    wdist = wdist + jnp.sum(diff * diff, axis=(0, 2)) * self._replication_scale(s)
-                    if participation is not None:
-                        stacked = (
-                            self.granularity == "layer" and s is not None
-                            and len(s) >= 2 and s[0] == pipe_axis
+                            rep = (model_axis,) + (() if stacked else (pipe_axis,))
+                            pscale = 1.0
+                            for a in rep:
+                                pscale /= self.mesh.shape[a]
+                            part_sum = part_sum + jnp.sum(participation, axis=0) * pscale
+                            part_count += participation.shape[0] * (
+                                self.mesh.shape[pipe_axis] if stacked else 1
+                            )
+                    # one aggregate per PARAMETER: strip the local worker
+                    # stacking dim from the layout target
+                    agg_leaves.append(agg.reshape(g.shape[1:]).astype(g.dtype))
+                agg_tree = jax.tree_util.tree_unflatten(treedef, agg_leaves)
+
+            with phase("apply"):
+                # (6) local optax update — layouts already match the parameters
+                updates, opt_state = tx.update(agg_tree, state.opt_state, state.params)
+                params = optax.apply_updates(state.params, updates)
+
+            with phase("epilogue"):
+                sq = jnp.float32(0.0)
+                for agg, s in zip(agg_leaves, s_leaves):
+                    sq = sq + jnp.sum(jnp.square(agg.astype(jnp.float32))) * self._replication_scale(s)
+                grad_norm = jnp.sqrt(jax.lax.psum(sq, _IN_GROUP_AXES))
+
+                # loss is a local partial: sum the local workers, then the worker
+                # group's devices, then groups
+                total_loss = jax.lax.psum(jnp.sum(losses), _IN_GROUP_AXES + (worker_axis,))
+                worker_nan = None
+                if self.health_probe:
+                    # Per-worker NaN-row flags over the POST-TRANSPORT shards:
+                    # count this worker's non-finite coordinates locally,
+                    # complete over the worker group, flag, gather workers.
+                    bad = jnp.zeros((k,), jnp.int32)
+                    for g in g_leaves:
+                        bad = bad + jnp.sum(
+                            (~jnp.isfinite(g)).astype(jnp.int32),
+                            axis=tuple(range(1, g.ndim)),
                         )
-                        rep = (model_axis,) + (() if stacked else (pipe_axis,))
-                        pscale = 1.0
-                        for a in rep:
-                            pscale /= self.mesh.shape[a]
-                        part_sum = part_sum + jnp.sum(participation, axis=0) * pscale
-                        part_count += participation.shape[0] * (
-                            self.mesh.shape[pipe_axis] if stacked else 1
-                        )
-                # one aggregate per PARAMETER: strip the local worker
-                # stacking dim from the layout target
-                agg_leaves.append(agg.reshape(g.shape[1:]).astype(g.dtype))
-            agg_tree = jax.tree_util.tree_unflatten(treedef, agg_leaves)
-
-            # (6) local optax update — layouts already match the parameters
-            updates, opt_state = tx.update(agg_tree, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-
-            sq = jnp.float32(0.0)
-            for agg, s in zip(agg_leaves, s_leaves):
-                sq = sq + jnp.sum(jnp.square(agg.astype(jnp.float32))) * self._replication_scale(s)
-            grad_norm = jnp.sqrt(jax.lax.psum(sq, _IN_GROUP_AXES))
-
-            # loss is a local partial: sum the local workers, then the worker
-            # group's devices, then groups
-            total_loss = jax.lax.psum(jnp.sum(losses), _IN_GROUP_AXES + (worker_axis,))
-            worker_nan = None
-            if self.health_probe:
-                # Per-worker NaN-row flags over the POST-TRANSPORT shards:
-                # count this worker's non-finite coordinates locally,
-                # complete over the worker group, flag, gather workers.
-                bad = jnp.zeros((k,), jnp.int32)
-                for g in g_leaves:
-                    bad = bad + jnp.sum(
-                        (~jnp.isfinite(g)).astype(jnp.int32),
-                        axis=tuple(range(1, g.ndim)),
+                    bad = jax.lax.psum(bad, _IN_GROUP_AXES)
+                    worker_nan = jax.lax.all_gather(bad > 0, worker_axis).reshape(
+                        self.nb_workers
                     )
-                bad = jax.lax.psum(bad, _IN_GROUP_AXES)
-                worker_nan = jax.lax.all_gather(bad > 0, worker_axis).reshape(
-                    self.nb_workers
+                secure_metrics = None
+                if secure_local is not None:
+                    # complete each worker's lane sums over its in-group shards
+                    # (uint32 psum wraps mod 2^32 — the checksum's own domain),
+                    # then gather worker-major like the probe's NaN flags
+                    def complete(local, summed):
+                        value = (
+                            jax.lax.psum(local, _IN_GROUP_AXES) if summed else local
+                        )
+                        gathered = jax.lax.all_gather(value, worker_axis)
+                        return gathered.reshape((self.nb_workers,) + value.shape[1:])
+
+                    secure_metrics = {
+                        "digest_sent": complete(secure_local["digest_sent"], True),
+                        "digest_recv": complete(secure_local["digest_recv"], True),
+                        "forged": complete(secure_local["forged"], False),
+                        "rejected": complete(secure_local["rejected"], False),
+                    }
+                return self._finalize_step(
+                    state, params=params, opt_state=opt_state, new_carry=new_carry,
+                    new_momentum=new_momentum, new_momentum_steps=new_momentum_steps,
+                    total_loss=total_loss, update_norm=grad_norm,
+                    worker_nan=worker_nan,
+                    rep_dist=(
+                        jax.lax.psum(rep_dist, _IN_GROUP_AXES)
+                        if self.reputation_decay is not None else None
+                    ),
+                    wdist=(
+                        jax.lax.psum(wdist, _IN_GROUP_AXES)
+                        if self.worker_metrics else None
+                    ),
+                    participation=(
+                        jax.lax.psum(part_sum, _IN_GROUP_AXES) / part_count
+                        if part_count else None
+                    ),
+                    secure_metrics=secure_metrics, ridx=ridx,
                 )
-            secure_metrics = None
-            if secure_local is not None:
-                # complete each worker's lane sums over its in-group shards
-                # (uint32 psum wraps mod 2^32 — the checksum's own domain),
-                # then gather worker-major like the probe's NaN flags
-                def complete(local, summed):
-                    value = (
-                        jax.lax.psum(local, _IN_GROUP_AXES) if summed else local
-                    )
-                    gathered = jax.lax.all_gather(value, worker_axis)
-                    return gathered.reshape((self.nb_workers,) + value.shape[1:])
-
-                secure_metrics = {
-                    "digest_sent": complete(secure_local["digest_sent"], True),
-                    "digest_recv": complete(secure_local["digest_recv"], True),
-                    "forged": complete(secure_local["forged"], False),
-                    "rejected": complete(secure_local["rejected"], False),
-                }
-            return self._finalize_step(
-                state, params=params, opt_state=opt_state, new_carry=new_carry,
-                new_momentum=new_momentum, new_momentum_steps=new_momentum_steps,
-                total_loss=total_loss, update_norm=grad_norm,
-                worker_nan=worker_nan,
-                rep_dist=(
-                    jax.lax.psum(rep_dist, _IN_GROUP_AXES)
-                    if self.reputation_decay is not None else None
-                ),
-                wdist=(
-                    jax.lax.psum(wdist, _IN_GROUP_AXES)
-                    if self.worker_metrics else None
-                ),
-                participation=(
-                    jax.lax.psum(part_sum, _IN_GROUP_AXES) / part_count
-                    if part_count else None
-                ),
-                secure_metrics=secure_metrics, ridx=ridx,
-            )
 
         return body
 
@@ -2039,7 +2070,7 @@ class RobustEngine:
         )
         return trace.traced(
             "train_step.dispatch",
-            jax.jit(sharded, donate_argnums=(0,), out_shardings=out_shardings),
+            jax.jit(_revised(sharded), donate_argnums=(0,), out_shardings=out_shardings),
             cat="train",
         )
 
@@ -2077,7 +2108,7 @@ class RobustEngine:
         )
         return trace.traced(
             "train_multi_step.dispatch",
-            jax.jit(sharded, donate_argnums=(0,), out_shardings=out_shardings),
+            jax.jit(_revised(sharded), donate_argnums=(0,), out_shardings=out_shardings),
             cat="train",
         )
 
@@ -2320,41 +2351,45 @@ class RobustEngine:
             if self.batch_transform is not None:
                 # fold tag 3: the augmentation stream (same as the fused body)
                 wkey = jax.random.fold_in(jax.random.fold_in(key, widx), 3)
-                worker_batch = self.batch_transform(worker_batch, wkey)
-            loss, grads = jax.value_and_grad(loss_fn)(params, worker_batch)
+                with phase("augment"):
+                    worker_batch = self.batch_transform(worker_batch, wkey)
+            with phase("grad"):
+                loss, grads = jax.value_and_grad(loss_fn)(params, worker_batch)
             leaves = jax.tree_util.tree_leaves(grads)
-            row = jnp.concatenate(
-                [leaf.reshape(-1).astype(jnp.float32) for leaf in leaves]
-            )
-            out = {"loss": loss}
-            if beta is not None:
-                new_m = beta * momentum[widx] + (1.0 - beta) * row
-                out["momentum"] = new_m
-                correction = 1.0 - beta ** (
-                    jnp.asarray(momentum_steps, jnp.float32) + 1.0
+            with phase("flatten"):
+                row = jnp.concatenate(
+                    [leaf.reshape(-1).astype(jnp.float32) for leaf in leaves]
                 )
-                row = new_m / correction
-            if self.attack is not None and not self.attack.omniscient:
-                wkey = jax.random.fold_in(key, widx)
-                forged = self.attack.apply_local(row, jax.random.fold_in(wkey, 1))
-                row = jnp.where(widx < self.nb_real_byz, forged, row)
-            if self.codec is not None:
-                if ef is not None:
-                    payload, image, new_ef = self.codec.ef_encode(row, ef[widx])
-                    out["ef"] = new_ef
-                else:
-                    payload = self.codec.encode(row)
-                    image = self.codec.decode(payload, row.shape[-1])
+            out = {"loss": loss}
+            with phase("perturb"):
+                if beta is not None:
+                    new_m = beta * momentum[widx] + (1.0 - beta) * row
+                    out["momentum"] = new_m
+                    correction = 1.0 - beta ** (
+                        jnp.asarray(momentum_steps, jnp.float32) + 1.0
+                    )
+                    row = new_m / correction
+                if self.attack is not None and not self.attack.omniscient:
+                    wkey = jax.random.fold_in(key, widx)
+                    forged = self.attack.apply_local(row, jax.random.fold_in(wkey, 1))
+                    row = jnp.where(widx < self.nb_real_byz, forged, row)
+                if self.codec is not None:
+                    if ef is not None:
+                        payload, image, new_ef = self.codec.ef_encode(row, ef[widx])
+                        out["ef"] = new_ef
+                    else:
+                        payload = self.codec.encode(row)
+                        image = self.codec.decode(payload, row.shape[-1])
+                    if self.secure:
+                        out["digest"] = row_digest(image)
+                    out["row"] = payload
+                    return out
                 if self.secure:
-                    out["digest"] = row_digest(image)
-                out["row"] = payload
+                    out["digest"] = row_digest(row)
+                if self.exchange_dtype is not None:
+                    row = row.astype(self.exchange_dtype)
+                out["row"] = row
                 return out
-            if self.secure:
-                out["digest"] = row_digest(row)
-            if self.exchange_dtype is not None:
-                row = row.astype(self.exchange_dtype)
-            out["row"] = row
-            return out
 
         return body
 
@@ -2391,7 +2426,7 @@ class RobustEngine:
                         momentum_steps, ef)
 
         return trace.traced(
-            "worker_grad.dispatch", jax.jit(grad_fn), cat="train"
+            "worker_grad.dispatch", jax.jit(_revised(grad_fn)), cat="train"
         )
 
     def build_group_grad(self, loss_fn):
@@ -2434,7 +2469,7 @@ class RobustEngine:
                                   None, None)
 
         return trace.traced(
-            "group_grad.dispatch", jax.jit(group_fn), cat="train"
+            "group_grad.dispatch", jax.jit(_revised(group_fn)), cat="train"
         )
 
     def build_submesh_grad(self, loss_fn):
@@ -2479,7 +2514,7 @@ class RobustEngine:
             return jax.vmap(one)(jnp.arange(k), group_batch)
 
         jitted = jax.jit(
-            submesh_fn, out_shardings=NamedSharding(self.mesh, P())
+            _revised(submesh_fn), out_shardings=NamedSharding(self.mesh, P())
         )
         return trace.traced("submesh_grad.dispatch", jitted, cat="train")
 
@@ -2549,106 +2584,110 @@ class RobustEngine:
 
         def agg_fn(state, rows, losses, arrived, stale, extras):
             key = jax.random.fold_in(state.rng, state.step)
-            if rows_form == "wire" and self.codec is not None:
-                # decode at the aggregation boundary: every GAR sees f32
-                rows = self.codec.decode_rows(rows, d)
-            else:
-                rows = rows.astype(jnp.float32)
-            # deadline verdict first: a worker that neither arrived nor
-            # carries a live stale row IS a NaN row — the exact convention
-            # of a fully-lossy link, absorbed by the rule
-            valid = arrived | stale
-            rows = jnp.where(valid[:, None], rows, jnp.nan)
-            if rows_form == "wire" and self.codec is None:
-                # the dtype twin's wire image (no-op on the f32 wire; the
-                # codec/decoded forms already ARE the wire image)
-                rows = wire_roundtrip(rows, dtype=self.exchange_dtype)
-            reweight_coeff = None
-            if stale_reweight:
-                # v3 age reweighting: damp each stale carry row by
-                # c(a) = 1/(1+a) — traced, so steady state never
-                # recompiles as ages tick.  Applied AFTER decode and the
-                # wire image (the coefficient scales what the rule sees,
-                # not what crossed the wire) and BEFORE _prepare_rows
-                # (reputation/quarantine judge the damped row, exactly
-                # what enters the aggregate).
-                ages = extras["stale_age"].astype(jnp.float32)
-                reweight_coeff = jnp.where(stale, 1.0 / (1.0 + ages), 1.0)
-                rows = rows * reweight_coeff[:, None]
-            rows, raw_rows = self._prepare_rows(rows, key, state.reputation)
-            dist2 = None
-            if self.gar.needs_distances:
-                dist2 = jnp.maximum(pairwise_sq_distances(rows), 0.0)
-            gar_key = jax.random.fold_in(key, GAR_KEY_TAG)
-            participation = None
-            if self.worker_metrics:
-                agg, participation = self.gar.aggregate_block_and_participation(
-                    rows, dist2, axis_name=None, key=gar_key
+            with phase("reshard"):
+                if rows_form == "wire" and self.codec is not None:
+                    # decode at the aggregation boundary: every GAR sees f32
+                    rows = self.codec.decode_rows(rows, d)
+                else:
+                    rows = rows.astype(jnp.float32)
+                # deadline verdict first: a worker that neither arrived nor
+                # carries a live stale row IS a NaN row — the exact convention
+                # of a fully-lossy link, absorbed by the rule
+                valid = arrived | stale
+                rows = jnp.where(valid[:, None], rows, jnp.nan)
+                if rows_form == "wire" and self.codec is None:
+                    # the dtype twin's wire image (no-op on the f32 wire; the
+                    # codec/decoded forms already ARE the wire image)
+                    rows = wire_roundtrip(rows, dtype=self.exchange_dtype)
+                reweight_coeff = None
+                if stale_reweight:
+                    # v3 age reweighting: damp each stale carry row by
+                    # c(a) = 1/(1+a) — traced, so steady state never
+                    # recompiles as ages tick.  Applied AFTER decode and the
+                    # wire image (the coefficient scales what the rule sees,
+                    # not what crossed the wire) and BEFORE _prepare_rows
+                    # (reputation/quarantine judge the damped row, exactly
+                    # what enters the aggregate).
+                    ages = extras["stale_age"].astype(jnp.float32)
+                    reweight_coeff = jnp.where(stale, 1.0 / (1.0 + ages), 1.0)
+                    rows = rows * reweight_coeff[:, None]
+            with phase("gar"):
+                rows, raw_rows = self._prepare_rows(rows, key, state.reputation)
+                dist2 = None
+                if self.gar.needs_distances:
+                    dist2 = jnp.maximum(pairwise_sq_distances(rows), 0.0)
+                gar_key = jax.random.fold_in(key, GAR_KEY_TAG)
+                participation = None
+                if self.worker_metrics:
+                    agg, participation = self.gar.aggregate_block_and_participation(
+                        rows, dist2, axis_name=None, key=gar_key
+                    )
+                else:
+                    agg = self.gar._call_aggregate(
+                        rows, dist2, axis_name=None, key=gar_key
+                    )
+                agg = agg.astype(jnp.float32)
+            with phase("apply"):
+                agg_tree = flatmap.inflate(agg)
+                updates, opt_state = tx.update(agg_tree, state.opt_state, state.params)
+                params = optax.apply_updates(state.params, updates)
+            with phase("epilogue"):
+                # the aggregator can only sum the losses it RECEIVED; a late
+                # worker's loss never arrived (its row is the NaN infill)
+                total_loss = jnp.sum(jnp.where(arrived, losses, 0.0))
+                wdist = rep_dist = None
+                if self.worker_metrics:
+                    diff = rows - agg[None, :]
+                    wdist = jnp.sum(diff * diff, axis=1)
+                if self.reputation_decay is not None:
+                    rdiff = raw_rows - agg[None, :]
+                    rep_dist = jnp.sum(rdiff * rdiff, axis=1)
+                worker_nan = None
+                if self.health_probe:
+                    worker_nan = jnp.any(~jnp.isfinite(rows), axis=1)
+                new_momentum = new_momentum_steps = None
+                if self.worker_momentum is not None:
+                    # write back only the rows whose submission ARRIVED: a
+                    # timed-out worker's momentum update never completed (its
+                    # thread's result was discarded with the round).  Emitted
+                    # replicated, like every other plain-jit output here; the
+                    # host step re-places init_state's worker-sharded buffer
+                    # ONCE so round 0's input layout matches every later
+                    # round's (parallel/bounded.py — else both executables
+                    # would recompile at round 1)
+                    new_momentum = jnp.where(
+                        arrived[:, None], extras["momentum"], state.momentum
+                    )
+                    new_momentum_steps = state.momentum_steps + 1
+                new_ef = None
+                if self.carries_ef:
+                    # same convention as momentum: a timed-out worker's
+                    # error-feedback residual never updated (its submission —
+                    # and the quantization error it absorbed — never shipped)
+                    new_ef = jnp.where(arrived[:, None], extras["ef"], state.ef)
+                secure_metrics = None
+                if self.secure:
+                    # sent == received by construction on this path (no
+                    # in-transit transform between the submission executable
+                    # and the host's stack); the host authenticator still
+                    # signs and verifies one dispatch behind, and a digest
+                    # mismatch there would name a real corruption
+                    nobody = jnp.zeros((self.nb_workers,), bool)
+                    secure_metrics = {
+                        "digest_sent": extras["digests"],
+                        "digest_recv": extras["digests"],
+                        "forged": nobody,
+                        "rejected": nobody,
+                    }
+                new_state, metrics = self._finalize_step(
+                    state, params=params, opt_state=opt_state, new_carry=None,
+                    new_momentum=new_momentum,
+                    new_momentum_steps=new_momentum_steps,
+                    total_loss=total_loss, update_norm=jnp.linalg.norm(agg),
+                    worker_nan=worker_nan, rep_dist=rep_dist, wdist=wdist,
+                    participation=participation, secure_metrics=secure_metrics,
+                    ridx=None, new_ef=new_ef,
                 )
-            else:
-                agg = self.gar._call_aggregate(
-                    rows, dist2, axis_name=None, key=gar_key
-                )
-            agg = agg.astype(jnp.float32)
-            agg_tree = flatmap.inflate(agg)
-            updates, opt_state = tx.update(agg_tree, state.opt_state, state.params)
-            params = optax.apply_updates(state.params, updates)
-            # the aggregator can only sum the losses it RECEIVED; a late
-            # worker's loss never arrived (its row is the NaN infill)
-            total_loss = jnp.sum(jnp.where(arrived, losses, 0.0))
-            wdist = rep_dist = None
-            if self.worker_metrics:
-                diff = rows - agg[None, :]
-                wdist = jnp.sum(diff * diff, axis=1)
-            if self.reputation_decay is not None:
-                rdiff = raw_rows - agg[None, :]
-                rep_dist = jnp.sum(rdiff * rdiff, axis=1)
-            worker_nan = None
-            if self.health_probe:
-                worker_nan = jnp.any(~jnp.isfinite(rows), axis=1)
-            new_momentum = new_momentum_steps = None
-            if self.worker_momentum is not None:
-                # write back only the rows whose submission ARRIVED: a
-                # timed-out worker's momentum update never completed (its
-                # thread's result was discarded with the round).  Emitted
-                # replicated, like every other plain-jit output here; the
-                # host step re-places init_state's worker-sharded buffer
-                # ONCE so round 0's input layout matches every later
-                # round's (parallel/bounded.py — else both executables
-                # would recompile at round 1)
-                new_momentum = jnp.where(
-                    arrived[:, None], extras["momentum"], state.momentum
-                )
-                new_momentum_steps = state.momentum_steps + 1
-            new_ef = None
-            if self.carries_ef:
-                # same convention as momentum: a timed-out worker's
-                # error-feedback residual never updated (its submission —
-                # and the quantization error it absorbed — never shipped)
-                new_ef = jnp.where(arrived[:, None], extras["ef"], state.ef)
-            secure_metrics = None
-            if self.secure:
-                # sent == received by construction on this path (no
-                # in-transit transform between the submission executable
-                # and the host's stack); the host authenticator still
-                # signs and verifies one dispatch behind, and a digest
-                # mismatch there would name a real corruption
-                nobody = jnp.zeros((self.nb_workers,), bool)
-                secure_metrics = {
-                    "digest_sent": extras["digests"],
-                    "digest_recv": extras["digests"],
-                    "forged": nobody,
-                    "rejected": nobody,
-                }
-            new_state, metrics = self._finalize_step(
-                state, params=params, opt_state=opt_state, new_carry=None,
-                new_momentum=new_momentum,
-                new_momentum_steps=new_momentum_steps,
-                total_loss=total_loss, update_norm=jnp.linalg.norm(agg),
-                worker_nan=worker_nan, rep_dist=rep_dist, wdist=wdist,
-                participation=participation, secure_metrics=secure_metrics,
-                ridx=None, new_ef=new_ef,
-            )
             # deadline evidence AFTER the epilogue: the flight recorder's
             # lane set predates the protocol; forensics/registry consume
             # these from the metrics dict on the host.  ``nb_timeouts`` is
@@ -2662,7 +2701,7 @@ class RobustEngine:
                 metrics["stale_reweight_coeff"] = reweight_coeff
             return new_state, metrics
 
-        jitted = jax.jit(agg_fn, donate_argnums=(0,))
+        jitted = jax.jit(_revised(agg_fn), donate_argnums=(0,))
         return trace.traced("bounded_aggregate.dispatch", jitted, cat="train")
 
     def build_incremental_fold(self, d):

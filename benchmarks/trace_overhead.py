@@ -10,7 +10,8 @@ recompile, identical cache):
 - ``uninstrumented``  call the raw jit (``step.inner``) — the pre-telemetry
   baseline;
 - ``disabled``        call through the span wrapper with NO tracer
-  installed — the one-``None``-check fast path every untraced run pays;
+  installed — the fast path every untraced run pays (one ``None`` check
+  and an inactive ``jax.profiler.TraceAnnotation``);
 - ``enabled``         call through the wrapper with a tracer installed and
   the runner's companion spans (``input``/``host_gap``) simulated per step
   — the fully traced run.

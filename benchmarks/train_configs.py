@@ -128,7 +128,7 @@ CONFIGS = {
         "args": ["--experiment", "cnnet", "--aggregator", "krum",
                  "--nb-workers", "8", "--nb-decl-byz-workers", "2",
                  "--experiment-args", "batch-size:128", "dtype:bfloat16", "augment:device",
-                 "--trace", "--trace-dir", "benchmarks/trace_r03"],
+                 "--xprof", "2:5", "--trace-dir", "benchmarks/trace_r03"],
     },
     "6u": {
         "name": "resnet50_cifar10_leaf_krum_n8_f2_unrolled",

@@ -569,9 +569,6 @@ def test_runner_flight_rejects_bad_flags():
     with pytest.raises(UserException):
         runner.main(BASE_ARGS + [
             "--max-step", "2", "--live-ready-file", "/tmp/r"])
-    with pytest.raises(UserException):
-        runner.main(BASE_ARGS + [
-            "--max-step", "2", "--xprof", "2:4", "--trace"])
 
 
 @pytest.mark.slow  # two full runner mains; the regress test keeps tier-1 coverage
