@@ -60,9 +60,17 @@ def _pad_axis(x, axis, multiple, value=0.0):
     return jnp.pad(x, widths, constant_values=value)
 
 
+#: Lane width of the (8, 128) tiles, and the widest column block a kernel
+#: takes: every kernel's block is a multiple of the first and at most the
+#: second, so rows whose width is a multiple of ``MAX_BLOCK`` (of ``LANE``
+#: when narrower) pass ``_pad_axis`` untouched at any power-of-two block.
+LANE = 128
+MAX_BLOCK = 1024
+
+
 def _clamp_block(blk, d):
-    blk = max(128, min(1024, (blk // 128) * 128))
-    return min(blk, max(128, ((d + 127) // 128) * 128))
+    blk = max(LANE, min(MAX_BLOCK, (blk // LANE) * LANE))
+    return min(blk, max(LANE, -(-d // LANE) * LANE))
 
 
 #: Worker-row tile of the distance kernels: above this many (padded) rows

@@ -11,9 +11,14 @@ update, SURVEY.md §3.1).  Dataflow per step, for ``n`` logical workers over a
 2.  **Local Byzantine attack / lossy link** — transforms that only read the
     worker's own slot run here, before any collective (honest threat model).
 3.  **Reshard worker->dimension** — ``all_to_all`` turns the implicit (n, d)
-    gradient matrix into per-device column blocks (n, d/W).  This is the
+    gradient matrix into per-device column blocks (n, blk).  This is the
     engine's key memory move: no device ever holds n gradients, per-device
-    footprint stays O(d) (SURVEY.md §7 hard part (b)).
+    footprint stays O(d) (SURVEY.md §7 hard part (b)).  ``blk`` is
+    ``_block_width(d)``: ``ceil(d / W)`` rounded up to the kernels' column
+    tile (1,024 columns; 128 under that), the rows zero-padded once to
+    ``W * blk``, so that every block starts on a lane boundary and the cut
+    is a bitcast and not a relayout; on one device ``blk`` is ``d`` and
+    nothing is padded or moved.
 4.  **Omniscient attacks** — coalition attacks needing honest statistics
     (coordinate-wise mean/std) apply blockwise on the gathered rows.
 5.  **Distances** — Krum/Bulyan need the (n, n) squared-distance matrix: each
@@ -43,6 +48,7 @@ from ..core.train_state import TrainState
 from ..gars.common import centered_gram_sq_distances
 from ..obs import trace
 from ..obs.profiler import PHASE_PREFIX
+from ..ops.pallas_kernels import LANE, MAX_BLOCK
 from ..utils import UserException
 from ..utils.hw import on_tpu
 from .mesh import model_axis, pipe_axis, worker_axis
@@ -627,20 +633,44 @@ class RobustEngine:
         new_ef = jnp.stack(ef_rows, axis=0) if ef_rows is not None else None
         return stacked, carry, secure_info, new_ef
 
+    def _block_width(self, d):
+        """Columns of one device's block of the (n, d) gradient matrix.
+
+        On one device the block is the rows themselves.  Over ``W`` devices
+        it is the smallest multiple of the kernels' column tile
+        (``ops/pallas_kernels``: ``MAX_BLOCK``, or ``LANE`` where the block
+        is narrower than that) holding ``ceil(d / W)`` columns: every block
+        then starts on a lane boundary of the rows' (8, 128) tiles, so the
+        cut moves whole tiles (a bitcast at k = 8, where a block cut off
+        the lane boundary is relaid element by element), and the kernels
+        find the width they would pad to."""
+        W = self.nb_devices
+        blk = -(-d // W)
+        if W > 1:
+            blk += (-blk) % (MAX_BLOCK if blk >= MAX_BLOCK else LANE)
+        return blk
+
     def _reshard_to_blocks(self, gvecs, d):
-        """(k, d) worker-sharded -> (n, d_block) dimension-sharded column block."""
-        W, k = self.nb_devices, self.workers_per_device
+        """(k, d) worker-sharded -> (n, d_block) dimension-sharded column
+        block: device w gets columns ``[w * blk, (w + 1) * blk)`` of every
+        row, ``blk = _block_width(d)``, the columns past ``d`` zero (they
+        add nothing to a distance and aggregate to zero under every rule;
+        the gather cuts them off)."""
+        W = self.nb_devices
         if self.exchange_dtype is not None:
             gvecs = gvecs.astype(self.exchange_dtype)
-        blk = -(-d // W)
-        padded = jnp.pad(gvecs, ((0, 0), (0, W * blk - d)))
-        pieces = padded.reshape(k, W, blk).transpose(1, 0, 2)  # (W, k, blk)
         if W == 1:
-            gathered = pieces
-        else:
-            gathered = jax.lax.all_to_all(pieces, worker_axis, split_axis=0, concat_axis=0, tiled=True)
-            gathered = gathered.reshape(W, k, blk)
-        return gathered.reshape(self.nb_workers, blk)
+            return gvecs
+        padded = jnp.pad(gvecs, ((0, 0), (0, W * self._block_width(d) - d)))
+        return jax.lax.all_to_all(padded, worker_axis, split_axis=1, concat_axis=0, tiled=True)
+
+    def _gather_blocks(self, agg_block, d):
+        """(d_block,) aggregated column block of every device -> the (d,)
+        vector: block w holds columns ``[w * blk, (w + 1) * blk)``, so the
+        blocks in device order are the padded row."""
+        if self.nb_devices == 1:
+            return agg_block[:d]
+        return jax.lax.all_gather(agg_block, worker_axis, axis=0).reshape(-1)[:d]
 
     def _prepare_rows(self, rows, attack_key, reputation, ridx=None):
         """The ORDER-SENSITIVE shared front of both aggregation paths:
@@ -1059,11 +1089,7 @@ class RobustEngine:
                 with phase("gather"):
                     if self.exchange_dtype is not None:
                         agg_block = agg_block.astype(self.exchange_dtype)  # wire, leg 2
-                    if W > 1:
-                        agg = jax.lax.all_gather(agg_block, worker_axis, axis=0).reshape(-1)[:d]
-                    else:
-                        agg = agg_block[:d]
-                    agg = agg.astype(jnp.float32)
+                    agg = self._gather_blocks(agg_block, d).astype(jnp.float32)
                 wdist = rep_dist = None
                 with phase("epilogue"):
                     if self.worker_metrics:
@@ -1282,7 +1308,7 @@ class RobustEngine:
         from ..gars import GAR_KEY_TAG
 
         W = self.nb_devices
-        blk = -(-int(d) // W)
+        blk = self._block_width(int(d))
         # Generate the synthetic rows ON DEVICE under jit with an explicit
         # output sharding: GSPMD shards the generation itself, so the host
         # never materializes the (n, d) matrix (n x the model footprint at

@@ -18,7 +18,7 @@ def flat_params(state):
 
 def make_setup(gar_name="average", n=8, f=0, nb_devices=1, attack=None,
                attack_args=(), nb_real_byz=0, lossy_spec=None, lr=0.05,
-               mode="flat"):
+               mode="flat", experiment="mnist"):
     """Delegates to the suite-wide cached engine-fixture factory
     (tests/conftest.py, ISSUE 10 satellite): identical configurations share
     one compiled step across tests; multi-device coverage lives in the
@@ -27,8 +27,8 @@ def make_setup(gar_name="average", n=8, f=0, nb_devices=1, attack=None,
     from conftest import build_engine_stack
 
     exp, engine, tx, step, make_state = build_engine_stack(
-        mode=mode, gar=gar_name, n=n, f=f, nb_devices=nb_devices, lr=lr,
-        attack=attack, attack_args=attack_args, nb_real_byz=nb_real_byz,
+        mode=mode, experiment=experiment, gar=gar_name, n=n, f=f, nb_devices=nb_devices,
+        lr=lr, attack=attack, attack_args=attack_args, nb_real_byz=nb_real_byz,
         lossy=lossy_spec)
     return exp, engine, step, make_state()
 
@@ -57,22 +57,30 @@ def test_training_decreases_loss(gar_name, f):
     assert losses[-1] < losses[0], "%s: loss %r -> %r" % (gar_name, losses[0], losses[-1])
 
 
-def test_device_count_invariance():
+# Neither model's d is a multiple of W x 128, so on W > 1 devices every run
+# below pads its rows to the aligned block width (engine._block_width): mnist
+# (d = 79,510) takes the 1,024-column tile at every W, digits (d = 7,510) the
+# 128-column one on 8 devices (939 columns a block) and 1,024 on 4 and 2.
+@pytest.mark.parametrize("experiment", ["mnist", "digits"])
+def test_device_count_invariance(experiment):
     """n=8 workers on 8 devices must produce the same updates as on 1 device
     (the sharded all_to_all/psum path vs the degenerate local path)."""
     results = []
     for nb_devices in (8, 1):
-        exp, engine, step, state = make_setup("krum", n=8, f=1, nb_devices=nb_devices)
+        exp, engine, step, state = make_setup("krum", n=8, f=1, nb_devices=nb_devices,
+                                              experiment=experiment)
         state, _ = run_steps(exp, engine, step, state, 3)
         results.append(flat_params(state))
     np.testing.assert_allclose(results[0], results[1], rtol=1e-5, atol=1e-6)
 
 
-def test_intermediate_device_count_invariance():
+@pytest.mark.parametrize("experiment", ["mnist", "digits"])
+def test_intermediate_device_count_invariance(experiment):
     """n=8 over 4 devices (2 workers/device) matches the fully sharded run."""
     results = []
     for nb_devices in (8, 4, 2):
-        exp, engine, step, state = make_setup("bulyan", n=8, f=1, nb_devices=nb_devices)
+        exp, engine, step, state = make_setup("bulyan", n=8, f=1, nb_devices=nb_devices,
+                                              experiment=experiment)
         state, _ = run_steps(exp, engine, step, state, 2)
         results.append(flat_params(state))
     np.testing.assert_allclose(results[0], results[1], rtol=1e-5, atol=1e-6)

@@ -1,0 +1,146 @@
+"""The worker -> dimension reshard (parallel/engine.py ``_block_width``,
+``_reshard_to_blocks``, ``_gather_blocks``): over W > 1 devices a column block
+is as wide as the smallest multiple of the kernels' tile that holds
+``ceil(d / W)`` columns, so every block starts on a lane boundary of the rows'
+(8, 128) tiles and the cut in front of the ``all_to_all`` moves whole tiles.
+The cut alone on the virtual CPU devices, and the four-chip program it compiles
+to at ResNet-50's width for a described (not attached) ``v5e:2x2``."""
+
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P
+
+from aggregathor_tpu import gars
+from aggregathor_tpu.ops.pallas_kernels import LANE, MAX_BLOCK
+from aggregathor_tpu.parallel import RobustEngine, make_mesh
+from aggregathor_tpu.parallel.mesh import worker_axis
+
+
+def engine_over(devices, k, exchange_dtype=None):
+    nb_workers = len(devices) * k
+    return RobustEngine(make_mesh(nb_workers=len(devices), devices=devices),
+                        gars.instantiate("average", nb_workers, 0), nb_workers,
+                        exchange_dtype=exchange_dtype)
+
+
+def cut_and_gather(engine, d):
+    """``rows (n, d) -> (blocks (n, W * blk), gathered (n, d))`` through the
+    engine's own cut and gather: the blocks side by side in device order, and
+    every row of a block gathered the way the step gathers the aggregate."""
+
+    def body(gvecs):
+        block = engine._reshard_to_blocks(gvecs, d)
+        return block, jax.vmap(lambda row: engine._gather_blocks(row, d))(block)
+
+    return jax.jit(jax.shard_map(
+        body, mesh=engine.mesh, in_specs=P(worker_axis),
+        out_specs=(P(None, worker_axis), P()), check_vma=False))
+
+
+@pytest.mark.parametrize("d,W,k,exchange_dtype", [
+    (96, 8, 4, None),       # d divides, ceil(d / W) = 12, under one lane tile
+    (100, 8, 1, None),      # the 100-parameter model on 8 devices: 13 -> 128, not 1,024
+    (300, 2, 1, None),      # 150 -> 256
+    (1000, 4, 8, None),     # 250 -> 256
+    (1024, 2, 4, None),     # 512, on the boundary already: no padding at all
+    (4096, 4, 1, None),     # 1,024 exactly
+    (2049, 2, 8, None),     # 1,025 -> 2,048, d does not divide
+    (5160, 4, 8, None),     # ResNet-50 on four chips scaled down: 10 x 128 + 10 -> 2,048
+    (5157, 4, 8, None),     # the same block, d does not divide
+    (8200, 8, 4, None),     # 1,025 -> 2,048 on 8 devices
+    (5157, 4, 8, "bfloat16"),  # (16, 128) tiles on the wire: the cut must stay right
+    (100, 8, 4, "bfloat16"),
+    (100, 1, 8, None),      # one device: the identity
+    (5160, 1, 4, None),
+    (1290, 1, 1, "bfloat16"),
+])
+def test_block_cut(d, W, k, exchange_dtype):
+    engine = engine_over(jax.devices()[:W], k, exchange_dtype)
+    n, blk = W * k, engine._block_width(d)
+    # whole numbers under 256: exact in bfloat16, and no two columns of a row
+    # nor two rows of a column alike where it matters (251 and 241 are prime)
+    rows = (np.arange(n)[:, None] * 241 + np.arange(d)[None, :]) % 251 + 1.0
+    blocks, gathered = cut_and_gather(engine, d)(jnp.asarray(rows, jnp.float32))
+    blocks, gathered = np.asarray(blocks, np.float32), np.asarray(gathered, np.float32)
+    if W == 1:
+        assert blk == d
+    else:
+        columns = -(-d // W)
+        tile = MAX_BLOCK if columns >= MAX_BLOCK else LANE
+        assert blk % tile == 0 and columns <= blk < columns + tile
+    assert blocks.shape == (n, W * blk) and gathered.shape == (n, d)
+    np.testing.assert_array_equal(blocks[:, :d], rows)   # every row's columns, in order
+    np.testing.assert_array_equal(blocks[:, d:], 0.0)    # the padding is zeros
+    np.testing.assert_array_equal(gathered, rows)
+
+
+def test_gar_probe_times_the_steps_width():
+    """The runner's ``gar_seconds_total`` probe aggregates rows as wide as the
+    step's blocks."""
+    engine = engine_over(jax.devices()[:4], 2)
+    out = jax.block_until_ready(engine.build_gar_probe(d=5157)(0))
+    assert out.shape == (4 * engine._block_width(5157),) == (4 * 2048,)
+
+
+# --------------------------------------------------------------------------- #
+# The four-chip program at ResNet-50's width, compiled for a described chip
+
+
+@pytest.fixture(scope="module")
+def v5e_2x2():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as exc:  # whatever the missing or busy TPU library raises
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % exc)
+
+
+def test_cut_is_a_bitcast_on_four_chips(v5e_2x2):
+    """k = 8 rows of d = 25,557,032 over W = 4: the rows reach the
+    ``all-to-all`` padded once and cut by a bitcast.  At a block of
+    ``ceil(d / W)`` = 6,389,258 = 49,916 x 128 + 10 columns the compiler relaid
+    every element through two ``while`` loops and a flat 204,456,256-vector,
+    at twice the temporaries."""
+    from jax.experimental.compilation_cache import compilation_cache as cache_api
+
+    d, W, k = 25_557_032, 4, 8
+    engine = engine_over(list(v5e_2x2.devices), k)
+    blk = engine._block_width(d)
+    assert blk == 6_389_760
+    # six leaves, as a step's ``flatten`` concatenates them
+    sizes = [9_408, 2_048_000, 1_000, 8_388_608, 4_194_304]
+    sizes.append(d - sum(sizes))
+
+    def body(*leaves):
+        block = engine._reshard_to_blocks(jnp.concatenate(leaves, axis=1), d)
+        return block, engine._gather_blocks(jnp.sum(block, axis=0), d)
+
+    step = jax.jit(jax.shard_map(body, mesh=engine.mesh, in_specs=P(worker_axis),
+                                 out_specs=(P(None, worker_axis), P()), check_vma=False))
+    sharded = NamedSharding(engine.mesh, P(worker_axis))
+    leaves = [jax.ShapeDtypeStruct((W * k, size), jnp.float32, sharding=sharded)
+              for size in sizes]
+    # a program compiled for a described chip is written to the persistent
+    # cache but cannot be read back without the chip: keep it out
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cache_api.reset_cache()
+    try:
+        compiled = step.lower(*leaves).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_enabled)
+        cache_api.reset_cache()
+    text = compiled.as_text()
+    assert "while(" not in text
+    operands = re.findall(r" all-to-all\(%?([\w.-]+)\)", text)
+    assert len(operands) == 1
+    producer, = re.findall(r"^ *%%?%s = .*$" % re.escape(operands[0]), text, re.M)
+    assert " bitcast(" in producer and " copy(" not in producer, producer
+    # the padded rows and nothing beside them (the blocks are the output)
+    assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * k * W * blk * 4
