@@ -109,10 +109,19 @@ PREPROCESSING = {
 # Device-side tier: the same augmentations as jnp transforms running
 # INSIDE the jitted training step (engine ``batch_transform``), so the host
 # input path is just a gather + transfer.  This is the TPU-idiomatic home
-# for per-sample augmentation — the crop is a vmapped dynamic_slice (VPU
-# work fused into the step, zero host cost), where the reference necessarily
-# burned CPU threads on it (slim preprocessing ran on the input pipeline's
-# fetcher threads, experiments/cnnet.py:115-146).
+# for per-sample augmentation (VPU work fused into the step, zero host
+# cost), where the reference necessarily burned CPU threads on it (slim
+# preprocessing ran on the input pipeline's fetcher threads,
+# experiments/cnnet.py:115-146).
+#
+# The crop slices nothing per image: an offset takes 2*pad+1 values an axis,
+# so the crop is a choice among that many STATIC row shifts of the padded
+# batch and then as many static column shifts, each under a select on the
+# image's own offset.  Static slices and selects fuse into elementwise loops
+# in whatever layout the step keeps the batch; a per-image dynamic_slice
+# under the engine's vmap over workers compiled on the v5e to a ``while`` of
+# one slice an image, a fifth of config 2's step (PERF.md, PR 28).  The
+# output is the per-image crop's bit for bit (tests/test_preprocessing.py).
 #
 # Keying discipline matches the host tier: the engine derives the key from
 # (run seed, step, GLOBAL worker index), so worker w's augmentation stream
@@ -130,9 +139,16 @@ def _device_cifarnet(pad=4):
         kc, kf = jax.random.split(key)
         padded = jnp.pad(img, ((0, 0), (pad, pad), (pad, pad), (0, 0)), mode="reflect")
         off = jax.random.randint(kc, (b, 2), 0, 2 * pad + 1)
-        crop = jax.vmap(
-            lambda im, o: jax.lax.dynamic_slice(im, (o[0], o[1], 0), (h, w, im.shape[-1]))
-        )(padded, off)
+
+        def shifted(x, offset, axis, size):
+            """Image i's ``x[i, ..., offset[i]:offset[i] + size, ...]`` along ``axis``."""
+            offset = offset[:, None, None, None]
+            out = jax.lax.slice_in_dim(x, 0, size, axis=axis)
+            for s in range(1, 2 * pad + 1):
+                out = jnp.where(offset == s, jax.lax.slice_in_dim(x, s, s + size, axis=axis), out)
+            return out
+
+        crop = shifted(shifted(padded, off[:, 0], 1, h), off[:, 1], 2, w)
         flip = jax.random.bernoulli(kf, 0.5, (b,))
         out = jnp.where(flip[:, None, None, None], crop[:, :, ::-1, :], crop)
         return dict(batch, image=out)

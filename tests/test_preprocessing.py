@@ -1,5 +1,7 @@
 """Train-time preprocessing (augmentation) tests: slim preprocessing_factory parity."""
 
+import re
+
 import numpy as np
 import pytest
 
@@ -118,6 +120,60 @@ def test_device_cifarnet_properties():
     np.testing.assert_array_equal(x, x2)
     x3 = np.asarray(jax.jit(transform)(batch, jax.random.PRNGKey(7))["image"])
     assert not np.array_equal(x, x3)
+
+
+def _per_image_crop_flip(images, key, pad):
+    """``_device_cifarnet`` restated one image at a time: index arithmetic on
+    the same ``randint`` draw (as ``grid/references/feed_device.py`` gathers
+    it), then the same flip."""
+    import jax
+
+    count, height, width = images.shape[:3]
+    crop_key, flip_key = jax.random.split(key)
+    offsets = np.asarray(jax.random.randint(crop_key, (count, 2), 0, 2 * pad + 1))
+    flip = np.asarray(jax.random.bernoulli(flip_key, 0.5, (count,)))
+    padded = np.pad(images, ((0, 0), (pad, pad), (pad, pad), (0, 0)), mode="reflect")
+    out = np.empty_like(images)
+    for i, (oy, ox) in enumerate(offsets):
+        crop = padded[i, oy:oy + height, ox:ox + width]
+        out[i] = crop[:, ::-1] if flip[i] else crop
+    return out
+
+
+@pytest.mark.parametrize("seed", [0, 2_654_435_761])
+@pytest.mark.parametrize("shape", [(32, 32, 3), (12, 20, 3)])
+@pytest.mark.parametrize("pad", [1, 4])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_device_cifarnet_is_the_per_image_crop(dtype, pad, shape, seed):
+    """Under ``vmap`` over workers, as the engine's ``aug_one`` calls it: the
+    crop is a copy, so every bit of every image is the per-image crop's."""
+    import jax
+    import jax.numpy as jnp
+
+    workers, count = 3, 37  # offsets take (2 pad + 1)^2 values: 37 draws reach most rows and columns
+    images = jax.random.uniform(jax.random.PRNGKey(1), (workers, count) + shape).astype(dtype)
+    keys = jax.random.split(jax.random.PRNGKey(seed), workers)
+    transform = preprocessing._device_cifarnet(pad)
+    out = jax.jit(jax.vmap(lambda im, key: transform({"image": im}, key)["image"]))(images, keys)
+    assert out.dtype == jnp.dtype(dtype) and out.shape == images.shape
+    host = np.asarray(images.astype(jnp.float32))  # exact: widening only
+    got = np.asarray(out.astype(jnp.float32))
+    for worker in range(workers):
+        np.testing.assert_array_equal(got[worker], _per_image_crop_flip(host[worker], keys[worker], pad))
+
+
+def test_device_cifarnet_neither_gathers_nor_loops():
+    """Static slices under selects: no per-image slice for the compiler to
+    make a loop of (8192 iterations a step at config 2's batch)."""
+    import jax
+    import jax.numpy as jnp
+
+    transform = preprocessing.device_transform("cifarnet")
+    batch = {"image": jnp.zeros((2, 6, 32, 32, 3), jnp.bfloat16)}
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    text = str(jax.make_jaxpr(jax.vmap(transform))(batch, keys))  # nested jaxprs print inline
+    assert " slice[" in text and "select_n" in text
+    assert not re.findall(r"\b(?:dynamic_slice|gather|while|scan)\b", text)
 
 
 def test_device_flip_only_flips():

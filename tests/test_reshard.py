@@ -4,7 +4,12 @@ is as wide as the smallest multiple of the kernels' tile that holds
 ``ceil(d / W)`` columns, so every block starts on a lane boundary of the rows'
 (8, 128) tiles and the cut in front of the ``all_to_all`` moves whole tiles.
 The cut alone on the virtual CPU devices, and the four-chip program it compiles
-to at ResNet-50's width for a described (not attached) ``v5e:2x2``."""
+to at ResNet-50's width for a described (not attached) ``v5e:2x2``.
+
+Every program compiled for the described chip lives in this one file, config
+2's in-step crop (models/preprocessing.py) included: only one process at a
+time may load the TPU's library, and a second file's fixture could land on
+another test worker and skip in silence."""
 
 import os
 import re
@@ -101,14 +106,27 @@ def v5e_2x2():
         pytest.skip("no v5e:2x2 topology can be described here: %s" % exc)
 
 
+def compile_uncached(jitted, *shapes):
+    """A program compiled for a described chip is written to the persistent
+    cache but cannot be read back without the chip: keep it out."""
+    from jax.experimental.compilation_cache import compilation_cache as cache_api
+
+    was_enabled = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cache_api.reset_cache()
+    try:
+        return jitted.lower(*shapes).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was_enabled)
+        cache_api.reset_cache()
+
+
 def test_cut_is_a_bitcast_on_four_chips(v5e_2x2):
     """k = 8 rows of d = 25,557,032 over W = 4: the rows reach the
     ``all-to-all`` padded once and cut by a bitcast.  At a block of
     ``ceil(d / W)`` = 6,389,258 = 49,916 x 128 + 10 columns the compiler relaid
     every element through two ``while`` loops and a flat 204,456,256-vector,
     at twice the temporaries."""
-    from jax.experimental.compilation_cache import compilation_cache as cache_api
-
     d, W, k = 25_557_032, 4, 8
     engine = engine_over(list(v5e_2x2.devices), k)
     blk = engine._block_width(d)
@@ -126,16 +144,7 @@ def test_cut_is_a_bitcast_on_four_chips(v5e_2x2):
     sharded = NamedSharding(engine.mesh, P(worker_axis))
     leaves = [jax.ShapeDtypeStruct((W * k, size), jnp.float32, sharding=sharded)
               for size in sizes]
-    # a program compiled for a described chip is written to the persistent
-    # cache but cannot be read back without the chip: keep it out
-    was_enabled = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    cache_api.reset_cache()
-    try:
-        compiled = step.lower(*leaves).compile()
-    finally:
-        jax.config.update("jax_enable_compilation_cache", was_enabled)
-        cache_api.reset_cache()
+    compiled = compile_uncached(step, *leaves)
     text = compiled.as_text()
     assert "while(" not in text
     operands = re.findall(r" all-to-all\(%?([\w.-]+)\)", text)
@@ -144,3 +153,32 @@ def test_cut_is_a_bitcast_on_four_chips(v5e_2x2):
     assert " bitcast(" in producer and " copy(" not in producer, producer
     # the padded rows and nothing beside them (the blocks are the output)
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * k * W * blk * 4
+
+
+def test_crop_neither_loops_nor_slices_on_the_chip(v5e_2x2):
+    """Config 2's in-step crop as ``aug_one`` runs it, 8 workers x 1024
+    ``bfloat16`` images: static shifts under selects compile to loop fusions.
+    As a vmapped ``dynamic_slice`` it was a ``while`` of 8192 per-image
+    ``dynamic-update-slice``s, a fifth of the step (PERF.md, PR 28)."""
+    from jax.sharding import SingleDeviceSharding
+
+    from aggregathor_tpu.models import preprocessing
+
+    workers = 8
+    transform = preprocessing.device_transform("cifarnet")
+
+    def augment(images, key):
+        def aug_one(worker_images, j):
+            wkey = jax.random.fold_in(jax.random.fold_in(key, j), 3)
+            return transform({"image": worker_images}, wkey)["image"]
+
+        return jax.vmap(aug_one)(images, jnp.arange(workers))
+
+    one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
+    compiled = compile_uncached(
+        jax.jit(augment),
+        jax.ShapeDtypeStruct((workers, 1024, 32, 32, 3), jnp.bfloat16, sharding=one_chip),
+        jax.ShapeDtypeStruct((2,), jnp.uint32, sharding=one_chip))
+    text = compiled.as_text()
+    assert " while(" not in text and " dynamic-update-slice(" not in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 300e6
