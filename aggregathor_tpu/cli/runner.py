@@ -373,8 +373,7 @@ def build_parser():
         "--trace-file", default=None, metavar="PATH",
         help="whole-run HOST span trace (obs/trace): dispatch / block / "
              "host-gap / input / eval / checkpoint spans as Chrome "
-             "trace-event JSON, Perfetto-loadable; zero added recompiles, "
-             "bounded overhead (benchmarks/trace_overhead.py); "
+             "trace-event JSON, Perfetto-loadable; zero added recompiles; "
              "multi-process runs suffix non-lead files with .<process>",
     )
     parser.add_argument(

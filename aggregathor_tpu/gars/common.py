@@ -8,8 +8,8 @@ strip this handling by using explicit ``isfinite`` masking rather than NaN
 comparisons.
 """
 
+import contextlib
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -38,12 +38,31 @@ def _is_batched_tracer(x):
     (engine._aggregate_per_leaf_bucketed, the sharded per-bucket loop); a
     vmapped ``pallas_call`` lowers through Pallas' batching rule.
     Detecting the batching trace centrally means no call site can forget an
-    opt-out wrapper; the explicit ``GRAFT_GAR_TIER=pallas`` force remains
-    the one way to exercise the vmapped Pallas path end to end
+    opt-out wrapper; ``forced_tier("pallas")`` remains the one way to
+    exercise the vmapped Pallas path end to end
     (``tests/test_pallas.py::test_batched_tracer_detected_under_vmap`` fails
     loudly if the tracer class moves and detection stops firing).
     """
     return isinstance(x, BatchTracer)
+
+
+#: The tier ``forced_tier`` holds ``kernel_tier`` to; ``None`` outside it.
+_forced = None
+
+
+@contextlib.contextmanager
+def forced_tier(tier):
+    """Hold ``kernel_tier`` to ``"pallas"`` or ``"jnp"`` for what is TRACED
+    inside the block.  The seam of the tier-parity tests and of
+    scripts/pallas_tpu_check.py's jnp column; no training path enters it."""
+    global _forced
+    if tier not in ("pallas", "jnp"):
+        raise ValueError("forced_tier takes 'pallas' or 'jnp', got %r" % (tier,))
+    previous, _forced = _forced, tier
+    try:
+        yield
+    finally:
+        _forced = previous
 
 
 def kernel_tier(block):
@@ -60,16 +79,15 @@ def kernel_tier(block):
     order of averaged means differs, so low bits can (asserted on
     NaN-poisoned inputs by tests/test_pallas.py and on the chip by
     scripts/pallas_tpu_check.py).  A vmapped call stays on the jnp tier
-    (ROADMAP S3 lifts that).  ``GRAFT_GAR_TIER=jnp|pallas`` forces a tier
-    (tests, A/B timing); the ``pallas`` force outranks the vmap diversion —
-    it is the only way to exercise the vmapped Pallas path end to end.
+    (ROADMAP S3 lifts that).  Inside ``forced_tier`` the forced tier
+    answers; the ``pallas`` force outranks the vmap diversion, ``jnp`` does
+    not.
     """
-    forced = os.environ.get("GRAFT_GAR_TIER")
-    if forced == "pallas":
+    if _forced == "pallas":
         return "pallas"
     if _is_batched_tracer(block):
         return "jnp (vmapped)"
-    if forced == "jnp":
+    if _forced == "jnp":
         return "jnp"
     if on_tpu() and block.ndim == 2 and block.shape[1] >= PALLAS_MIN_COLUMNS:
         return "pallas"

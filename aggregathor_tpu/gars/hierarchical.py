@@ -14,8 +14,8 @@ two-level tree::
 
 so the n²·d term shrinks to (n/g)²·d plus an O(n·d) group pass.  With g
 grown ~n/const the outer matrix stays constant-sized and total work is
-linear in n — sublinear in n² (benchmarks/gar_kernels.py ``--sweep-ns``
-measures exactly this claim).
+linear in n — sublinear in n² (``gars/scaling.run_sweep`` measures exactly
+this claim).
 
 **Byzantine bookkeeping.**  Groups are a *partition*: f Byzantine workers
 can corrupt at most f group summaries (each worker sits in exactly one

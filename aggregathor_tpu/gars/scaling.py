@@ -27,13 +27,12 @@ the same harness.
 
 Timing protocol: every timed repetition is **individually synced** — the
 output is ``block_until_ready``'d and a scalar of it is fetched to the host
-before the clock stops — and the median rep is reported.  (The older
-dispatch-loop slope estimate in benchmarks/gar_kernels.py could go negative
-under backend latency jitter and clamped whole rows to 0.0 ms; see
+before the clock stops — and the median rep is reported.  (A dispatch-loop
+slope estimate can go negative under backend latency jitter; see
 ``time_aggregate``.)
 
-Used by ``benchmarks/gar_kernels.py --sweep-ns`` and
-``scripts/run_scaling_smoke.sh``; validated by tests/test_gar_scaling.py.
+Validated by tests/test_gar_scaling.py; ``time_aggregate`` also times
+scripts/pallas_tpu_check.py's two tiers.
 """
 
 import json
@@ -65,8 +64,7 @@ def sync_fetch(out):
     device and only the 4-byte scalar crosses to the host — so the same
     call also waits on the native tier's numpy outputs.
     The ONE sync primitive every timed GAR section uses (here,
-    benchmarks/gar_kernels.py, scripts/pallas_tpu_check.py, and the
-    runner's ``--gar-probe``)."""
+    scripts/pallas_tpu_check.py, and the runner's ``--gar-probe``)."""
     import jax
 
     jax.block_until_ready(out)

@@ -754,8 +754,8 @@ class RobustEngine:
         so a ResNet-50 (~160 leaves, ~dozens of distinct shapes) traces
         O(#distinct sizes) collectives and selection graphs instead of
         O(#leaves) (the compile-time/step-latency blowup VERDICT r2 flagged;
-        same stacking trick as the sharded engine's layer axis,
-        sharded_engine.py).  Per-leaf PRNG keys reproduce the unrolled
+        same stacking trick as the sharded dataflow's layer axis,
+        ``_make_sharded_body``).  Per-leaf PRNG keys reproduce the unrolled
         path's exactly (fold_in by ORIGINAL leaf index), so the two paths
         make the same selections and agree with
         ``_aggregate_per_leaf_unrolled`` to float tolerance (vmapped

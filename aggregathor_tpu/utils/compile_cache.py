@@ -1,6 +1,6 @@
 """Where the persistent compilation cache lives — decided from outside.
 
-Entry points (cli.runner, cli.serve, bench.py, chip_smoke.py,
+Entry points (cli.runner, cli.serve, chip_smoke.py,
 scripts/pallas_tpu_check.py) call :func:`place_compile_cache` before their
 first compile; importing the library sets nothing.  A machine that wants the
 cache to outlive the process exports ``JAX_COMPILATION_CACHE_DIR`` and JAX

@@ -1,7 +1,5 @@
-"""Tiny atomic JSON state files, shared by the resumable benchmark harnesses.
-
-One load/save pair instead of copies (per-cell robustness resume,
-per-config train_configs resume, the sweeps): load tolerates a
+"""Tiny atomic JSON state files for a resumable harness
+(benchmarks/robustness.py's per-cell resume): load tolerates a
 missing/corrupt/non-dict file by returning the default, save goes through a
 tmp file + os.replace so a kill mid-write can never leave a half-written
 state behind.

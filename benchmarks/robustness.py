@@ -6,7 +6,7 @@ experiments.sh:19-53 is its harness).  The unit suite proves this at toy
 scale; this harness produces the experiment-scale evidence: cnnet CIFAR-10,
 n=8 workers, f=2 declared / 2 real attackers, {average, krum, median} x
 {none, little, empire}, final evaluation accuracy after a fixed step budget
-— driven through the REAL CLI as subprocesses, like train_configs.py.
+— driven through the REAL CLI as subprocesses.
 
 Expected shape of the result: under ``little``/``empire`` the robust rules
 keep learning while ``average`` is dragged (or NaN-aborts, which the runner

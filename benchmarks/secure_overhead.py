@@ -216,7 +216,7 @@ def main(argv=None):
     noise_pct = float(
         (base_arr.max() - base_arr.min()) / 2.0 / np.median(base_arr) * 100.0
     )
-    # Noise-aware verdict (trace_overhead.py discipline): on a loaded CI
+    # Noise-aware verdict: on a loaded CI
     # core a load spike must not read as security tax — fail only beyond
     # BOTH the bar and the box's own measured noise floor.
     passed = overhead_pct <= max(args.bar, noise_pct)

@@ -52,6 +52,7 @@ def run_check(n, f, dims, rules=RULES, reps=10, nan_workers=2,
     import jax
 
     from aggregathor_tpu import gars
+    from aggregathor_tpu.gars.common import forced_tier
     from aggregathor_tpu.gars.scaling import time_aggregate
     from aggregathor_tpu.ops import pallas_kernels as pk
 
@@ -73,9 +74,7 @@ def run_check(n, f, dims, rules=RULES, reps=10, nan_workers=2,
     # (gars/common.py kernel_tier), which would turn the jnp column into a
     # second Pallas column.  The *-pallas registrations override
     # aggregate_block directly and ignore this.
-    previous_tier = os.environ.get("GRAFT_GAR_TIER")
-    os.environ["GRAFT_GAR_TIER"] = "jnp"
-    try:
+    with forced_tier("jnp"):
         for d in dims:
             g_host = rng.normal(size=(n, d)).astype(np.float32)
             if nan_workers:
@@ -149,11 +148,6 @@ def run_check(n, f, dims, rules=RULES, reps=10, nan_workers=2,
                     row["parity"] = "ERROR"
                     row["error"] = "%s: %s" % (type(exc).__name__, str(exc)[:400])
                 report(row)
-    finally:
-        if previous_tier is None:
-            del os.environ["GRAFT_GAR_TIER"]
-        else:
-            os.environ["GRAFT_GAR_TIER"] = previous_tier
     return failed
 
 

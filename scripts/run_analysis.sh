@@ -22,9 +22,9 @@ export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 
 echo "== ruff (lint + import sort; pyproject.toml [tool.ruff]) =="
 if command -v ruff >/dev/null 2>&1; then
-    ruff check aggregathor_tpu tests benchmarks scripts bench.py chip_smoke.py
+    ruff check aggregathor_tpu tests benchmarks scripts chip_smoke.py
 elif python -m ruff --version >/dev/null 2>&1; then
-    python -m ruff check aggregathor_tpu tests benchmarks scripts bench.py chip_smoke.py
+    python -m ruff check aggregathor_tpu tests benchmarks scripts chip_smoke.py
 else
     echo "ruff not installed in this environment: SKIPPED" \
          "(pip install -e '.[lint]' to enable)"

@@ -179,19 +179,30 @@ def test_pallas_krum_excludes_fully_nan_row_like_jnp():
     np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-5)
 
 
-def test_engine_auto_tier_matches_jnp(monkeypatch):
+def _tier_under_vmap(stack):
+    """What ``kernel_tier`` answers for the (n, d) blocks of ``stack`` under vmap."""
+    import jax
+
+    from aggregathor_tpu.gars import common
+
+    tiers = []
+    jax.vmap(lambda x: tiers.append(common.kernel_tier(x)) or x.sum())(stack)
+    return tiers
+
+
+def test_engine_auto_tier_matches_jnp():
     """The round-4 backend auto-dispatch (gars/common.use_pallas_coordinate_tier):
-    forcing GRAFT_GAR_TIER=pallas routes median/averaged-median/bulyan-final
+    ``forced_tier("pallas")`` routes median/averaged-median/bulyan-final
     selections AND the engine's partial distances through the Pallas kernels
     (interpret mode on CPU) inside the full shard_map step — and the result
     matches the default jnp tier."""
     import jax
     from aggregathor_tpu import gars, models
     from aggregathor_tpu.core import build_optimizer, build_schedule
+    from aggregathor_tpu.gars.common import forced_tier
     from aggregathor_tpu.parallel import RobustEngine, make_mesh
 
-    def run(tier):
-        monkeypatch.setenv("GRAFT_GAR_TIER", tier)
+    def run():
         exp = models.instantiate("mnist", ["batch-size:8"])
         # bulyan: needs_distances (the engine's partial-distance dispatch)
         # AND an averaged-median final phase (the coordinate dispatch)
@@ -207,26 +218,57 @@ def test_engine_auto_tier_matches_jnp(monkeypatch):
             [np.ravel(np.asarray(x)) for x in jax.tree_util.tree_leaves(state.params)]
         )
 
-    np.testing.assert_allclose(run("pallas"), run("jnp"), rtol=1e-5, atol=1e-6)
+    with forced_tier("pallas"):
+        through_pallas = run()
+    with forced_tier("jnp"):
+        through_jnp = run()
+    np.testing.assert_allclose(through_pallas, through_jnp, rtol=1e-5, atol=1e-6)
 
 
-def test_use_pallas_tier_env_force(monkeypatch):
-    from aggregathor_tpu.gars.common import use_pallas_coordinate_tier
+def test_use_pallas_tier_forced():
+    from aggregathor_tpu.gars.common import forced_tier, use_pallas_coordinate_tier
 
     block = np.zeros((8, 4), np.float32)
-    monkeypatch.setenv("GRAFT_GAR_TIER", "pallas")
-    assert use_pallas_coordinate_tier(block)
-    monkeypatch.setenv("GRAFT_GAR_TIER", "jnp")
+    with forced_tier("pallas"):
+        assert use_pallas_coordinate_tier(block)
+        with forced_tier("jnp"):
+            assert not use_pallas_coordinate_tier(block)
+        assert use_pallas_coordinate_tier(block)  # the outer force is restored
+    with pytest.raises(ValueError, match="'pallas' or 'jnp'"):
+        with forced_tier("auto"):
+            pass
+    # nothing forced, CPU backend: the jnp tier regardless of size
     assert not use_pallas_coordinate_tier(block)
-    monkeypatch.delenv("GRAFT_GAR_TIER")
-    # CPU backend: auto stays on the jnp tier regardless of size
     assert not use_pallas_coordinate_tier(np.zeros((8, 1 << 20), np.float32))
+
+
+@pytest.mark.parametrize("exported", ["jnp", "pallas"])
+def test_kernel_tier_ignores_the_environment(monkeypatch, exported):
+    """A shell that exports GRAFT_GAR_TIER (the switch read here until PR 29)
+    moves no decision: on a 'tpu' backend and on the CPU, plain and under
+    vmap, ``kernel_tier`` answers as with the variable unset."""
+    import jax
+
+    from aggregathor_tpu.gars import common
+
+    big = np.zeros((8, common.PALLAS_MIN_COLUMNS), np.float32)
+
+    def answers():
+        return [common.kernel_tier(big), common.kernel_tier(big[:, :128])] + _tier_under_vmap(big[None])
+
+    monkeypatch.delenv("GRAFT_GAR_TIER", raising=False)
+    unset_cpu = answers()
+    monkeypatch.setenv("GRAFT_GAR_TIER", exported)
+    assert answers() == unset_cpu == ["jnp", "jnp", "jnp (vmapped)"]
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert answers() == ["pallas", "jnp", "jnp (vmapped)"]
 
 
 def test_use_pallas_tier_suspends_under_vmap(monkeypatch):
     """The auto-dispatch detects a batching trace centrally: even on a
     'tpu' backend with a large block, a vmapped rule call stays on the
-    jnp tier — while the same call outside vmap dispatches."""
+    jnp tier — while the same call outside vmap dispatches.  The ``jnp``
+    force does not outrank the diversion's name; the ``pallas`` force does."""
     import jax
 
     from aggregathor_tpu.gars import common
@@ -242,6 +284,10 @@ def test_use_pallas_tier_suspends_under_vmap(monkeypatch):
     jax.vmap(probe)(big)          # batched (8, d) block -> suspended
     probe(big[0])                 # same block, plain call -> dispatches
     assert decisions == [False, True]
+    with common.forced_tier("jnp"):
+        assert _tier_under_vmap(big) == ["jnp (vmapped)"]
+    with common.forced_tier("pallas"):
+        assert _tier_under_vmap(big) == ["pallas"]
 
 
 def test_batched_tracer_detected_under_vmap():
@@ -276,9 +322,7 @@ def test_kernel_tier_names_the_served_tier(monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert common.kernel_tier(big) == "pallas"
     assert common.kernel_tier(big[:, :128]) == "jnp"
-    tiers = []
-    jax.vmap(lambda x: tiers.append(common.kernel_tier(x)) or x.sum())(big[None])
-    assert tiers == ["jnp (vmapped)"]
+    assert _tier_under_vmap(big[None]) == ["jnp (vmapped)"]
 
 
 @pytest.mark.parametrize("case", CASES)
@@ -304,7 +348,7 @@ def test_coordinate_trimmed_mean_poisoned_band():
 def test_pallas_tpu_check_callable_in_interpret_mode(monkeypatch):
     """scripts/pallas_tpu_check.run_check — chip_smoke's leg C — run off-TPU
     at a tiny d: every row comes back parity-ok through ``emit``, the jnp
-    pin is restored, and without --allow-interpret it refuses to interpret."""
+    force is released, and without --allow-interpret it refuses to interpret."""
     import os
     import sys
 
@@ -312,7 +356,8 @@ def test_pallas_tpu_check_callable_in_interpret_mode(monkeypatch):
     monkeypatch.syspath_prepend(scripts)
     import pallas_tpu_check
 
-    monkeypatch.delenv("GRAFT_GAR_TIER", raising=False)
+    from aggregathor_tpu.gars import common
+
     rows = []
     failed = pallas_tpu_check.run_check(
         8, 2, [256], rules=("median", "krum"), reps=1,
@@ -322,7 +367,7 @@ def test_pallas_tpu_check_callable_in_interpret_mode(monkeypatch):
         "median", "krum", "median-vmap4", "averaged-median-vmap4",
         "trimmed-mean-vmap4", "pairwise-dist-vmap4"]
     assert all(r["parity"] == "ok" for r in rows)
-    assert "GRAFT_GAR_TIER" not in os.environ
+    assert common._forced is None
     with pytest.raises(RuntimeError, match="needs a TPU"):
         pallas_tpu_check.run_check(8, 2, [256], rules=("median",), reps=1)
     sys.modules.pop("pallas_tpu_check", None)

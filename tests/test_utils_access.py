@@ -1,8 +1,14 @@
 """Tests for the can_access pre-check (reference tools/access.py parity)."""
 
+import glob
 import os
+import re
+
+import pytest
 
 from aggregathor_tpu.utils import can_access
+
+_REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 def test_can_access_file(tmp_path):
@@ -50,21 +56,43 @@ def test_state_json_roundtrip_and_corruption(tmp_path):
     assert load_json(path, default={"done": []}) == {"done": []}
 
 
-def test_hw_peaks_keyed_by_device_kind():
-    """utils/hw: one sourced table keyed by device_kind; a kind that is not
-    in it raises and names itself — never another chip's peak."""
-    import types
-
-    import pytest
-
+def test_hw_names_no_peaks():
+    """utils/hw answers one question (``on_tpu``); the chip's published peaks
+    live with the benchmark alone (grid/peaks.json), not in a second table."""
     from aggregathor_tpu.utils import hw
 
-    v5e = hw.peaks(types.SimpleNamespace(device_kind="TPU v5 lite", platform="tpu"))
-    assert v5e.bf16_flops == 1.97e14 and v5e.hbm_bytes_per_s == 8.19e11
-    assert "TPU v5e" in v5e.source
-    with pytest.raises(KeyError, match="cpu-kind-nobody-listed"):
-        hw.peaks(types.SimpleNamespace(device_kind="cpu-kind-nobody-listed",
-                                       platform="cpu"))
+    assert callable(hw.on_tpu) and hw.on_tpu() is False  # the suite runs on the CPU
+    assert not [name for name in vars(hw) if "peak" in name.lower()]
+    assert os.path.exists(os.path.join(_REPO, "grid", "peaks.json"))
+
+
+#: README, the index of records, every page of docs/ and the builders' skill;
+#: not PERF.md or ROADMAP.md, which name files that later PRs are to bring.
+_DOCUMENTS = ["README.md", "BENCHMARKS.md", ".claude/skills/verify/SKILL.md"] + sorted(
+    "docs/" + name for name in os.listdir(os.path.join(_REPO, "docs")) if name.endswith(".md"))
+#: A relative path to a script or a record.  Not taken: absolute paths, and
+#: what hangs off a placeholder or a variable (``<snapshot>.manifest.json``,
+#: ``$out/x.json``) — a file that a documented command WRITES is named under
+#: ``/tmp`` for that reason.
+_PATH = re.compile(r"(?<![\w/.$~<>{*-])([\w.*-]+(?:/[\w.*-]+)*\.(?:py|sh|json))(?![\w/*])")
+
+
+@pytest.mark.parametrize("document", _DOCUMENTS)
+def test_documents_name_files_that_exist(document):
+    """Every path to a ``.py``, ``.sh`` or ``.json`` that a document back-quotes
+    or puts on a command line is in the tree, from the repository's root or
+    from the package's directory (a ``*`` globs).  A reader who follows a
+    document must not land on an instrument that is gone."""
+    with open(os.path.join(_REPO, document)) as fd:
+        text = fd.read()
+    fenced = re.findall(r"```.*?```", text, re.S)
+    quoted = re.findall(r"`([^`\n]+)`", re.sub(r"```.*?```", "", text, flags=re.S))
+    named = {m.group(1) for span in fenced + quoted for m in _PATH.finditer(span)}
+    dangling = sorted(
+        path for path in named
+        if not any(glob.glob(os.path.join(base, path))
+                   for base in (_REPO, os.path.join(_REPO, "aggregathor_tpu"))))
+    assert not dangling, "%s names files that are not in the tree: %s" % (document, dangling)
 
 
 def test_compile_cache_is_placed_from_outside(monkeypatch):
