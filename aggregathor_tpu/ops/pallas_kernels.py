@@ -5,11 +5,16 @@ The framework's counterpart of the reference's C++/CUDA custom ops
 aggregators/deprecated_native/native.cpp:678-747).  Two hot shapes:
 
 - **Pairwise squared distances** of the (n, d) gradient matrix — O(n²·d),
-  streamed over column blocks so the whole matrix never sits in VMEM.  Two
-  kernels: an exact difference-form (VPU, reference-faithful accumulation
-  order per block) and an MXU Gram-form (``|a|² + |b|² − 2ab`` per block,
-  per-block median-centered against catastrophic cancellation — the same
-  math the sharded engine psums, parallel/engine.py).
+  streamed over column blocks so the whole matrix never sits in VMEM.  Three
+  kernels.  Up to 64 rows (every cell of the grid) the exact difference form
+  runs as one row tile, ``_dist_pairs_kernel``: one read of each (n, blk)
+  block, blocks of up to 16,384 columns, each unordered pair of 8-row groups
+  taken once, squared differences kept as 128-lane partials in VMEM and
+  reduced across lanes once, at the last grid step.  Beyond, or with a forced
+  ``row_tile``: the (i, j, k)-tiled difference form (VPU, a tile x tile x
+  blk tensor per step) and the MXU Gram form (``|a|² + |b|² − 2ab`` per block,
+  median-centered against catastrophic cancellation — the same math the
+  sharded engine psums, parallel/engine.py).
 - **Coordinate-wise selection** (median / averaged-median, Bulyan phase 3) —
   the reference's per-coordinate ``nth_element`` (native.cpp:678-747) is
   control flow, which doesn't vectorize on TPU; here selection is
@@ -31,6 +36,16 @@ keys +inf at the highest indices, and its rank is exactly n (every real row
 precedes it), strictly above every selection threshold (n//2 < n, beta <=
 n); ``average_nan_columns`` ignores non-finite rows by construction, and
 the distance wrappers slice padded rows/columns off before returning.
+
+Ragged widths: the coordinate kernels and the pair kernel take the rows as
+wide as they are, on a grid over their ``d // blk`` whole blocks — no padded
+copy of the matrix (3.3 GB a step in ResNet-50's Bulyan until PR 30).  The
+fewer-than-a-block columns left over go through the same arithmetic as plain
+jnp on a slice, added to the distances or appended to the coordinate rule's
+row.  Only rows narrower than one block are padded up to it.  (A grid of
+``ceil(d / blk)`` blocks whose last one reads past the edge, masked in the
+kernel, ran as fast but cost the one-chip ResNet-50 step 47 s more to start
+in every process after a machine's first; PERF.md, PR 30.)
 
 All kernels auto-fall back to interpreter mode off-TPU, so the same code
 path is exercised by the CPU test suite.
@@ -60,17 +75,35 @@ def _pad_axis(x, axis, multiple, value=0.0):
     return jnp.pad(x, widths, constant_values=value)
 
 
-#: Lane width of the (8, 128) tiles, and the widest column block a kernel
-#: takes: every kernel's block is a multiple of the first and at most the
-#: second, so rows whose width is a multiple of ``MAX_BLOCK`` (of ``LANE``
-#: when narrower) pass ``_pad_axis`` untouched at any power-of-two block.
+#: Lane width of the (8, 128) tiles, and the widest column block of the
+#: coordinate kernels and of the tiled distance kernels: their blocks are a
+#: multiple of the first and at most the second.  ``engine._block_width`` cuts
+#: the four-chip column blocks on the same two numbers, so that every block
+#: starts on a tile boundary and ends on a whole kernel block.
 LANE = 128
 MAX_BLOCK = 1024
 
+#: The pair kernel (``_dist_pairs_kernel``) serves up to this many (padded)
+#: rows — its lane-partial accumulator is (rows, rows, LANE) float32, 2 MB at
+#: 64, and its comparator loop is unrolled like ``_ranks``' — in blocks of at
+#: most this many columns.
+PAIR_ROWS_MAX = 64
+PAIR_MAX_BLOCK = 16384
 
-def _clamp_block(blk, d):
-    blk = max(LANE, min(MAX_BLOCK, (blk // LANE) * LANE))
+
+def _clamp_block(blk, d, widest=MAX_BLOCK):
+    blk = max(LANE, min(widest, (blk // LANE) * LANE))
     return min(blk, max(LANE, -(-d // LANE) * LANE))
+
+
+def _whole_blocks(x, blk, fill):
+    """``x`` as the kernels read it, and the width its whole blocks cover:
+    rows padded with ``fill`` to the sublane multiple, columns left as they
+    are — only rows narrower than one block are padded up to it."""
+    xp = _pad_axis(x, 0, 8, fill)
+    if xp.shape[1] < blk:
+        xp = _pad_axis(xp, 1, blk)
+    return xp, xp.shape[1] // blk * blk
 
 
 #: Worker-row tile of the distance kernels: above this many (padded) rows
@@ -81,9 +114,10 @@ ROW_TILE = 128
 
 
 def _pick_block_diff(tile, d, vmem_budget=1 << 22):
-    """Diff-form distance block: the tile·tile·blk difference tensor sets
-    the size (``tile`` is the ROW TILE, not n — row tiling keeps the
-    budget independent of the worker count)."""
+    """Block of the TILED difference form (more than ``PAIR_ROWS_MAX`` rows,
+    or a forced ``row_tile``): the tile·tile·blk difference tensor sets the
+    size (``tile`` is the ROW TILE, not n — row tiling keeps the budget
+    independent of the worker count)."""
     return _clamp_block(vmem_budget // max(tile * tile * 4, 1), d)
 
 
@@ -154,95 +188,99 @@ def _store_row(out_ref, row):
     out_ref[:] = jnp.broadcast_to(row[None, :], out_ref.shape)
 
 
-def _median_kernel(n, x_ref, out_ref):
-    x = x_ref[:]
-    _store_row(out_ref, _select_rank(x, _ranks(_inf_key(x), n), n // 2))
+# Each rule maps an (n, w) slab of values to its (w,) row: inside the kernel
+# on a block read from its ref, and as plain jnp on the few columns past the
+# last whole block (``_coordinate_call``).
+
+def _median_rule(n, x):
+    return _select_rank(x, _ranks(_inf_key(x), n), n // 2)
 
 
-def _averaged_median_kernel(n, beta, x_ref, out_ref):
-    x = x_ref[:]
+def _averaged_median_rule(n, beta, x):
     med = _select_rank(x, _ranks(_inf_key(x), n), n // 2)
     dev_ranks = _ranks(_inf_key(jnp.abs(x - med[None, :])), n)
     chosen = jnp.where(dev_ranks < beta, x, 0.0)
-    _store_row(out_ref, jnp.sum(chosen, axis=0) / float(beta))
+    return jnp.sum(chosen, axis=0) / float(beta)
 
 
-def _trimmed_mean_kernel(n, trim, keep, x_ref, out_ref):
+def _trimmed_mean_rule(n, trim, keep, x):
     # Mean of the CLEANED (+inf-mapped) values at ranks [trim, trim+keep):
     # an inf in the kept band poisons the sum -> NaN surfaced, matching
     # gars/trimmed_mean.trimmed_mean_columns.  Padded rows rank exactly n
     # (every real row outranks or index-ties below them), never selected.
-    x = x_ref[:]
     key = _inf_key(x)
     ranks = _ranks(key, n)
     sel = jnp.where((ranks >= trim) & (ranks < trim + keep), key, 0.0)
     mean = jnp.sum(sel, axis=0) / float(keep)
-    _store_row(out_ref, jnp.where(jnp.isfinite(mean), mean, jnp.nan))
+    return jnp.where(jnp.isfinite(mean), mean, jnp.nan)
 
 
-def _coordinate_call(name, kernel, x, block_d=None):
-    """Run a (n, blk) -> row coordinate kernel over column blocks.
+def _average_nan_rule(x):
+    finite = jnp.isfinite(x)  # NaN-padded rows count for nothing
+    total = jnp.sum(jnp.where(finite, x, 0.0), axis=0)
+    count = jnp.sum(finite.astype(jnp.float32), axis=0)
+    return jnp.where(count > 0, total / jnp.maximum(count, 1.0), 0.0)
+
+
+def _coordinate_call(name, rule, x, block_d=None):
+    """Run the coordinate rule ``rule`` ((n, w) slab -> (w,) row) over the
+    columns of ``x``: as a Pallas kernel over the whole blocks of the rows as
+    they are, and as jnp on the fewer-than-a-block columns left over.
 
     ``name`` is the public function's: what the ``pallas_call`` is called in
-    a compiled program and a device trace.  Rank thresholds inside ``kernel``
+    a compiled program and a device trace.  Rank thresholds inside ``rule``
     use the REAL n; the slab rows are padded to the f32 sublane multiple with
     NaN (neutral, module docstring).
     """
     n, d = x.shape
     rows = n + (-n) % 8  # the slab the kernel actually holds is padded
     blk = block_d or _pick_block_coord(rows, d)
-    xp = _pad_axis(x.astype(jnp.float32), 1, blk)
-    xp = _pad_axis(xp, 0, 8, jnp.nan)
-    grid = xp.shape[1] // blk
+    xp, whole = _whole_blocks(x.astype(jnp.float32), blk, jnp.nan)
+
+    def kernel(x_ref, out_ref):
+        _store_row(out_ref, rule(x_ref[:]))
+
     out = pl.pallas_call(
         kernel,
-        grid=(grid,),
+        grid=(whole // blk,),
         in_specs=[pl.BlockSpec((rows, blk), lambda i: (0, i), memory_space=pltpu.VMEM)],
         out_specs=pl.BlockSpec((8, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((8, xp.shape[1]), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((8, whole), jnp.float32),
         interpret=_interpret(),
         name=name,
     )(xp)
-    return out[0, :d]
+    row = out[0]
+    if whole < xp.shape[1]:
+        row = jnp.concatenate([row, rule(xp[:, whole:])])
+    return row[:d]
 
 
 def coordinate_median(x, block_d=None):
     """(d,) upper median per column of an (n, d) matrix, non-finite last."""
-    n = x.shape[0]
     return _coordinate_call(
-        "coordinate_median", functools.partial(_median_kernel, n), x, block_d)
+        "coordinate_median", functools.partial(_median_rule, x.shape[0]), x, block_d)
 
 
 def coordinate_averaged_median(x, beta, block_d=None):
     """(d,) per-column mean of the ``beta`` values closest to the median."""
-    n = x.shape[0]
     return _coordinate_call(
         "coordinate_averaged_median",
-        functools.partial(_averaged_median_kernel, n, int(beta)), x, block_d
+        functools.partial(_averaged_median_rule, x.shape[0], int(beta)), x, block_d
     )
 
 
 def coordinate_trimmed_mean(x, trim, keep, block_d=None):
     """(d,) per-column mean of the values at sorted ranks [trim, trim+keep)
     with non-finite mapped to +inf; NaN where the kept band is poisoned."""
-    n = x.shape[0]
     return _coordinate_call(
         "coordinate_trimmed_mean",
-        functools.partial(_trimmed_mean_kernel, n, int(trim), int(keep)), x, block_d
+        functools.partial(_trimmed_mean_rule, x.shape[0], int(trim), int(keep)), x, block_d
     )
 
 
 def average_nan_columns(x, block_d=None):
     """(d,) finite-only column mean (all-non-finite column -> 0)."""
-
-    def kernel(x_ref, out_ref):
-        v = x_ref[:]
-        finite = jnp.isfinite(v)  # NaN-padded rows count for nothing
-        total = jnp.sum(jnp.where(finite, v, 0.0), axis=0)
-        count = jnp.sum(finite.astype(jnp.float32), axis=0)
-        _store_row(out_ref, jnp.where(count > 0, total / jnp.maximum(count, 1.0), 0.0))
-
-    return _coordinate_call("average_nan_columns", kernel, x, block_d)
+    return _coordinate_call("average_nan_columns", _average_nan_rule, x, block_d)
 
 
 # --------------------------------------------------------------------------- #
@@ -283,23 +321,112 @@ def _dist_gram_kernel(xa_ref, xb_ref, out_ref):
     out_ref[:] += sqa + jnp.transpose(sqb) - 2.0 * gram
 
 
+#: Row groups (8 sublanes each) that one broadcast comparator row meets in a
+#: pass of the pair kernel — 8 comparators x 4 groups = 32 accumulators held
+#: in registers — and 128-lane chunks per iteration of its loop (Mosaic
+#: unrolls a ``fori_loop`` whole or not at all, so the body repeats itself).
+#: (32, 25,557,032) on a v5e, PR 30: 17.5 ms at 1 chunk, 10.4 at 4, 8.8 at
+#: 16, 8.5 at 32; the groups move it by 1 %; 240 vector operations a chunk
+#: are 8.0 ms.
+PAIR_GROUPS = 4
+PAIR_UNROLL = 16
+
+
+def _pick_block_pairs(rows, d, vmem_budget=1 << 21):
+    """Pair-kernel block: the (rows, blk) slab is all that is held (twice: the
+    pipeline double-buffers it), so 2 MB buy 16,384 columns at 32 rows where
+    the tiled form's tile x tile x blk tensor buys 1,024 for 4 MB."""
+    return _clamp_block(vmem_budget // (rows * 4), d, PAIR_MAX_BLOCK)
+
+
+def _dist_pairs_kernel(x_ref, out_ref, acc_ref):
+    """All pairs of the rows of one (rows, blk) column block per grid step,
+    from ONE read of it, each unordered pair of 8-row groups once: comparator
+    row j, broadcast over the sublanes, meets the groups that hold rows >= j
+    (the idiom of ``_ranks``).  Squared differences add up chunk by 128-lane
+    chunk into (8, LANE) lane partials, ``acc_ref[j, s, :]`` for the pair
+    (j, s); the one cross-lane sum and the transpose into the lower triangle
+    happen at the last step."""
+    rows, blk = x_ref.shape
+    groups, chunks = rows // 8, blk // LANE
+    k = pl.program_id(0)
+
+    @pl.when(k == 0)
+    def _():
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+
+    for gj in range(groups):
+        for g0 in range(gj, groups, PAIR_GROUPS):
+            met = range(g0, min(g0 + PAIR_GROUPS, groups))
+
+            def chunk(off, accs):
+                xs = [x_ref[8 * g:8 * g + 8, pl.ds(off, LANE)] for g in met]
+                out = []
+                for jj in range(8):
+                    xj = x_ref[pl.ds(8 * gj + jj, 1), pl.ds(off, LANE)]
+                    for i, xg in enumerate(xs):
+                        diff = xg - xj
+                        out.append(accs[jj * len(met) + i] + diff * diff)
+                return tuple(out)
+
+            def several(c, accs):
+                for u in range(PAIR_UNROLL):
+                    accs = chunk(pl.multiple_of((c * PAIR_UNROLL + u) * LANE, LANE), accs)
+                return accs
+
+            accs = (jnp.zeros((8, LANE), jnp.float32),) * (8 * len(met))
+            if chunks >= PAIR_UNROLL:
+                accs = jax.lax.fori_loop(0, chunks // PAIR_UNROLL, several, accs)
+            for c in range(chunks - chunks % PAIR_UNROLL, chunks):
+                accs = chunk(c * LANE, accs)
+            for jj in range(8):
+                for i, g in enumerate(met):
+                    acc_ref[8 * gj + jj, 8 * g:8 * g + 8, :] += accs[jj * len(met) + i]
+
+    @pl.when(k == pl.num_programs(0) - 1)
+    def _():
+        upper = jnp.sum(acc_ref[:], axis=-1)  # [j, s] filled where s // 8 >= j // 8
+        row = jax.lax.broadcasted_iota(jnp.int32, upper.shape, 0) // 8
+        col = jax.lax.broadcasted_iota(jnp.int32, upper.shape, 1) // 8
+        out_ref[:] = jnp.where(col >= row, upper, upper.T)
+
+
 def pairwise_sq_distances(x, block_d=None, use_mxu=None, row_tile=None):
     """(n, n) all-pairs squared L2 distances of the rows of (n, d).
 
-    ``use_mxu=None`` picks the difference-form (exact) when the per-block
-    tile²·blk intermediate is cheap and the Gram-form (one MXU matmul per
-    tile pair) otherwise.  NaN rows yield NaN entries (callers map to +inf),
-    matching the jnp tier.  Rows are processed in ``row_tile``-sized tiles
-    (default: one tile up to ROW_TILE rows, ROW_TILE beyond) so the VMEM
-    footprint is independent of the worker count.
+    ``use_mxu=None`` picks the difference-form (exact) up to n = 64 and the
+    Gram-form (one MXU matmul per tile pair) beyond.  NaN rows yield NaN
+    entries (callers map to +inf), matching the jnp tier.  The difference
+    form of up to ``PAIR_ROWS_MAX`` rows runs as one row tile over the rows
+    as they are (``_dist_pairs_kernel``: no padded copy, one read); with a
+    forced ``row_tile``, more rows or the Gram form, rows are processed in
+    ``row_tile``-sized tiles (default: one tile up to ROW_TILE rows, ROW_TILE
+    beyond) so the VMEM footprint is independent of the worker count.
     """
     n, d = x.shape
     rows = n + (-n) % 8  # sublane-padded row count
-    tile = row_tile or (rows if rows <= ROW_TILE else ROW_TILE)
-    tile = max(8, tile + (-tile) % 8)
     if use_mxu is None:
         use_mxu = n > 64
     x = x.astype(jnp.float32)
+    if not use_mxu and row_tile is None and rows <= PAIR_ROWS_MAX:
+        blk = block_d or _pick_block_pairs(rows, d)
+        xp, whole = _whole_blocks(x, blk, 0.0)  # zero rows are sliced off below
+        out = pl.pallas_call(
+            _dist_pairs_kernel,
+            grid=(whole // blk,),
+            in_specs=[pl.BlockSpec((rows, blk), lambda k: (0, k), memory_space=pltpu.VMEM)],
+            out_specs=pl.BlockSpec((rows, rows), lambda k: (0, 0), memory_space=pltpu.VMEM),
+            out_shape=jax.ShapeDtypeStruct((rows, rows), jnp.float32),
+            scratch_shapes=[pltpu.VMEM((rows, rows, LANE), jnp.float32)],
+            interpret=_interpret(),
+            name="pairwise_sq_distances",
+        )(xp)[:n, :n]
+        if whole < xp.shape[1]:  # fewer than a block of columns are left over
+            diff = x[:, None, whole:] - x[None, :, whole:]
+            out = out + jnp.sum(diff * diff, axis=-1)
+        return out
+    tile = row_tile or (rows if rows <= ROW_TILE else ROW_TILE)
+    tile = max(8, tile + (-tile) % 8)
     if use_mxu:
         kernel = _dist_gram_kernel
         blk = block_d or _pick_block_coord(tile, d)

@@ -228,14 +228,20 @@ def test_pairwise_distances_row_tiled_nan_rows(rng):
     assert np.all(np.isfinite(out[np.ix_(mask, mask)]))
 
 
-def test_pairwise_distances_tile_invariance(rng):
+@pytest.mark.parametrize("n,d", [(32, 256), (8, 129), (13, 1000), (32, 3 * 256 - 7), (64, 256 + 1)])
+def test_pairwise_distances_tile_invariance(rng, n, d):
     """The tiling is a pure blocking choice: tiled == single-tile to float
-    tolerance, both MXU and diff forms."""
-    g = make_grads(rng, 32, d=256)
+    tolerance, both MXU and diff forms — and in the diff form the single
+    tile is the pair kernel on the rows as they are, the tiled one the
+    (i, j, k) grid on rows padded to whole blocks, at ragged widths too."""
+    g = make_grads(rng, n, d=d)
     for use_mxu in (False, True):
-        one = np.asarray(pk.pairwise_sq_distances(g, use_mxu=use_mxu))
-        tiled = np.asarray(pk.pairwise_sq_distances(g, use_mxu=use_mxu, row_tile=8))
-        np.testing.assert_allclose(tiled, one, rtol=1e-5, atol=1e-4)
+        one = np.asarray(pk.pairwise_sq_distances(g, use_mxu=use_mxu, block_d=256))
+        tiled = np.asarray(pk.pairwise_sq_distances(g, use_mxu=use_mxu, block_d=256, row_tile=8))
+        # the Gram form's cancellation leaves ~1e-4 where a distance is 0
+        np.testing.assert_allclose(tiled, one, rtol=1e-5, atol=1e-3 if use_mxu else 1e-4)
+    ref = oracle._pairwise_sq_distances(g.astype(np.float64))
+    np.testing.assert_allclose(pk.pairwise_sq_distances(g, block_d=256), ref, rtol=1e-5, atol=1e-4)
 
 
 def test_ranks_rolled_loop_matches_unrolled(rng):

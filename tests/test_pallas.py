@@ -25,6 +25,9 @@ CASES = [
     dict(n=8, d=300, seed=1, nan_frac=0.1),
     dict(n=15, d=130, seed=2, nan_frac=0.0),
     dict(n=16, d=7, seed=3, nan_frac=0.2),
+    # widths off the block and off the lane, which the kernels take unpadded
+    dict(n=32, d=1000, seed=4, nan_frac=0.05),
+    dict(n=13, d=3 * 128 - 7, seed=5, nan_frac=0.1),
 ]
 
 
@@ -60,14 +63,28 @@ def test_pairwise_distances(use_mxu):
     np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
 
 
-def test_pairwise_distances_nan_row():
-    g = _rand(8, 64, 9)
-    g[3, 10] = np.nan
-    out = np.asarray(pk.pairwise_sq_distances(g, block_d=128, use_mxu=False))
-    assert np.all(np.isnan(out[3, :3])) and np.all(np.isnan(out[:3, 3]))
-    finite_mask = np.ones((8, 8), bool)
-    finite_mask[3, :] = finite_mask[:, 3] = False
-    assert np.all(np.isfinite(out[finite_mask]))
+@pytest.mark.parametrize("n,d,block_d", [(8, 64, 128), (13, 1000, 128), (32, 1000, 256)])
+@pytest.mark.parametrize("poison", ["nan-coordinate", "nan-row", "inf-row", "huge-row"])
+def test_pairwise_distances_nan_row(n, d, block_d, poison):
+    """One Byzantine row, whatever it holds, spoils its own row and column
+    of the matrix and nothing else: the difference form never mixes rows, so
+    the honest pairs stay exact."""
+    g = _rand(n, d, 9)
+    if poison == "nan-coordinate":
+        g[3, 10] = np.nan
+    elif poison == "nan-row":
+        g[3] = np.nan
+    elif poison == "inf-row":
+        g[3] = np.inf
+    else:
+        g[3] = 1e30 * np.where(np.arange(d) % 2, 1.0, -1.0)  # squares overflow float32
+    out = np.asarray(pk.pairwise_sq_distances(g, block_d=block_d, use_mxu=False))
+    honest = np.arange(n) != 3
+    assert not np.any(np.isfinite(out[3, honest])) and not np.any(np.isfinite(out[honest, 3]))
+    if poison.startswith("nan"):
+        assert np.all(np.isnan(out[3, :])) and np.all(np.isnan(out[:, 3]))
+    ref = oracle._pairwise_sq_distances(g[honest].astype(np.float64))
+    np.testing.assert_allclose(out[np.ix_(honest, honest)], ref, rtol=1e-5, atol=1e-5)
 
 
 @pytest.mark.parametrize(
@@ -138,6 +155,8 @@ TILE_CASES = [
     dict(n=9, d=256, seed=11, nan_frac=0.1),
     dict(n=8, d=129, seed=12, nan_frac=0.0),
     dict(n=3, d=384, seed=13, nan_frac=0.3),
+    dict(n=16, d=1000, seed=16, nan_frac=0.1),
+    dict(n=32, d=3 * 128 - 7, seed=17, nan_frac=0.0),
 ]
 
 
@@ -152,15 +171,29 @@ def test_coordinate_kernels_at_tile_boundaries(case):
         rtol=1e-5, atol=1e-6)
 
 
+# (n, d, block_d): the three boundary widths at n=6, then widths that are a
+# multiple neither of the block nor of the lane — blk + 1 and 3·blk − 7 at
+# blocks wide enough to run the pair kernel's chunk loop, and one at the
+# block the wrapper picks itself — at n = 8, 13, 32, 64.
+DISTANCE_WIDTHS = [
+    (6, 128, 128), (6, 129, 128), (6, 256, 128),
+    (8, 129, 128), (13, 1000, 128), (32, 1000, 256), (64, 3 * 128 - 7, 128),
+    (32, 2048 + 1, 2048), (8, 3 * 4096 - 7, 4096), (16, pk.PAIR_MAX_BLOCK + 1, None),
+]
+
+
 @pytest.mark.parametrize("use_mxu", [False, True])
-@pytest.mark.parametrize("d", [128, 129, 256])
-def test_pairwise_distances_at_tile_boundaries(use_mxu, d):
-    g = _rand(6, d, 14)
-    out = np.array(pk.pairwise_sq_distances(g, block_d=128, use_mxu=use_mxu))
+@pytest.mark.parametrize("n,d,block_d", DISTANCE_WIDTHS)
+def test_pairwise_distances_at_tile_boundaries(use_mxu, n, d, block_d):
+    g = _rand(n, d, 14)
+    out = np.array(pk.pairwise_sq_distances(g, block_d=block_d, use_mxu=use_mxu))
     ref = oracle._pairwise_sq_distances(g.astype(np.float64))
-    np.fill_diagonal(out, 0.0)
+    if not use_mxu:  # exact: each pair once, mirrored; a row against itself is 0
+        np.testing.assert_array_equal(out, out.T)
+        np.testing.assert_array_equal(np.diag(out), 0.0)
+    np.fill_diagonal(out, 0.0)  # oracle pins the diagonal; the Gram form leaves ~0
     tol = 1e-4 if use_mxu else 1e-5
-    np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
+    np.testing.assert_allclose(out, ref, rtol=tol, atol=tol * np.sqrt(d / 128))
 
 
 def test_pallas_krum_excludes_fully_nan_row_like_jnp():
