@@ -182,18 +182,27 @@ def rope(x, positions, theta):
     return jnp.concatenate([rx1[..., None], rx2[..., None]], axis=-1).reshape(b, s, h, dh).astype(x.dtype)
 
 
+def online_softmax_step(scores, weigh, num, den, mx):
+    """Fold one block of keys into a running softmax: ``scores`` (..., q, k)
+    are float32 and already masked with ``_NEG``, ``weigh(p)`` gives the
+    block's (..., q, d) sum of values under the weights ``p``.  Shared with
+    models/sdar.py, whose heads are grouped and whose mask is not causal."""
+    new_mx = jnp.maximum(mx, scores.max(axis=-1))
+    corr = jnp.exp(mx - new_mx)
+    p = jnp.exp(scores - new_mx[..., None])
+    num = num * corr[..., None] + weigh(p)
+    den = den * corr + p.sum(axis=-1)
+    return num, den, new_mx
+
+
 def _attend_block(q, k, v, q_pos, k_pos, num, den, mx):
     """One online-softmax accumulation step of blockwise causal attention."""
     scale = 1.0 / math.sqrt(q.shape[-1])
     scores = jnp.einsum("bqhd,bkhd->bhqk", q, k).astype(jnp.float32) * scale
     mask = q_pos[:, None] >= k_pos[None, :]
     scores = jnp.where(mask[None, None], scores, _NEG)
-    new_mx = jnp.maximum(mx, scores.max(axis=-1))
-    corr = jnp.exp(mx - new_mx)
-    p = jnp.exp(scores - new_mx[..., None])
-    num = num * corr[..., None] + jnp.einsum("bhqk,bkhd->bhqd", p, v.astype(jnp.float32))
-    den = den * corr + p.sum(axis=-1)
-    return num, den, new_mx
+    return online_softmax_step(
+        scores, lambda p: jnp.einsum("bhqk,bkhd->bhqd", p, v.astype(jnp.float32)), num, den, mx)
 
 
 def ring_attention(q, k, v, positions, axis):

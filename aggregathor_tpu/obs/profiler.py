@@ -28,6 +28,7 @@ zero-recompile discipline):
 
 import collections
 import contextlib
+import functools
 import re
 import threading
 
@@ -58,13 +59,22 @@ _ELEMENT = re.compile(r" get-tuple-element\(.*\), index=(\d+)")
 _BODY = re.compile(r" while\(.*\bbody=%?([\w.\-]+)")
 _OPERAND = re.compile(r"%([\w.\-]+)")
 _OP_NAME = re.compile(r'\bop_name="([^"]*)"')
-_PHASE = re.compile(r"(?:^|[/(])%s([A-Za-z_]+)(?=[/)]|$)" % re.escape(PHASE_PREFIX))
+#: what a model's own scopes inside its loss start with (models/sdar.py:
+#: ``model.attention``, ``model.experts``, ...).  They nest under ``step.grad``
+#: and ``step.augment`` and are a second cut of the same program: the table of
+#: one prefix takes no notice of the other's scopes
+MODEL_PREFIX = "model."
 
 
-def phase_of(op_name):
-    """The innermost step phase on an ``op_name`` path, or None:
+@functools.lru_cache(maxsize=None)
+def _scope(prefix):
+    return re.compile(r"(?:^|[/(])%s([A-Za-z_]+)(?=[/)]|$)" % re.escape(prefix))
+
+
+def phase_of(op_name, prefix=PHASE_PREFIX):
+    """The innermost scope of ``prefix`` on an ``op_name`` path, or None:
     ``jit(many)/while/body/step.grad/vmap(jvp(conv))/mul`` -> ``grad``."""
-    found = _PHASE.findall(op_name or "")
+    found = _scope(prefix).findall(op_name or "")
     return found[-1] if found else None
 
 
@@ -85,9 +95,11 @@ def _computations(hlo_text):
     return computations
 
 
-def phase_table(hlo_text):
+def phase_table(hlo_text, prefix=PHASE_PREFIX):
     """``({instruction name: phase or None}, notes)`` of a compiled program's
-    text (``compiled.as_text()``, ``TracedCallable.compiled_text()``).
+    text (``compiled.as_text()``, ``TracedCallable.compiled_text()``), by the
+    scopes that start with ``prefix``: the step's phases, or with
+    ``MODEL_PREFIX`` the parts a model names inside its loss.
 
     An instruction's phase is the innermost ``step.<phase>`` scope of its
     ``metadata={op_name="..."}`` (parallel/engine.py ``phase``); a profiler
@@ -119,7 +131,7 @@ def phase_table(hlo_text):
 
     def own_phase(line):
         found = _OP_NAME.search(line)
-        return phase_of(found.group(1)) if found else None
+        return phase_of(found.group(1), prefix) if found else None
 
     table, soft, operands, bare, moved, plumbing = {}, [], {}, set(), set(), set()
     home, caller = {}, {}  # instruction -> its computation -> the instruction that calls it
@@ -169,7 +181,7 @@ def phase_table(hlo_text):
             "compilation cache (JAX_COMPILATION_CACHE_DIR, <checkout>/.jax_cache) that a "
             "build without the scopes filled - the cache key leaves metadata out, so the "
             "cached program is that build's; clear the directory or point at a fresh one"
-            % (len(table), PHASE_PREFIX))
+            % (len(table), prefix))
 
     users = collections.defaultdict(list)
     for name, feeds in operands.items():

@@ -29,13 +29,12 @@ index; a selected non-finite value is returned *as-is* (the original
 NaN/inf poisons that coordinate, same identity in every tier).
 
 Tile alignment (Mosaic lowers f32 in (8, 128) sublane x lane tiles): the
-host wrappers pad the worker dim to a multiple of 8 and the coordinate
-kernels write full (8, blk) output tiles — no sub-tile block shapes reach
-the compiler.  Worker padding is provably neutral: a padded row is all-NaN,
-keys +inf at the highest indices, and its rank is exactly n (every real row
-precedes it), strictly above every selection threshold (n//2 < n, beta <=
-n); ``average_nan_columns`` ignores non-finite rows by construction, and
-the distance wrappers slice padded rows/columns off before returning.
+distance wrappers pad the worker dim to a multiple of 8 (zero rows, sliced
+off before returning).  The coordinate kernels take the rows as they lie,
+whatever their count — the block's row dimension is the array's — and write
+a full (8, blk) output tile where n is a multiple of 8, one row where it is
+not (``_coordinate_call`` says what a padded copy and a broadcast row cost
+at 4 rows x 305 M columns).  Rank thresholds use n, the rows there are.
 
 Ragged widths: the coordinate kernels and the pair kernel take the rows as
 wide as they are, on a grid over their ``d // blk`` whole blocks — no padded
@@ -222,20 +221,37 @@ def _average_nan_rule(x):
     return jnp.where(count > 0, total / jnp.maximum(count, 1.0), 0.0)
 
 
+#: Widest column block of a coordinate kernel whose rows are no multiple of the
+#: 8 sublanes: few rows make a block a thin slab, and at the widths that send
+#: few rows here (4 workers x 305 M coordinates, grid cell
+#: sdar30b_median_blockdiff) ``MAX_BLOCK`` columns would make 300,000 grid
+#: steps of 16 KB each.
+THIN_MAX_BLOCK = 16384
+
+
 def _coordinate_call(name, rule, x, block_d=None):
     """Run the coordinate rule ``rule`` ((n, w) slab -> (w,) row) over the
     columns of ``x``: as a Pallas kernel over the whole blocks of the rows as
     they are, and as jnp on the fewer-than-a-block columns left over.
 
     ``name`` is the public function's: what the ``pallas_call`` is called in
-    a compiled program and a device trace.  Rank thresholds inside ``rule``
-    use the REAL n; the slab rows are padded to the f32 sublane multiple with
-    NaN (neutral, module docstring).
+    a compiled program and a device trace.  The block is all n rows as they
+    lie (a block dimension may equal the array's) and the rule runs on that
+    (n, blk) slab, whatever n: padding rows to the sublane multiple in front of
+    the kernel would be a copy of the whole matrix (9.8 GB at (4, 305 M)).
+    Where n is a multiple of 8 the result goes out as a full (8, blk) tile, as
+    it always has (``_store_row``); where it is not, as the one row it is —
+    broadcast over 8 sublanes it would be 8 x the result, 9.8 GB more there.
     """
     n, d = x.shape
-    rows = n + (-n) % 8  # the slab the kernel actually holds is padded
-    blk = block_d or _pick_block_coord(rows, d)
-    xp, whole = _whole_blocks(x.astype(jnp.float32), blk, jnp.nan)
+    thin = n % 8 != 0
+    blk = block_d or (_clamp_block((1 << 21) // (n * 4 * 8), d, widest=THIN_MAX_BLOCK)
+                      if thin else _pick_block_coord(n, d))
+    xp = x.astype(jnp.float32)
+    if d < blk:  # only rows narrower than one block are padded up to it
+        xp = _pad_axis(xp, 1, blk)
+    whole = xp.shape[1] // blk * blk
+    out_rows = 1 if thin else 8
 
     def kernel(x_ref, out_ref):
         _store_row(out_ref, rule(x_ref[:]))
@@ -243,9 +259,9 @@ def _coordinate_call(name, rule, x, block_d=None):
     out = pl.pallas_call(
         kernel,
         grid=(whole // blk,),
-        in_specs=[pl.BlockSpec((rows, blk), lambda i: (0, i), memory_space=pltpu.VMEM)],
-        out_specs=pl.BlockSpec((8, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
-        out_shape=jax.ShapeDtypeStruct((8, whole), jnp.float32),
+        in_specs=[pl.BlockSpec((n, blk), lambda i: (0, i), memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((out_rows, blk), lambda i: (0, i), memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((out_rows, whole), jnp.float32),
         interpret=_interpret(),
         name=name,
     )(xp)

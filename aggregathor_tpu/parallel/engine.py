@@ -490,21 +490,26 @@ class RobustEngine:
     # ------------------------------------------------------------------ #
 
     def _worker_gradients(self, params, batch_shard, loss_fn):
-        """vmap the local k workers' loss/grad; returns ((k,) losses, (k, d) grads, flatmap)."""
+        """vmap the local k workers' loss/grad; returns ((k,) losses, (k, d)
+        grads, flatmap, counters).  A loss marked ``has_aux`` returns ``(loss,
+        counters)``, a dict of scalars the model counts as it runs
+        (models/sdar.py); ``counters`` is then that dict with (k,) leaves,
+        else None."""
+        has_aux = getattr(loss_fn, "has_aux", False)
 
         def one(worker_batch):
-            loss, grads = jax.value_and_grad(loss_fn)(params, worker_batch)
-            return loss, grads
+            return jax.value_and_grad(loss_fn, has_aux=has_aux)(params, worker_batch)
 
         with phase("grad"):
             losses, grads = jax.vmap(one)(batch_shard)
+        losses, counters = losses if has_aux else (losses, None)
         k = self.workers_per_device
         leaves = jax.tree_util.tree_leaves(grads)
         with phase("flatten"):
             gvecs = jnp.concatenate(
                 [leaf.reshape(k, -1).astype(jnp.float32) for leaf in leaves], axis=1)
         flatmap = FlatMap(jax.tree_util.tree_map(lambda g: g[0], grads))
-        return losses, gvecs, flatmap
+        return losses, gvecs, flatmap, counters
 
     def _perturb_local(self, gvecs, key, carry=None, ridx=None, ef=None):
         """Apply local attack + wire codec + lossy link + chaos regime +
@@ -1049,7 +1054,8 @@ class RobustEngine:
 
                 with phase("augment"):
                     batch = jax.vmap(aug_one)(batch, jnp.arange(k))
-            losses, gvecs, flatmap = self._worker_gradients(state.params, batch, loss_fn)
+            losses, gvecs, flatmap, counters = self._worker_gradients(
+                state.params, batch, loss_fn)
             if self.codec is not None:
                 # the codec budget is validated at the first trace, which
                 # is also every guardian-escalation rebuild
@@ -1139,7 +1145,7 @@ class RobustEngine:
                         name: gather_workers(value)
                         for name, value in secure_info.items()
                     }
-                return self._finalize_step(
+                new_state, metrics = self._finalize_step(
                     state, params=params, opt_state=opt_state, new_carry=new_carry,
                     new_momentum=new_momentum, new_momentum_steps=new_momentum_steps,
                     total_loss=total_loss, update_norm=jnp.linalg.norm(agg),
@@ -1147,6 +1153,12 @@ class RobustEngine:
                     participation=participation, secure_metrics=secure_metrics,
                     ridx=ridx, new_ef=new_ef,
                 )
+                if counters is not None:
+                    # the model's own counters, one value a worker, worker-major
+                    metrics["model_counters"] = jax.tree.map(
+                        lambda c: (jax.lax.all_gather(c, worker_axis).reshape(self.nb_workers)
+                                   if W > 1 else c), counters)
+                return new_state, metrics
 
         return body
 
