@@ -15,13 +15,21 @@ aggregators/deprecated_native/native.cpp:678-747).  Two hot shapes:
   blk tensor per step) and the MXU Gram form (``|a|² + |b|² − 2ab`` per block,
   median-centered against catastrophic cancellation — the same math the
   sharded engine psums, parallel/engine.py).
-- **Coordinate-wise selection** (median / averaged-median, Bulyan phase 3) —
-  the reference's per-coordinate ``nth_element`` (native.cpp:678-747) is
-  control flow, which doesn't vectorize on TPU; here selection is
-  reformulated as *rank computation*: ``rank(i) = #{j : key_j < key_i}``
-  (ties to the lower index) is n fused VPU compare-accumulate passes over
-  the whole block, and "the median" is a masked sum over rows — no sort, no
-  gather, O(n²) vector ops per coordinate slab (SURVEY.md §7 hard part (a)).
+- **Coordinate-wise selection** (median / averaged-median / trimmed mean,
+  Bulyan phase 3) — the reference's per-coordinate ``nth_element``
+  (native.cpp:678-747) is control flow, which doesn't vectorize on TPU; here
+  selection is reformulated as *rank computation*: ``rank(i) = #{j : key_j <
+  key_i}`` (ties to the lower index), and "the median" is a masked sum over
+  rows — no sort, no gather (SURVEY.md §7 hard part (a)).  One count in two
+  forms, chosen from the row count (``_coordinate_call``).  Up to
+  ``PLANE_ROWS_MAX`` = 32 rows every row is a *plane*: 1,024 of its columns
+  fill one (8, 128) vreg, read out of the (n, blk) block as it lies by one
+  strided load, and each unordered pair of rows is compared once —
+  n·(n−1)/2 compares, nothing crossing sublanes, a vreg full whatever n
+  (``_plane_call``; ``..._planes`` in the kernel's name).  Beyond, the rows
+  stay on the sublanes of an (n, blk) slab and the ranks are n broadcast
+  compare-accumulate passes over it (unrolled to 64 rows, a rolled loop
+  above).
 
 NaN conventions are identical to the jnp tier and the numpy oracle: a
 non-finite value keys as +inf (sorts last); ties break by lower worker
@@ -31,17 +39,19 @@ NaN/inf poisons that coordinate, same identity in every tier).
 Tile alignment (Mosaic lowers f32 in (8, 128) sublane x lane tiles): the
 distance wrappers pad the worker dim to a multiple of 8 (zero rows, sliced
 off before returning).  The coordinate kernels take the rows as they lie,
-whatever their count — the block's row dimension is the array's — and write
-a full (8, blk) output tile where n is a multiple of 8, one row where it is
-not (``_coordinate_call`` says what a padded copy and a broadcast row cost
-at 4 rows x 305 M columns).  Rank thresholds use n, the rows there are.
+whatever their count — the block's row dimension is the array's.  The plane
+form writes its one row of results dense, as the (d / 128, 128) array whose
+flattening is the row; the slab form (more than 32 rows, and
+``average_nan_columns``) writes a full (8, blk) tile where n is a multiple of
+8, one row where it is not.  Rank thresholds use n, the rows there are.
 
 Ragged widths: the coordinate kernels and the pair kernel take the rows as
 wide as they are, on a grid over their ``d // blk`` whole blocks — no padded
 copy of the matrix (3.3 GB a step in ResNet-50's Bulyan until PR 30).  The
 fewer-than-a-block columns left over go through the same arithmetic as plain
-jnp on a slice, added to the distances or appended to the coordinate rule's
-row.  Only rows narrower than one block are padded up to it.  (A grid of
+jnp on a slice, added to the distances, appended to the slab form's row, or
+written in place behind the plane form's blocks.  Only rows narrower than one
+block are padded up to it.  (A grid of
 ``ceil(d / blk)`` blocks whose last one reads past the edge, masked in the
 kernel, ran as fast but cost the one-chip ResNet-50 step 47 s more to start
 in every process after a machine's first; PERF.md, PR 30.)
@@ -142,17 +152,36 @@ RANK_UNROLL_MAX = 64
 
 
 def _ranks(key, n):
-    """rank[i, :] = #{j : key_j < key_i, ties to lower j}, per coordinate.
+    """rank[i] = #{j : key_j < key_i, ties to lower j}, per coordinate.
 
-    n VPU passes of compare+accumulate over the (n, blk) slab; memory stays
-    O(n·blk).  Statically unrolled up to ``RANK_UNROLL_MAX`` comparators, a
+    Two forms of one count, chosen by what ``key`` is.  A stack of PLANES
+    ((n, sublanes, lanes): row i is ``key[i]``, whole vregs of one row's
+    columns) takes each unordered pair i < j ONCE: ``key_j < key_i`` adds one
+    to rank i, and its contrary one to rank j — the tie goes to the lower index
+    by the choice of the compare, and rank j starts at j, the count of its
+    contraries, so a pair is one compare, one convert, one add, one subtract,
+    n·(n−1)/2 of them and nothing crossing sublanes (plain operations on planes
+    taken out once: 120 pairs of ``jnp.where`` and ``key[j]`` cost
+    ``resnet50_bulyan_1chip`` 2 s of its first dispatch in every process).  An
+    (n, blk) SLAB (rows on the sublanes) takes n VPU passes of
+    compare+accumulate over the whole slab, comparator row j broadcast over the
+    sublanes: statically unrolled up to ``RANK_UNROLL_MAX`` comparators, a
     ``fori_loop`` beyond (identical selections: the loop body is the same
     compare+accumulate either way).  The rolled loop picks comparator row j
-    with a masked max over the rows — Mosaic lowers no ``dynamic_slice`` of
-    a VALUE (found on the chip at PR 21: "Unimplemented primitive in Pallas
-    TPU lowering: dynamic_slice"), and ``key`` holds no NaN (non-finite is
-    keyed +inf), so the max over one unmasked row is exactly that row.
+    with a masked max over the rows — Mosaic lowers no ``dynamic_slice`` of a
+    VALUE (found on the chip at PR 21: "Unimplemented primitive in Pallas TPU
+    lowering: dynamic_slice"), and ``key`` holds no NaN (non-finite is keyed
+    +inf), so the max over one unmasked row is exactly that row.
     """
+    if key.ndim == 3:
+        planes = [key[i] for i in range(n)]
+        ranks = [jnp.full(key.shape[1:], i, jnp.int32) for i in range(n)]
+        for i in range(n):
+            for j in range(i + 1, n):
+                below = (planes[j] < planes[i]).astype(jnp.int32)
+                ranks[i] += below
+                ranks[j] -= below
+        return jnp.stack(ranks)
     row = jax.lax.broadcasted_iota(jnp.int32, key.shape, 0)
     if n <= RANK_UNROLL_MAX:
         ranks = jnp.zeros(key.shape, jnp.int32)
@@ -169,7 +198,8 @@ def _ranks(key, n):
 
 
 def _select_rank(x, ranks, r):
-    """Per coordinate, the value whose rank equals r (masked sum over rows)."""
+    """Per coordinate, the value whose rank equals r (masked sum over rows:
+    across the sublanes of a slab, vreg by vreg over a stack of planes)."""
     return jnp.sum(jnp.where(ranks == r, x, 0.0), axis=0)
 
 
@@ -187,9 +217,10 @@ def _store_row(out_ref, row):
     out_ref[:] = jnp.broadcast_to(row[None, :], out_ref.shape)
 
 
-# Each rule maps an (n, w) slab of values to its (w,) row: inside the kernel
-# on a block read from its ref, and as plain jnp on the few columns past the
-# last whole block (``_coordinate_call``).
+# Each rule maps the n rows to their one row of results, whatever lies behind
+# the row axis: an (n, w) slab gives (w,), a stack of planes (n, s, l) gives
+# (s, l) — inside the kernel on what it reads from its ref, and as plain jnp on
+# the few columns past the last whole block (``_coordinate_call``).
 
 def _median_rule(n, x):
     return _select_rank(x, _ranks(_inf_key(x), n), n // 2)
@@ -197,7 +228,7 @@ def _median_rule(n, x):
 
 def _averaged_median_rule(n, beta, x):
     med = _select_rank(x, _ranks(_inf_key(x), n), n // 2)
-    dev_ranks = _ranks(_inf_key(jnp.abs(x - med[None, :])), n)
+    dev_ranks = _ranks(_inf_key(jnp.abs(x - med[None])), n)
     chosen = jnp.where(dev_ranks < beta, x, 0.0)
     return jnp.sum(chosen, axis=0) / float(beta)
 
@@ -221,29 +252,88 @@ def _average_nan_rule(x):
     return jnp.where(count > 0, total / jnp.maximum(count, 1.0), 0.0)
 
 
-#: Widest column block of a coordinate kernel whose rows are no multiple of the
-#: 8 sublanes: few rows make a block a thin slab, and at the widths that send
-#: few rows here (4 workers x 305 M coordinates, grid cell
-#: sdar30b_median_blockdiff) ``MAX_BLOCK`` columns would make 300,000 grid
-#: steps of 16 KB each.
+#: Widest column block of a slab-form coordinate kernel whose rows are no
+#: multiple of the 8 sublanes: few rows make a block a thin slab.
 THIN_MAX_BLOCK = 16384
 
+#: The plane form of the rank rules.  ``PLANE`` columns of one row fill one
+#: (8, LANE) vreg; the kernel reads them out of the (n, blk) block as it lies
+#: with one strided load a row (``x_ref[j, pl.ds(off, PLANE)]``: the sublanes
+#: of the vreg come from 8 successive tiles of the block) and ranks the n planes
+#: against each other, ``_ranks``' pairs-once form.  It serves up to
+#: ``PLANE_ROWS_MAX`` rows — the pairs are unrolled, 496 at 32 rows, where the
+#: keys and ranks already spill — in blocks of ``PLANE_BLOCK_BYTES`` of rows
+#: (double-buffered by the pipeline, beside a result of 1/n that size).
+PLANE = 8 * LANE
+PLANE_ROWS_MAX = 32
+PLANE_BLOCK_BYTES = 1 << 21
 
-def _coordinate_call(name, rule, x, block_d=None):
-    """Run the coordinate rule ``rule`` ((n, w) slab -> (w,) row) over the
+
+def _plane_call(name, rule, x, block_d=None):
+    """The rank rule ``rule`` over the columns of ``x`` (n <= ``PLANE_ROWS_MAX``
+    rows, as they lie) with every row a plane: per ``PLANE`` columns the kernel
+    stacks n vregs, one a row, and the rule runs on that (n, 8, LANE) stack —
+    nothing crosses sublanes, whatever n.  The result leaves dense, as the
+    (d / LANE, LANE) array whose flattening is the row: no broadcast tile.  The
+    kernel fills the whole blocks; the fewer-than-a-block columns past them go
+    through the same rule as jnp on the slice (as a slab: its n passes trace in
+    a sixth of the time of the unrolled pairs, and every process traces the
+    step) and into the same array in place — no block is read past the edge
+    (PR 30), and no copy of the row joins the two (3 ms at 305 M columns)."""
+    n, d = x.shape
+    xp = x.astype(jnp.float32)
+    if d < PLANE:  # only rows narrower than one plane are padded up to it
+        xp = _pad_axis(xp, 1, PLANE)
+    width = xp.shape[1]
+    blk = block_d or PLANE_BLOCK_BYTES // (4 * n)
+    blk = max(PLANE, min(blk, width) // PLANE * PLANE)
+    whole = width // blk * blk
+
+    def kernel(x_ref, out_ref):
+        def piece(p, carry):
+            off = pl.multiple_of(p * PLANE, PLANE)
+            planes = [x_ref[j, pl.ds(off, PLANE)].reshape(8, LANE) for j in range(n)]
+            out_ref[pl.ds(pl.multiple_of(p * 8, 8), 8), :] = rule(jnp.stack(planes))
+            return carry
+
+        jax.lax.fori_loop(0, blk // PLANE, piece, 0)
+
+    out = pl.pallas_call(
+        kernel,
+        grid=(whole // blk,),
+        in_specs=[pl.BlockSpec((n, blk), lambda i: (0, i), memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((blk // LANE, LANE), lambda i: (i, 0), memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((-(-width // LANE), LANE), jnp.float32),
+        interpret=_interpret(),
+        name=name,
+    )(xp)
+    if whole < width:
+        rest = _pad_axis(rule(xp[:, whole:]), 0, LANE)
+        out = jax.lax.dynamic_update_slice(out, rest.reshape(-1, LANE), (whole // LANE, 0))
+    return out.reshape(-1)[:d]
+
+
+def _coordinate_call(name, rule, x, block_d=None, ranked=True):
+    """Run the coordinate rule ``rule`` (the n rows -> their one row) over the
     columns of ``x``: as a Pallas kernel over the whole blocks of the rows as
     they are, and as jnp on the fewer-than-a-block columns left over.
 
     ``name`` is the public function's: what the ``pallas_call`` is called in
-    a compiled program and a device trace.  The block is all n rows as they
-    lie (a block dimension may equal the array's) and the rule runs on that
-    (n, blk) slab, whatever n: padding rows to the sublane multiple in front of
-    the kernel would be a copy of the whole matrix (9.8 GB at (4, 305 M)).
-    Where n is a multiple of 8 the result goes out as a full (8, blk) tile, as
-    it always has (``_store_row``); where it is not, as the one row it is —
-    broadcast over 8 sublanes it would be 8 x the result, 9.8 GB more there.
+    a compiled program and a device trace, with ``_planes`` behind it where
+    the plane form ran.  One algorithm in two forms, chosen from the row count:
+    a rank rule (``ranked``) of up to ``PLANE_ROWS_MAX`` rows runs on planes
+    (``_plane_call``); more rows, and the rule without ranks, run on the slab.
+
+    The slab's block is all n rows as they lie (a block dimension may equal the
+    array's) and the rule runs on that (n, blk) slab, whatever n: padding rows
+    to the sublane multiple in front of the kernel would be a copy of the whole
+    matrix.  Where n is a multiple of 8 the result goes out as a full (8, blk)
+    tile, as it always has (``_store_row``); where it is not, as the one row
+    it is.
     """
     n, d = x.shape
+    if ranked and n <= PLANE_ROWS_MAX:
+        return _plane_call(name + "_planes", rule, x, block_d)
     thin = n % 8 != 0
     blk = block_d or (_clamp_block((1 << 21) // (n * 4 * 8), d, widest=THIN_MAX_BLOCK)
                       if thin else _pick_block_coord(n, d))
@@ -296,7 +386,7 @@ def coordinate_trimmed_mean(x, trim, keep, block_d=None):
 
 def average_nan_columns(x, block_d=None):
     """(d,) finite-only column mean (all-non-finite column -> 0)."""
-    return _coordinate_call("average_nan_columns", _average_nan_rule, x, block_d)
+    return _coordinate_call("average_nan_columns", _average_nan_rule, x, block_d, ranked=False)
 
 
 # --------------------------------------------------------------------------- #

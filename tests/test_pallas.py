@@ -12,11 +12,21 @@ from aggregathor_tpu.gars import oracle
 from aggregathor_tpu.ops import pallas_kernels as pk
 
 
-def _rand(n, d, seed, nan_frac=0.0):
+def _rand(n, d, seed, nan_frac=0.0, block=128, edges=False):
+    """``block`` is the test's ``block_d``, not the rows'.  ``edges`` plants,
+    at both ends of the rows (the first block and the leftover columns): a
+    column of ties, one tied over half its rows, one holding NaN, +inf and
+    -inf, and one with no finite value."""
     rng = np.random.default_rng(seed)
     g = rng.normal(size=(n, d)).astype(np.float32)
     if nan_frac:
         g[rng.random(size=g.shape) < nan_frac] = np.nan
+    if edges:
+        for first in (1, d - 5):
+            g[:, first] = 0.5
+            g[: n // 2 + 1, first + 1] = -0.25
+            g[:3, first + 2] = [np.nan, np.inf, -np.inf][:n]
+            g[:, first + 3] = np.resize([np.nan, np.inf, -np.inf], n)
     return g
 
 
@@ -30,26 +40,46 @@ CASES = [
     dict(n=13, d=3 * 128 - 7, seed=5, nan_frac=0.1),
 ]
 
+# The plane form (up to ``pk.PLANE_ROWS_MAX`` rows): widths of blk - 1, blk,
+# blk + 1 and 1,024 k + 40, with the columns ``_rand`` plants at both ends; and
+# the bound on n at which the form changes, one case each side.
+PLANE_CASES = [
+    dict(n=3, d=1023, seed=20, nan_frac=0.1, block=1024, edges=True),
+    dict(n=4, d=1024, seed=21, nan_frac=0.0, block=1024, edges=True),
+    dict(n=4, d=2049, seed=22, nan_frac=0.2, block=2048, edges=True),
+    dict(n=4, d=3 * 1024 + 40, seed=23, nan_frac=0.1, block=1024, edges=True),
+    dict(n=5, d=1025, seed=24, nan_frac=0.1, block=1024, edges=True),
+    dict(n=8, d=2047, seed=25, nan_frac=0.1, block=2048, edges=True),
+    dict(n=16, d=2048 + 40, seed=26, nan_frac=0.1, block=2048, edges=True),
+    dict(n=17, d=1024 + 40, seed=27, nan_frac=0.05, block=1024, edges=True),
+    dict(n=pk.PLANE_ROWS_MAX, d=1024, seed=28, nan_frac=0.05, block=1024, edges=True),
+    dict(n=pk.PLANE_ROWS_MAX + 1, d=1024, seed=29, nan_frac=0.05, block=1024, edges=True),
+]
+CASES += PLANE_CASES
+
 
 @pytest.mark.parametrize("case", CASES)
 def test_coordinate_median(case):
     g = _rand(**case)
-    out = np.asarray(pk.coordinate_median(g, block_d=128))
-    np.testing.assert_allclose(out, oracle.median(g), rtol=1e-6, atol=1e-7)
+    out = np.asarray(pk.coordinate_median(g, block_d=case.get("block", 128)))
+    # a selection: equal to the bit, NaN for NaN and infinity for infinity
+    np.testing.assert_array_equal(out, oracle.median(g).astype(np.float32))
 
 
 @pytest.mark.parametrize("case", CASES)
 def test_coordinate_averaged_median(case):
     g = _rand(**case)
     f = 2
-    out = np.asarray(pk.coordinate_averaged_median(g, g.shape[0] - f, block_d=128))
-    np.testing.assert_allclose(out, oracle.averaged_median(g, f), rtol=1e-5, atol=1e-6)
+    out = np.asarray(pk.coordinate_averaged_median(g, g.shape[0] - f, block_d=case.get("block", 128)))
+    with np.errstate(invalid="ignore"):  # the oracle's mean over +inf and -inf
+        ref = oracle.averaged_median(g, f)
+    np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
 
 @pytest.mark.parametrize("case", CASES)
 def test_average_nan_columns(case):
     g = _rand(**case)
-    out = np.asarray(pk.average_nan_columns(g, block_d=128))
+    out = np.asarray(pk.average_nan_columns(g, block_d=case.get("block", 128)))
     np.testing.assert_allclose(out, oracle.average_nan(g), rtol=1e-5, atol=1e-6)
 
 
@@ -157,18 +187,42 @@ TILE_CASES = [
     dict(n=3, d=384, seed=13, nan_frac=0.3),
     dict(n=16, d=1000, seed=16, nan_frac=0.1),
     dict(n=32, d=3 * 128 - 7, seed=17, nan_frac=0.0),
+    # the plane form's blocks of 1,024 and 2,048: one short, whole, one over
+    dict(n=4, d=1023, seed=30, nan_frac=0.1, block=1024, edges=True),
+    dict(n=16, d=1024, seed=31, nan_frac=0.1, block=1024, edges=True),
+    dict(n=4, d=1025, seed=32, nan_frac=0.0, block=1024, edges=True),
+    dict(n=5, d=2 * 2048 + 1, seed=33, nan_frac=0.1, block=2048, edges=True),
+    dict(n=2, d=2 * 1024 + 40, seed=34, nan_frac=0.3, block=1024, edges=True),
 ]
 
 
 @pytest.mark.parametrize("case", TILE_CASES)
 def test_coordinate_kernels_at_tile_boundaries(case):
     g = _rand(**case)
+    block = case.get("block", 128)
+    np.testing.assert_array_equal(
+        np.asarray(pk.coordinate_median(g, block_d=block)), oracle.median(g).astype(np.float32))
     np.testing.assert_allclose(
-        np.asarray(pk.coordinate_median(g, block_d=128)), oracle.median(g),
-        rtol=1e-6, atol=1e-7)
-    np.testing.assert_allclose(
-        np.asarray(pk.average_nan_columns(g, block_d=128)), oracle.average_nan(g),
+        np.asarray(pk.average_nan_columns(g, block_d=block)), oracle.average_nan(g),
         rtol=1e-5, atol=1e-6)
+
+
+@pytest.mark.parametrize("n,form", [
+    (4, "_planes"), (16, "_planes"), (pk.PLANE_ROWS_MAX, "_planes"), (pk.PLANE_ROWS_MAX + 1, "")])
+def test_kernel_name_says_the_form(n, form):
+    """The form follows the row count and nothing else, and the name of the
+    ``pallas_call`` — what a compiled step and the ledger's ``breakdown``
+    show — says which ran: the rank rules' planes up to ``PLANE_ROWS_MAX``
+    rows, the slab beyond, and for the rule without ranks."""
+    import jax
+
+    g = np.zeros((n, 2048), np.float32)
+    for name, rule in [
+            ("coordinate_median", pk.coordinate_median),
+            ("coordinate_averaged_median", lambda x: pk.coordinate_averaged_median(x, n - 1)),
+            ("coordinate_trimmed_mean", lambda x: pk.coordinate_trimmed_mean(x, 1, n - 2))]:
+        assert " name=%s%s\n" % (name, form) in str(jax.make_jaxpr(rule)(g)) + "\n"
+    assert " name=average_nan_columns\n" in str(jax.make_jaxpr(pk.average_nan_columns)(g)) + "\n"
 
 
 # (n, d, block_d): the three boundary widths at n=6, then widths that are a
@@ -362,8 +416,8 @@ def test_kernel_tier_names_the_served_tier(monkeypatch):
 def test_coordinate_trimmed_mean(case):
     g = _rand(**case)
     n = g.shape[0]
-    trim = 2
-    out = np.asarray(pk.coordinate_trimmed_mean(g, trim, n - 2 * trim, block_d=128))
+    trim = min(2, (n - 1) // 2)
+    out = np.asarray(pk.coordinate_trimmed_mean(g, trim, n - 2 * trim, block_d=case.get("block", 128)))
     np.testing.assert_allclose(
         out, oracle.trimmed_mean(g, trim), rtol=1e-5, atol=1e-6, equal_nan=True)
 

@@ -155,33 +155,47 @@ def test_cut_is_a_bitcast_on_four_chips(v5e_2x2):
     assert compiled.memory_analysis().temp_size_in_bytes < 1.1 * k * W * blk * 4
 
 
-def test_rule_reads_the_rows_as_they_are_on_one_chip(v5e_2x2, monkeypatch):
+@pytest.mark.parametrize("rule,n,f,d,kernel,held", [
+    # the t = 16 selections, their mask (a pred[16, d]: 4 d bytes) and a row of room
+    ("bulyan", 32, 7, 25_557_032, "coordinate_averaged_median_planes", (32 - 2 * 7 - 2) + 4 + 1),
+    # nothing: the leftover columns land in the kernel's own output, in place
+    ("median", 4, 1, 305_351_680, "coordinate_median_planes", 0.1),
+])
+def test_rule_reads_the_rows_as_they_are_on_one_chip(v5e_2x2, monkeypatch, rule, n, f, d, kernel, held):
     """Bulyan at W = 1 over ResNet-50's (32, 25,557,032) rows, 984 columns
-    short of a block of 1,024: the distance kernel reads the rows once, as
-    one operand, and neither it nor the averaged-median kernel behind it is
-    handed a padded copy.  Until PR 30 each wrapper padded its rows to whole
-    blocks first: 3.27 + 1.64 GB copied a step, 15 ms of
-    ``resnet50_bulyan_1chip``'s 288."""
+    short of a block of 1,024, and the median over SDAR's (4, 305,351,680):
+    the distance kernel reads the rows once, as one operand, and neither it
+    nor the coordinate kernel behind it is handed a padded, copied or
+    transposed matrix — the plane form takes each row out of the block as it
+    lies — and what the coordinate kernel writes is its one dense row, not an
+    (8, d) tile.  Until PR 30 each wrapper padded its rows to whole blocks
+    first (3.27 + 1.64 GB copied a step, 15 ms of ``resnet50_bulyan_1chip``'s
+    288); until PR 33 Bulyan's result left as 8 equal rows (0.82 GB a step)."""
     from jax.sharding import SingleDeviceSharding
 
     from aggregathor_tpu.gars.common import forced_tier
     from aggregathor_tpu.ops import pallas_kernels
 
-    n, f, d = 32, 7, 25_557_032
     monkeypatch.setattr(pallas_kernels, "on_tpu", lambda: True)  # compile the kernels, not interpret
     rows = jax.ShapeDtypeStruct((n, d), jnp.float32,
                                 sharding=SingleDeviceSharding(v5e_2x2.devices[0]))
     with forced_tier("pallas"):
-        compiled = compile_uncached(jax.jit(gars.instantiate("bulyan", n, f).aggregate), rows)
+        compiled = compile_uncached(jax.jit(gars.instantiate(rule, n, f).aggregate), rows)
     text = compiled.as_text()
     calls = dict(re.findall(r"^ *%?((?:pairwise|coordinate)[\w.-]*) = .* custom-call\(([^)]*)\), "
                             r'custom_call_target="tpu_custom_call"', text, re.M))
-    distance, = [name for name in calls if name.startswith("pairwise_sq_distances")]
-    selection, = [name for name in calls if name.startswith("coordinate_averaged_median")]
-    assert len(calls[distance].split(",")) == 1 and len(calls[selection].split(",")) == 1
-    assert not re.search(r"= f32\[\d+,\d{6,}\]\S* pad\(", text)
-    # the t = 16 selections and the kernel's (8, d) output, and no copy of the rows
-    assert compiled.memory_analysis().temp_size_in_bytes < ((n - 2 * f - 2) + 8 + 2) * d * 4
+    assert all(len(operands.split(",")) == 1 for operands in calls.values()), calls
+    selection, = [name for name in calls if name.startswith(kernel)]
+    if rule == "bulyan":
+        distance, = [name for name in calls if name.startswith("pairwise_sq_distances")]
+        assert "grads" in calls[distance]
+    else:
+        assert len(calls) == 1 and "grads" in calls[selection]
+    # nothing writes the n rows anew in front of a kernel, nor 8 rows behind
+    # one (the entry computation's instructions are the ones that own a buffer)
+    assert not re.search(r"= f32\[(%d|8),\d{7,}\]\S* (pad|copy|transpose|fusion)\(" % n,
+                         text[text.index("\nENTRY "):])
+    assert compiled.memory_analysis().temp_size_in_bytes < held * d * 4
 
 
 def test_crop_neither_loops_nor_slices_on_the_chip(v5e_2x2):
