@@ -1138,16 +1138,22 @@ def main(argv=None):
             # l1/l2 regularization wraps the per-worker loss (reference:
             # graph.py:125-139) — the ONE wrapper shared by the flat
             # engine and the sharded bounded-wait submission body, so the
-            # two arms cannot silently diverge
+            # two arms cannot silently diverge.  A loss that carries its
+            # model's counters (``has_aux``: models/sdar.py, laguna.py)
+            # keeps them through the wrapper
+            has_aux = getattr(base_loss, "has_aux", False)
+
             def loss_fn(params, batch):
                 loss = base_loss(params, batch)
+                loss, counters = loss if has_aux else (loss, None)
                 leaves = jax.tree_util.tree_leaves(params)
                 if l1:
                     loss = loss + l1 * sum(jnp.sum(jnp.abs(p)) for p in leaves)
                 if l2:
                     loss = loss + l2 * sum(jnp.sum(p * p) for p in leaves)
-                return loss
+                return (loss, counters) if has_aux else loss
 
+            loss_fn.has_aux = has_aux
             return loss_fn
 
         class TrainingStack:

@@ -48,7 +48,7 @@ import numpy as np
 from . import Experiment, register
 from ..utils import UserException, parse_keyval
 from .common import check_dtype
-from .transformer import _NEG, online_softmax_step, rms_norm, rope
+from .transformer import _NEG, online_softmax_step, rms_norm, rope, rope_frequencies
 
 #: standard deviation of every matrix's initial entries (norm scales start at 1)
 INIT_STD = 0.02
@@ -184,8 +184,9 @@ def attention(u, layer, cfg):
     q = (u @ w("wq")).reshape(b, two_l, g * r, dh)
     k = (u @ w("wk")).reshape(b, two_l, g, dh)
     v = (u @ w("wv")).reshape(b, two_l, g, dh)
-    q = rope(rms_norm(q, w("q_norm"), cfg.norm_eps), positions, cfg.rope_theta)
-    k = rope(rms_norm(k, w("k_norm"), cfg.norm_eps), positions, cfg.rope_theta)
+    turn = lambda heads: rope(heads, positions, rope_frequencies(dh, cfg.rope_theta))
+    q = turn(rms_norm(q, w("q_norm"), cfg.norm_eps))
+    k = turn(rms_norm(k, w("k_norm"), cfg.norm_eps))
     return masked_attention(q.reshape(b, two_l, g, r, dh), k, v, cfg) @ w("wo")
 
 
@@ -202,9 +203,11 @@ def route(tokens, router, cfg):
     return top_p / jnp.sum(top_p, axis=-1, keepdims=True), top_e
 
 
-def moe(u, layer, cfg):
-    """(B, S, D) -> ((B, S, D) the held experts' part of the output,
-    positions routed to held experts, held experts no position reached).
+def held_experts(tokens, weights, chosen, layer, held, dtype):
+    """(N, D) tokens under a router's ``weights`` and ``chosen`` experts, both
+    (N, experts_per_token) -> ((N, D) the part of the output that the experts
+    ``held`` give, positions routed to them, held experts no position reached).
+    The loop every model with such a layer calls (models/laguna.py too).
 
     Every held expert runs over every position and its output is weighted by
     what the router gave it there, zero where the position did not choose it:
@@ -213,21 +216,29 @@ def moe(u, layer, cfg):
     trip count followed the tokens moved ``steps_per_s`` by 2.4 % between two
     seeds, five times what the grid admits in a new cell).  An expert no
     position chose gets a gradient of exact zeros."""
+    out = jnp.zeros_like(tokens)
+    routed, idle = jnp.float32(0), jnp.float32(0)
+    for slot, expert in enumerate(held):
+        hit = chosen == expert
+        mine = jnp.sum(jnp.where(hit, weights, 0.0), axis=-1).astype(tokens.dtype)
+        hidden = (jax.nn.silu(tokens @ layer["we_gate"][slot].astype(dtype))
+                  * (tokens @ layer["we_up"][slot].astype(dtype)))
+        out = out + mine[:, None] * (hidden @ layer["we_down"][slot].astype(dtype))
+        count = jnp.sum(jnp.any(hit, axis=-1).astype(jnp.float32))
+        routed, idle = routed + count, idle + (count == 0).astype(jnp.float32)
+    return out, routed, idle
+
+
+def moe(u, layer, cfg):
+    """(B, S, D) -> ((B, S, D) the held experts' part of the output,
+    positions routed to held experts, held experts no position reached)."""
     b, s, d = u.shape
     tokens = u.reshape(b * s, d)
     with jax.named_scope("model.router"):
         weights, chosen = route(tokens, layer["router"].astype(cfg.dtype), cfg)
     with jax.named_scope("model.experts"):
-        out = jnp.zeros_like(tokens)
-        routed, idle = jnp.float32(0), jnp.float32(0)
-        for slot, expert in enumerate(cfg.experts_held):
-            hit = chosen == expert
-            mine = jnp.sum(jnp.where(hit, weights, 0.0), axis=-1).astype(tokens.dtype)
-            hidden = (jax.nn.silu(tokens @ layer["we_gate"][slot].astype(cfg.dtype))
-                      * (tokens @ layer["we_up"][slot].astype(cfg.dtype)))
-            out = out + mine[:, None] * (hidden @ layer["we_down"][slot].astype(cfg.dtype))
-            count = jnp.sum(jnp.any(hit, axis=-1).astype(jnp.float32))
-            routed, idle = routed + count, idle + (count == 0).astype(jnp.float32)
+        out, routed, idle = held_experts(tokens, weights, chosen, layer, cfg.experts_held,
+                                         cfg.dtype)
     return out.reshape(b, s, d), routed, idle
 
 

@@ -170,16 +170,31 @@ def rms_norm(x, scale, eps):
     return (x * jax.lax.rsqrt(var + eps)).astype(x.dtype) * scale
 
 
-def rope(x, positions, theta):
-    """Rotary embedding; ``positions`` are *global* so SP blocks stay aligned."""
+def rope_frequencies(width, theta):
+    """The default table: ``theta^(-2i / width)`` for the ``width / 2`` pairs."""
+    return jnp.exp(-jnp.arange(0, width, 2, dtype=jnp.float32) * (math.log(theta) / width))
+
+
+def rope(x, positions, inv_freq, factor=None):
+    """Rotary embedding; ``positions`` are *global* so SP blocks stay aligned.
+
+    ``inv_freq`` is one inverse frequency a rotated pair (``rope_frequencies``,
+    or a table of the caller's own such as YaRN's blend): the first
+    ``2 * len(inv_freq)`` dims of each head turn, pair (2i, 2i + 1) by
+    ``position * inv_freq[i]``, and the rest pass as they are; ``factor``,
+    where given, scales cos and sin."""
     b, s, h, dh = x.shape
-    freqs = jnp.exp(-jnp.arange(0, dh, 2, dtype=jnp.float32) * (math.log(theta) / dh))
-    angles = positions.astype(jnp.float32)[:, None] * freqs[None, :]  # (s, dh/2)
+    width = 2 * inv_freq.shape[0]
+    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # (s, width/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = x[..., 0::2], x[..., 1::2]
+    if factor is not None:
+        cos, sin = factor * cos, factor * sin
+    turned = x if width == dh else x[..., :width]
+    x1, x2 = turned[..., 0::2], turned[..., 1::2]
     rx1 = x1 * cos[None, :, None, :] - x2 * sin[None, :, None, :]
     rx2 = x1 * sin[None, :, None, :] + x2 * cos[None, :, None, :]
-    return jnp.concatenate([rx1[..., None], rx2[..., None]], axis=-1).reshape(b, s, h, dh).astype(x.dtype)
+    turned = jnp.concatenate([rx1[..., None], rx2[..., None]], axis=-1).reshape(b, s, h, width).astype(x.dtype)
+    return turned if width == dh else jnp.concatenate([turned, x[..., width:]], axis=-1)
 
 
 def online_softmax_step(scores, weigh, num, den, mx):
@@ -241,8 +256,9 @@ def ring_attention(q, k, v, positions, axis):
 def attention_block(x, positions, wq, wk, wv, wo, cfg, axis):
     b, sb, d = x.shape
     h, dh = cfg.n_heads, cfg.head_dim
-    q = rope((x @ wq).reshape(b, sb, h, dh), positions, cfg.rope_theta)
-    k = rope((x @ wk).reshape(b, sb, h, dh), positions, cfg.rope_theta)
+    turn = lambda heads: rope(heads, positions, rope_frequencies(dh, cfg.rope_theta))
+    q = turn((x @ wq).reshape(b, sb, h, dh))
+    k = turn((x @ wk).reshape(b, sb, h, dh))
     v = (x @ wv).reshape(b, sb, h, dh)
     out = ring_attention(q, k, v, positions, axis)
     return out.reshape(b, sb, h * dh) @ wo

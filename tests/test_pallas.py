@@ -76,6 +76,31 @@ def test_coordinate_averaged_median(case):
     np.testing.assert_allclose(out, ref, rtol=1e-5, atol=1e-6)
 
 
+@pytest.mark.parametrize("d,block", [(2 * 1024 + 40, 1024), (3 * 2048 + 7, 2048)])
+def test_averaged_median_at_three_rows_against_the_jnp_tier(d, block):
+    """n = 3, f = 1 (the mean of the median and the nearer of the other two):
+    the plane form over the whole blocks and the leftover columns, against the
+    jnp tier of gars/averaged_median.py, with NaN scattered, the columns
+    ``_rand`` plants at both ends, and columns whose two outer values lie
+    equally far from the median (the lower row wins) in a block and in the
+    leftover."""
+    import jax.numpy as jnp
+
+    from aggregathor_tpu.gars.averaged_median import averaged_median_columns
+
+    g = _rand(3, d, seed=40, nan_frac=0.1, block=block, edges=True)
+    for column in (7, block + 9, d - 9):
+        g[:, column] = (1.0, 2.0, 3.0)
+        g[:, column + 1] = (3.0, 2.0, 1.0)
+        g[:, column + 2] = (0.0, 0.0, 5.0)  # two workers whose tokens reached no expert
+    out = np.asarray(pk.coordinate_averaged_median(g, 2, block_d=block))
+    with np.errstate(invalid="ignore"):
+        jnp_tier = np.asarray(averaged_median_columns(jnp.asarray(g), 3, 2))
+    np.testing.assert_allclose(out, jnp_tier, rtol=1e-6, atol=0, equal_nan=True)
+    for column in (7, block + 9, d - 9):
+        assert list(out[column:column + 3]) == [1.5, 2.5, 0.0]
+
+
 @pytest.mark.parametrize("case", CASES)
 def test_average_nan_columns(case):
     g = _rand(**case)
