@@ -33,13 +33,22 @@ width as published, no bias anywhere:
 How it is computed here: consecutive layers of one kind (attention, head
 count and feed-forward alike) are a RUN whose leaves are stacked on a leading
 axis; a run of several layers goes under ``lax.scan``, a run of one is called
-as it is, each layer under ``jax.checkpoint``.  Attention scans the queries a
-chunk at a time and folds into the running softmax it shares with
+as it is, each layer under ``jax.checkpoint``.  Attention has two forms and
+one chooser (ops/attention.py ``attention_form``; no flag chooses).  **On a
+TPU, for shapes the kernel takes** (L a multiple of its tiles, a head of 128
+lanes: the configuration's) it is ops/attention.py's fused Pallas kernel,
+forward and backward, full and sliding layers alike under ``Causal(window)``:
+scores, running maximum and sums stay in VMEM, out come the output and one
+log-sum-exp a query a head, and the backward pass makes a tile's scores again.
+**Everywhere else** (the CPU, a length that does not divide; the parity oracle
+of the kernel's tests) it is ``chunked_attention``, plain XLA: a scan of the
+queries a chunk at a time that folds into the running softmax it shares with
 models/transformer.py only the key chunks the chunk may read — all before it
-and its own on a full layer, the window's on a sliding one — so no L x L score
-tensor exists and the work follows the pairs the mask allows; each chunk is
+and its own on a full layer, the window's on a sliding one; each chunk is
 checkpointed too, so that a layer's backward pass holds one chunk's scores
-and not the layer's (5 GB at three workers, 48 heads and L = 4096).
+and not the layer's (5 GB at three workers, 48 heads and L = 4096).  In either
+form no L x L score tensor exists and the work follows the pairs the mask
+allows.
 """
 
 import dataclasses
@@ -52,6 +61,7 @@ import numpy as np
 
 from . import Experiment, register
 from ..utils import UserException, parse_keyval
+from ..ops.attention import Causal, attend
 from .common import check_dtype
 from .sdar import INIT_STD, _parse_held, held_experts
 from .transformer import _NEG, online_softmax_step, rms_norm, rope
@@ -206,9 +216,9 @@ def init_params(cfg, key):
 
 def allowed(q_pos, k_pos, window):
     """(q, k) booleans: may the query read the key?  ``window`` None: every
-    key up to the query's own; else only the last ``window`` of them."""
-    back = q_pos[:, None] - k_pos[None, :]
-    return back >= 0 if window is None else (back >= 0) & (back < window)
+    key up to the query's own; else only the last ``window`` of them.  The
+    predicate itself is ops/attention.py ``Causal``, the kernel's too."""
+    return Causal(window)(q_pos[:, None], k_pos[None, :])
 
 
 def key_offsets(chunk, nb_chunks, window):
@@ -243,7 +253,15 @@ def _fold(carry, qi, keys, values, mask):
 
 
 def causal_attention(q, k, v, cfg, window):
-    """q (B, L, G, R, Dh), k and v (B, L, G, Dh) -> (B, L, G * R * Dh).
+    """q (B, L, G, R, Dh), k and v (B, L, G, Dh) -> (B, L, G * R * Dh): the
+    fused kernel where ops/attention.py ``attention_form`` says so (a TPU, and
+    a shape the kernel takes), ``chunked_attention`` everywhere else."""
+    return attend(q, k, v, Causal(window),
+                  lambda q, k, v: chunked_attention(q, k, v, cfg, window))
+
+
+def chunked_attention(q, k, v, cfg, window):
+    """The same in plain XLA: the CPU's form, and what the kernel is held to.
 
     A scan over the query chunks; chunk i folds its own keys under the mask,
     then, in two inner scans, the clear and the edged key chunks behind it

@@ -7,4 +7,7 @@
 - ``ops.pallas_kernels`` — hand-written Pallas TPU kernels for the GAR hot
   path (pairwise distances, coordinate-wise selection), replacing the
   reference's CUDA/custom-op tier (native/op_krum, native/op_bulyan).
+- ``ops.attention`` — masked attention as one fused Pallas kernel, forward and
+  backward, with its chooser (the kernel on a TPU for shapes it takes, the
+  caller's XLA form elsewhere); models/laguna.py's attention calls it.
 """

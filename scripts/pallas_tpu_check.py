@@ -21,6 +21,16 @@ Usage::
 Prints one JSON line per (rule, d) with parity verdict + per-tier ms.
 Exit code 0 iff every parity check passed; 2 when there is no TPU.
 ``run_check`` is the same thing as a callable (chip_smoke.py's leg C).
+
+The attention column (``run_attention_check``; ``--columns gar,attention``):
+ops/attention.py's fused kernel against models/laguna.py's chunked XLA form at
+the two shapes of ``laguna_avgmedian_causal4k`` (24 query heads over 4, full;
+32 over 4 under a window of 512; L = 4096, three workers vmapped as the engine
+does) — output and the gradients of q, k and v at ``highest`` precision (what
+separates the two is then the order of float32 sums) and at the default (what
+the step runs), and each form alone, forward and forward + backward, on a
+device-synchronised host clock, least of ``--attention-reps``.
+``--attention-tiles 128x256,256x512`` times the kernel alone at other tiles.
 """
 
 import argparse
@@ -151,6 +161,118 @@ def run_check(n, f, dims, rules=RULES, reps=10, nan_workers=2,
     return failed
 
 
+#: (name, query heads a kv head, window) of the grid's Laguna cell: layers 0
+#: and 4, layers 1-3 (grid/configs/laguna-xs2-ep32-n3.json).
+ATTENTION_SHAPES = (("full", 6, None), ("window", 8, 512))
+
+
+def _least_ms(fn, reps):
+    """Least wall ms of ``fn()`` over ``reps`` calls, each waited for."""
+    import time
+
+    import jax
+
+    jax.block_until_ready(fn())
+    times = []
+    for _ in range(max(1, reps)):
+        begin = time.perf_counter()
+        jax.block_until_ready(fn())
+        times.append(time.perf_counter() - begin)
+    return min(times) * 1e3
+
+
+def run_attention_check(reps=5, tiles=(), length=4096, kv_heads=4, head_dim=128, workers=3,
+                        shapes=ATTENTION_SHAPES, allow_interpret=False, emit=_print_row):
+    """Parity and time of the fused attention kernel against the chunked XLA
+    form at each of ``shapes``; ``emit(row)`` per shape, then per (shape, tile)
+    of ``tiles``.  Returns the rows whose parity is not ``"ok"``."""
+    import jax
+    import jax.numpy as jnp
+
+    from aggregathor_tpu.models import laguna
+    from aggregathor_tpu.ops import attention
+
+    if not allow_interpret and attention._interpret():
+        raise RuntimeError("the attention column needs a TPU backend (the kernel would "
+                           "interpret on %r)" % jax.default_backend())
+    cfg = laguna.LagunaConfig(seq=length, head_dim=head_dim, kv_heads=kv_heads,
+                              attn_chunk=min(length, laguna.LagunaConfig.attn_chunk))
+    failed = []
+    for name, rep, window in shapes:
+        key = jax.random.PRNGKey(11)
+        normal = lambda place, *dims: jax.random.normal(
+            jax.random.fold_in(key, place), (workers, 1, length) + dims, jnp.float32)
+        q, k, v = normal(0, kv_heads, rep, head_dim), normal(1, kv_heads, head_dim), normal(
+            2, kv_heads, head_dim)
+        weight = normal(3, kv_heads * rep * head_dim)
+        mask = attention.Causal(window)
+
+        def forms(attend):
+            forward = jax.vmap(attend)
+            loss = lambda q, k, v: jnp.sum(forward(q, k, v) * weight)
+            return jax.jit(forward), jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2)))
+
+        def traced(form, precision):
+            """(forward, loss-and-gradients) of ``form``, traced inside the seam."""
+            def enter(fn):
+                def call(*args):
+                    with attention.forced_form(form), jax.default_matmul_precision(precision):
+                        return fn(*args)
+                return call
+            return [enter(fn) for fn in forms(
+                lambda q, k, v: laguna.causal_attention(q, k, v, cfg, window))]
+
+        q_tile, k_tile = attention.tiles_for(length)
+        row = {"metric": "pallas_tpu_check", "rule": "attention-" + name, "workers": workers,
+               "length": length, "heads": "%d/%d" % (kv_heads * rep, kv_heads), "window": window,
+               "tiles": "%dx%d" % (q_tile, k_tile),
+               **attention.table_counts(attention.tile_table(mask, length, q_tile, k_tile))}
+        try:
+            worst, exact = {}, None
+            for precision, bound in (("highest", 1e-4), ("default", 3e-2)):
+                ours = traced("kernel", precision)
+                theirs = traced("xla", precision)
+                out_k, out_x = ours[0](q, k, v), theirs[0](q, k, v)
+                (_, grads_k), (_, grads_x) = ours[1](q, k, v), theirs[1](q, k, v)
+                gap = lambda a, b: float("%.3g" % (jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))))
+                gaps = [gap(a, b) for a, b in zip((out_k,) + tuple(grads_k),
+                                                  (out_x,) + tuple(grads_x))]
+                finite = all(bool(jnp.all(jnp.isfinite(a))) for a in (out_k,) + tuple(grads_k))
+                row["gap_" + precision] = gaps
+                worst[precision] = finite and max(gaps) <= bound
+                suffix = "_ms" if precision == "default" else "_highest_ms"
+                row["kernel_fwd" + suffix] = round(_least_ms(lambda: ours[0](q, k, v), reps), 4)
+                row["kernel_fwd_bwd" + suffix] = round(_least_ms(lambda: ours[1](q, k, v), reps), 4)
+                if precision == "highest":
+                    exact = out_k
+                else:
+                    # how the chip multiplies the kernel's float32 operands at the default:
+                    # ~1e-6 from ``highest`` in several passes, ~3e-3 in one bfloat16 pass
+                    row["kernel_default_from_highest"] = gap(out_k, exact)
+                    row["xla_fwd_ms"] = round(_least_ms(lambda: theirs[0](q, k, v), reps), 4)
+                    row["xla_fwd_bwd_ms"] = round(_least_ms(lambda: theirs[1](q, k, v), reps), 4)
+            row["parity"] = "ok" if all(worst.values()) else "FAIL"
+        except Exception as exc:  # a kernel the compiler refuses is a finding
+            row["parity"] = "ERROR"
+            row["error"] = "%s: %s" % (type(exc).__name__, str(exc)[:400])
+        emit(row)
+        if row["parity"] != "ok":
+            failed.append(row)
+        for q_tile, k_tile in tiles:
+            row = {"metric": "pallas_tpu_check", "rule": "attention-%s-tiles" % name,
+                   "tiles": "%dx%d" % (q_tile, k_tile),
+                   **attention.table_counts(attention.tile_table(mask, length, q_tile, k_tile))}
+            try:
+                forward, gradients = forms(lambda q, k, v: attention.fused_attention(
+                    q, k, v, mask, q_tile, k_tile))
+                row["kernel_fwd_ms"] = round(_least_ms(lambda: forward(q, k, v), reps), 4)
+                row["kernel_fwd_bwd_ms"] = round(_least_ms(lambda: gradients(q, k, v), reps), 4)
+            except Exception as exc:
+                row["error"] = "%s: %s" % (type(exc).__name__, str(exc)[:400])
+            emit(row)
+    return failed
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=32)
@@ -163,6 +285,11 @@ def main():
     ap.add_argument("--allow-interpret", action="store_true",
                     help="harness self-test: run off-TPU in interpreter mode "
                          "(timings meaningless; parity logic still exercised)")
+    ap.add_argument("--columns", default="gar,attention",
+                    help="which checks run: 'gar' (the rules), 'attention' (the fused kernel)")
+    ap.add_argument("--attention-reps", type=int, default=5)
+    ap.add_argument("--attention-tiles", default="",
+                    help="QxK,... : also time the attention kernel alone at these tiles")
     args = ap.parse_args()
 
     import jax
@@ -175,11 +302,18 @@ def main():
         print(json.dumps({"error": "pallas_tpu_check requires a TPU backend, got %r" % platform}))
         sys.exit(2)
 
-    failed = run_check(
-        args.n, args.f, [int(d) for d in args.dims.split(",")],
-        rules=args.rules.split(","), reps=args.reps,
-        nan_workers=args.nan_workers, allow_interpret=args.allow_interpret,
-    )
+    columns, failed = args.columns.split(","), []
+    if "gar" in columns:
+        failed += run_check(
+            args.n, args.f, [int(d) for d in args.dims.split(",")],
+            rules=args.rules.split(","), reps=args.reps,
+            nan_workers=args.nan_workers, allow_interpret=args.allow_interpret,
+        )
+    if "attention" in columns:
+        tiles = [tuple(int(size) for size in pair.split("x"))
+                 for pair in args.attention_tiles.split(",") if pair]
+        failed += run_attention_check(args.attention_reps, tiles,
+                                      allow_interpret=args.allow_interpret)
     sys.exit(1 if failed else 0)
 
 

@@ -1,0 +1,251 @@
+"""ops/attention.py — the fused attention kernel — in interpreter mode on the
+CPU, at small shapes: against one dense masked softmax (output and the
+gradients of q, k and v), called as the step calls it (``vmap`` over workers
+inside ``checkpoint`` inside ``scan``); the tile table against a brute-force
+count of the mask; the chooser and its seam; and the products the float32
+Laguna loss holds with the kernel forced, the kernel's body included.
+Products run at ``highest`` precision, so what separates kernel and reference
+is the order of float32 sums.  (The kernels compiled for the described chip at
+the cell's shapes: tests/test_reshard.py, where every such program lives.)"""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from aggregathor_tpu import models
+from aggregathor_tpu.models import laguna
+from aggregathor_tpu.ops import attention
+from aggregathor_tpu.ops.attention import CLEAR, EDGED, SKIPPED, Causal
+
+LENGTH, KV_HEADS, HEAD_DIM, WORKERS, LAYERS = 32, 2, 16, 3, 2
+
+
+@pytest.fixture(autouse=True)
+def highest_precision():
+    with jax.default_matmul_precision("highest"):
+        yield
+
+
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """Another predicate than the model's: models/sdar.py's mask over a
+    sequence [noisy ; clean] of two halves cut into blocks — a noisy query reads
+    its own noisy block and the clean blocks before it, a clean one the clean
+    blocks up to its own.  Its rows of the tile table have gaps and two runs."""
+
+    half: int
+    block: int
+
+    def __call__(self, q_index, k_index):
+        q_noisy, k_noisy = q_index < self.half, k_index < self.half
+        q_block, k_block = (q_index % self.half) // self.block, (k_index % self.half) // self.block
+        return jnp.where(q_noisy, jnp.where(k_noisy, k_block == q_block, k_block < q_block),
+                         ~k_noisy & (k_block <= q_block))
+
+
+MASKS = [Causal(None), Causal(12), Causal(20), Causal(5), BlockDiffusion(LENGTH // 2, 4)]
+MASK_IDS = ["full", "window-12", "window-20", "window-5", "block-diffusion"]
+
+
+def dense_attention(q, k, v, mask):
+    """One softmax over all L keys under the boolean matrix."""
+    index = jnp.arange(q.shape[1])
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k) / np.sqrt(q.shape[-1])
+    weights = jax.nn.softmax(jnp.where(mask(index[:, None], index[None, :]), scores, -jnp.inf),
+                             axis=-1)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", weights, v).reshape(q.shape[0], q.shape[1], -1)
+
+
+def stepped(attend):
+    """sum over layers and workers of <attend(q, k, v), w>, computed the way
+    the engine's step does: a scan over layers, each body checkpointed, the
+    workers under ``vmap``."""
+    def total(q, k, v, w):
+        @jax.checkpoint
+        def layer(carry, leaves):
+            q, k, v, w = leaves
+            return carry + jnp.sum(jax.vmap(attend)(q, k, v) * w), None
+
+        return jax.lax.scan(layer, jnp.float32(0), (q, k, v, w))[0]
+
+    return jax.jit(jax.value_and_grad(total, argnums=(0, 1, 2)))
+
+
+def seeded(rep, dtype=jnp.float32, lead=(LAYERS, WORKERS, 1)):
+    key = jax.random.PRNGKey(5)
+    normal = lambda place, *dims: jax.random.normal(
+        jax.random.fold_in(key, place), lead + (LENGTH,) + dims).astype(dtype)
+    return (normal(0, KV_HEADS, rep, HEAD_DIM), normal(1, KV_HEADS, HEAD_DIM),
+            normal(2, KV_HEADS, HEAD_DIM), normal(3, KV_HEADS * rep * HEAD_DIM))
+
+
+# a window of 12 cuts every tile of 8 it touches, 20 leaves one clear, 5 is under a tile
+@pytest.mark.parametrize("tiles", [(32, 32), (8, 8), (16, 8)], ids=["one-tile", "8x8", "16x8"])
+@pytest.mark.parametrize("rep", [6, 8])
+@pytest.mark.parametrize("mask", MASKS, ids=MASK_IDS)
+def test_kernel_is_a_dense_masked_softmax(mask, rep, tiles):
+    q, k, v, w = seeded(rep)
+    (ours, ours_grads), (theirs, theirs_grads) = (
+        stepped(lambda q, k, v: attention.fused_attention(q, k, v, mask, *tiles))(q, k, v, w),
+        stepped(lambda q, k, v: dense_attention(q, k, v, mask))(q, k, v, w))
+    assert abs(float(ours) - float(theirs)) <= 1e-5 * abs(float(theirs))
+    for mine, dense in zip(ours_grads, theirs_grads):
+        assert mine.shape == dense.shape and mine.dtype == dense.dtype
+        np.testing.assert_allclose(np.asarray(mine), np.asarray(dense), rtol=1e-4, atol=2e-5)
+    out = jax.vmap(lambda q, k, v: attention.fused_attention(q, k, v, mask, *tiles))(q[0], k[0], v[0])
+    np.testing.assert_allclose(np.asarray(out), np.asarray(jax.vmap(
+        lambda q, k, v: dense_attention(q, k, v, mask))(q[0], k[0], v[0])), rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("mask,length,tiles,counts", [
+    (Causal(None), 4096, (256, 256), (120, 16, 120)),   # a full layer of the grid's cell, a head
+    (Causal(512), 4096, (256, 256), (15, 30, 211)),     # a window layer
+    (Causal(512), 4096, (128, 512), None),
+    (Causal(20), 32, (8, 8), None),
+    (Causal(5), 32, (16, 8), None),
+    (BlockDiffusion(2048, 4), 4096, (256, 256), None),  # models/sdar.py's, at its cell's length
+    (BlockDiffusion(16, 4), 32, (8, 8), None),
+])
+def test_the_table_counts_what_the_mask_allows(mask, length, tiles, counts):
+    """Every tile's class against the mask over its pairs (``laguna.allowed``
+    for the model's), the three counts at the cell's shapes, and the kernel's
+    loops: each tile that is not SKIPPED in exactly one range, the even slots
+    EDGED and the odd CLEAR."""
+    q_tile, k_tile = tiles
+    table = attention.tile_table(mask, length, q_tile, k_tile)
+    index = np.arange(length)
+    ok = np.asarray(laguna.allowed(index, index, mask.window) if isinstance(mask, Causal)
+                    else mask(index[:, None], index[None, :]))
+    for i in range(length // q_tile):
+        for j in range(length // k_tile):
+            tile = ok[i * q_tile:(i + 1) * q_tile, j * k_tile:(j + 1) * k_tile]
+            assert table[i, j] == (CLEAR if tile.all() else EDGED if tile.any() else SKIPPED)
+    found = attention.table_counts(table)
+    assert sum(found.values()) == table.size
+    if counts:
+        assert (found["clear"], found["edged"], found["skipped"]) == counts
+    folded = np.zeros_like(table)
+    for slot, (starts, stops) in enumerate(attention._slots(table)):
+        for i, (start, stop) in enumerate(zip(starts, stops)):
+            assert (table[i, start:stop] == (EDGED if slot % 2 == 0 else CLEAR)).all()
+            folded[i, start:stop] += 1
+    assert np.array_equal(folded, (table != SKIPPED).astype(folded.dtype))
+
+
+def kernels_in(jaxpr):
+    """Names of the ``pallas_call``s of a jaxpr, inner jaxprs included."""
+    names = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "pallas_call":
+            names.append(eqn.params["name"])
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            names += kernels_in(inner)
+    return names
+
+
+def traced_kernels(length, head_dim=HEAD_DIM, chunk=8):
+    cfg = laguna.LagunaConfig(seq=length, attn_chunk=chunk, head_dim=head_dim)
+    shape = lambda *dims: jax.ShapeDtypeStruct((1, length) + dims, jnp.float32)
+    return kernels_in(jax.make_jaxpr(lambda q, k, v: laguna.causal_attention(q, k, v, cfg, 12))(
+        shape(2, 3, head_dim), shape(2, head_dim), shape(2, head_dim)).jaxpr)
+
+
+def test_the_chooser_off_a_tpu_and_inside_the_seam():
+    """Off a TPU the chunked form runs; inside the seam the kernel does, and
+    leaves it again; a forced kernel refuses a length its tiles do not divide."""
+    assert attention.attention_form(4096, 128) == "xla" and traced_kernels(LENGTH) == []
+    with attention.forced_form("kernel"):
+        assert attention.attention_form(4096, 128) == "kernel"
+        assert traced_kernels(LENGTH) == ["causal_attention_fwd"]
+        with attention.forced_form("xla"):
+            assert traced_kernels(LENGTH) == []
+        with pytest.raises(ValueError):
+            attention.attention_form(4096 + 8, 128)
+    assert attention.attention_form(4096, 128) == "xla"
+    with pytest.raises(ValueError):
+        with attention.forced_form("pallas"):
+            pass
+
+
+@pytest.mark.parametrize("length,head_dim,form", [
+    (4096, 128, "kernel"),       # the grid's cell
+    (256, 128, "kernel"),        # a sequence of one tile
+    (4096 + 256, 128, "kernel"),
+    (4096 + 8, 128, "xla"),      # a length the tiles do not divide
+    (4096, 64, "xla"),           # a head that is not whole lanes
+    (32768, 128, "xla"),         # a head's K and V would not stay in VMEM
+])
+def test_the_chooser_on_a_tpu_takes_the_shapes_the_kernel_takes(monkeypatch, length, head_dim, form):
+    monkeypatch.setattr(attention.hw, "on_tpu", lambda: True)
+    assert attention.attention_form(length, head_dim) == form
+
+
+def test_a_length_that_does_not_divide_runs_the_chunked_form_on_a_tpu(monkeypatch):
+    """What the step would trace on a TPU: the kernel at a length of whole
+    tiles, the scan of chunks at one that is not (tiles shrunk to the test's
+    size; the kernel is traced, not run)."""
+    monkeypatch.setattr(attention.hw, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "Q_TILE", 16)
+    monkeypatch.setattr(attention, "K_TILE", 16)
+    monkeypatch.setattr(attention, "info", lambda *_: None)
+    assert traced_kernels(LENGTH, head_dim=128) == ["causal_attention_fwd"]
+    assert traced_kernels(LENGTH + 8, head_dim=128) == []
+
+
+def products_of(jaxpr, inside=False):
+    """[(operand dtypes, inside a kernel?)] of every product, the bodies of
+    ``pallas_call``s included (grid/check.py ``_count_narrow`` walks the same
+    way: a kernel's body is its ``jaxpr`` parameter)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name in ("dot_general", "conv_general_dilated"):
+            found.append((tuple(v.aval.dtype for v in eqn.invars), inside))
+        for inner in jax.core.jaxprs_in_params(eqn.params):
+            found += products_of(inner, inside or eqn.primitive.name == "pallas_call")
+    return found
+
+
+def test_the_float32_loss_with_the_kernel_forced_holds_no_narrow_product():
+    """The float32 Laguna loss and its gradient, kernel forced: no product has
+    an operand under 32 bits — the kernels' own seven a layer included — so
+    ``narrow_products`` stays 0 in the grid's cell."""
+    experiment = models.instantiate("laguna", [
+        "vocab:50", "hidden:64", "kv-heads:2", "head-dim:16",
+        "layer-types:full,sliding,sliding,sliding,full",
+        "mlp-types:dense,sparse,sparse,sparse,sparse", "heads:6,8,8,8,6", "window:12",
+        "dense-width:96", "experts:16", "experts-per-token:4", "expert-width:24",
+        "shared-width:24", "experts-held:1,4,7,12", "seq:%d" % LENGTH, "attn-chunk:8",
+        "batch-size:1", "corpus:4"])
+    params = experiment.init(jax.random.PRNGKey(0))
+    batch = {"tokens": jnp.asarray(experiment.corpus[:1])}
+    with attention.forced_form("kernel"):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p: experiment.loss(p, batch)[0]))(params)
+    products = products_of(jaxpr.jaxpr)
+    in_kernels = [operands for operands, inside in products if inside]
+    assert len(in_kernels) >= 2 + 5  # a forward's two and a backward's five, at the least
+    assert all(jnp.finfo(dtype).bits >= 32 for operands, _ in products for dtype in operands)
+
+
+@pytest.mark.parametrize("window", [None, 12])
+def test_a_narrower_input_is_widened_inside_the_kernel(window):
+    """bfloat16 q, k and v (``dtype:bfloat16``, the harness's control): every
+    product inside the kernels takes float32 operands, the outputs come back
+    as bfloat16 and the gradients are finite and near float32's."""
+    mask, rep = Causal(window), 3
+    q, k, v, w = seeded(rep, jnp.bfloat16, lead=(1,))
+    value = lambda q, k, v: jnp.sum(
+        attention.fused_attention(q, k, v, mask, 8, 8).astype(jnp.float32) * w.astype(jnp.float32))
+    products = products_of(jax.make_jaxpr(jax.grad(value, argnums=(0, 1, 2)))(q, k, v).jaxpr)
+    assert len(products) >= 2 + 5  # a forward's two and a backward's five, once a loop
+    assert all(inside and operands == (jnp.float32, jnp.float32) for operands, inside in products)
+    grads = jax.jit(jax.grad(value, argnums=(0, 1, 2)))(q, k, v)
+    wide = jax.jit(jax.grad(lambda q, k, v: jnp.sum(dense_attention(q, k, v, mask) * w),
+                            argnums=(0, 1, 2)))(*(a.astype(jnp.float32) for a in (q, k, v)))
+    for narrow, exact in zip(grads, wide):
+        assert narrow.dtype == jnp.bfloat16
+        assert bool(jnp.all(jnp.isfinite(narrow.astype(jnp.float32))))
+        scale = float(jnp.max(jnp.abs(exact)))
+        assert float(jnp.max(jnp.abs(narrow.astype(jnp.float32) - exact))) <= 2e-2 * scale

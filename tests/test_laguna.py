@@ -19,6 +19,7 @@ import pytest
 from aggregathor_tpu import gars, models
 from aggregathor_tpu.models import laguna
 from aggregathor_tpu.models.transformer import rope, rope_frequencies
+from aggregathor_tpu.ops.attention import forced_form
 from aggregathor_tpu.parallel import RobustEngine, make_mesh
 
 GRID = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "grid")
@@ -157,16 +158,19 @@ def test_the_window_is_a_window(kind, reads_far):
     assert moved(ahead) == 0.0
 
 
+@pytest.mark.parametrize("form", ["xla", "kernel"])
 @pytest.mark.parametrize("window", [None, WINDOW, 5])
-def test_chunked_attention_is_a_dense_masked_softmax(window):
+def test_chunked_attention_is_a_dense_masked_softmax(window, form):
     """The running softmax over a chunk's key ranges against one softmax over
-    all L keys under the boolean matrix, and the ranges against the mask."""
+    all L keys under the boolean matrix, and the ranges against the mask; the
+    fused kernel (ops/attention.py, interpreted here) through the same call."""
     cfg = laguna.LagunaConfig(attn_chunk=8, seq=LENGTH)
     key = jax.random.PRNGKey(2)
     q = jax.random.normal(jax.random.fold_in(key, 0), (2, LENGTH, 2, 3, 16))
     k = jax.random.normal(jax.random.fold_in(key, 1), (2, LENGTH, 2, 16))
     v = jax.random.normal(jax.random.fold_in(key, 2), (2, LENGTH, 2, 16))
-    ours = jax.jit(lambda q, k, v: laguna.causal_attention(q, k, v, cfg, window))(q, k, v)
+    with forced_form(form):
+        ours = jax.jit(lambda q, k, v: laguna.causal_attention(q, k, v, cfg, window))(q, k, v)
     mask = reference.causal_mask(LENGTH, window)
     positions = jnp.arange(LENGTH)
     assert np.array_equal(np.asarray(laguna.allowed(positions, positions, window)), np.asarray(mask))
@@ -188,11 +192,13 @@ def test_chunked_attention_is_a_dense_masked_softmax(window):
     assert grid_module("flops", "laguna").allowed_pairs(LENGTH, window) == int(mask.sum())
 
 
+@pytest.mark.parametrize("form", ["xla", "kernel"])
 @pytest.mark.parametrize("window", [None, WINDOW])
-def test_a_narrower_dtype_keeps_its_scores_wide(window):
+def test_a_narrower_dtype_keeps_its_scores_wide(window, form):
     """Under ``dtype:bfloat16`` every score product of the chunked attention
     leaves as float32 (rounded to bfloat16 first, q's and k's gradients read
-    NaN on the chip), and the gradients are finite."""
+    NaN on the chip), and the gradients are finite.  The kernel widens what it
+    loads: its products take float32 operands and none is narrow."""
     cfg = laguna.LagunaConfig(attn_chunk=8, seq=LENGTH, dtype=jnp.bfloat16)
     key = jax.random.PRNGKey(4)
     q = jax.random.normal(jax.random.fold_in(key, 0), (1, LENGTH, 2, 3, 16)).astype(jnp.bfloat16)
@@ -209,10 +215,12 @@ def test_a_narrower_dtype_keeps_its_scores_wide(window):
             for inner in jax.core.jaxprs_in_params(eqn.params):
                 walk(inner)
 
-    walk(jax.make_jaxpr(value)(q, k, v).jaxpr)
+    with forced_form(form):
+        walk(jax.make_jaxpr(value)(q, k, v).jaxpr)
+        grads = jax.jit(jax.grad(value, argnums=(0, 1, 2)))(q, k, v)
     narrow = [out for operands, out in products if operands == (jnp.bfloat16, jnp.bfloat16)]
-    assert narrow and all(out == jnp.float32 for out in narrow)
-    grads = jax.jit(jax.grad(value, argnums=(0, 1, 2)))(q, k, v)
+    assert products and all(out == jnp.float32 for _operands, out in products)
+    assert bool(narrow) is (form == "xla")
     assert all(bool(jnp.all(jnp.isfinite(g.astype(jnp.float32)))) for g in grads)
 
 

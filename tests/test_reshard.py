@@ -9,7 +9,9 @@ to at ResNet-50's width for a described (not attached) ``v5e:2x2``.
 Every program compiled for the described chip lives in this one file, config
 2's in-step crop (models/preprocessing.py) included: only one process at a
 time may load the TPU's library, and a second file's fixture could land on
-another test worker and skip in silence."""
+another test worker and skip in silence.  The fused attention kernels
+(ops/attention.py) at the shapes of the grid's Laguna cell are here for that
+reason."""
 
 import os
 import re
@@ -225,3 +227,39 @@ def test_crop_neither_loops_nor_slices_on_the_chip(v5e_2x2):
     text = compiled.as_text()
     assert " while(" not in text and " dynamic-update-slice(" not in text
     assert compiled.memory_analysis().temp_size_in_bytes < 300e6
+
+
+@pytest.mark.parametrize("rep,window", [(6, None), (8, 512)], ids=["full", "window"])
+def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, monkeypatch, rep, window):
+    """The fused attention kernel and its backward pass as the step of
+    ``laguna_avgmedian_causal4k`` calls them — three workers under ``vmap``,
+    L = 4096, 4 kv heads of 128 serving 6 (full) or 8 (window 512) query heads
+    each — compile for the described chip: the tiles fit VMEM, every slice is
+    on a tile boundary, and what the two leave in HBM beside q, k, v, the
+    output and their gradients is one log-sum-exp a query a head (128 lanes
+    wide as the chip stores it) and a row-major copy of q: nothing
+    score-shaped, nothing a fold."""
+    from jax.sharding import SingleDeviceSharding
+
+    from aggregathor_tpu.ops import attention
+
+    monkeypatch.setattr(attention.hw, "on_tpu", lambda: True)  # compile the kernels, not interpret
+    monkeypatch.setattr(attention, "info", lambda *_: None)
+    workers, length, kv_heads, head_dim = 3, 4096, 4, 128
+    assert attention.attention_form(length, head_dim) == "kernel"
+    one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
+    shape = lambda *dims: jax.ShapeDtypeStruct((workers, 1, length) + dims, jnp.float32,
+                                               sharding=one_chip)
+    attend = jax.vmap(lambda q, k, v: attention.attend(q, k, v, attention.Causal(window), None))
+    compiled = compile_uncached(
+        jax.jit(jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) ** 2), argnums=(0, 1, 2))),
+        shape(kv_heads, rep, head_dim), shape(kv_heads, head_dim), shape(kv_heads, head_dim))
+    text = compiled.as_text()
+    calls = re.findall(r"^ *%?([\w.-]*causal_attention_(?:fwd|bwd)[\w.-]*) = .* custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert len(calls) == 2 and any("fwd" in name for name in calls) and any(
+        "bwd" in name for name in calls), calls
+    assert " while(" not in text
+    q_bytes = workers * length * kv_heads * rep * head_dim * 4
+    # the log-sum-exp (as wide as q in HBM), q's copy, the output, its cotangent: 4 q's, and room
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * q_bytes
