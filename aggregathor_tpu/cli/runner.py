@@ -873,9 +873,7 @@ def main(argv=None):
     # spec fails before any compilation.
     xprof = None
     if args.xprof:
-        xprof = obs_profiler.ProfilerWindow(
-            args.xprof, args.trace_dir, registry=registry
-        )
+        xprof = obs_profiler.ProfilerWindow(args.xprof, args.trace_dir)
 
     with Context("graph"):
         experiment = models.instantiate(args.experiment, args.experiment_args)
@@ -1824,16 +1822,6 @@ def main(argv=None):
     g_wire_ratio.set(compress.compression_ratio(
         ts.model_dim, dtype=ts.engine.exchange_dtype, codec=ts.engine.codec
     ))
-    # guardian recovery counters — the third subsystem on the one registry
-    g_rollbacks = registry.counter(
-        "guardian_rollbacks_total", "Guardian rollbacks to last-known-good"
-    )
-    g_escalations = registry.counter(
-        "guardian_escalations_total", "Guardian escalation-ladder rungs applied"
-    )
-    g_recoveries = registry.counter(
-        "guardian_recoveries_total", "Guardian diverged-then-recovered verdicts"
-    )
     # flight-recorder fetch accounting (obs/flight.py): one amortized host
     # copy per summary fire instead of per-dispatch pulls
     c_flight_fetches = registry.counter(
@@ -1870,9 +1858,8 @@ def main(argv=None):
             from aggregathor_tpu.gars.scaling import sync_fetch
 
             if ts.gar_probe_fn is None:
-                with trace.span("gar.probe_build", cat="train"):
-                    ts.gar_probe_fn = ts.engine.build_gar_probe(ts.model_dim)
-                    sync_fetch(ts.gar_probe_fn(0))  # compile + full drain
+                ts.gar_probe_fn = ts.engine.build_gar_probe(ts.model_dim)
+                sync_fetch(ts.gar_probe_fn(0))  # compile + full drain
             with trace.span("gar.aggregate", cat="train"):
                 begin = time.perf_counter()
                 sync_fetch(ts.gar_probe_fn(step))
@@ -1934,8 +1921,7 @@ def main(argv=None):
                 # dispatch already materialized the state, so this is a
                 # host copy, not a device sync (the recorder's whole
                 # host-side cost).
-                with trace.span("flight.fetch", cat="obs"):
-                    window = flight_rec.fetch(state.flight)
+                window = flight_rec.fetch(state.flight)
                 c_flight_fetches.inc()
                 nb_rows = int(window["step"].size)
                 g_flight_rows.set(nb_rows)
@@ -2030,31 +2016,30 @@ def main(argv=None):
             if secure_fed["start"] == pending_start:
                 return
             secure_fed["start"] = pending_start
-            with trace.span("secure.verify", cat="obs"):
-                sec = {
-                    name: np.asarray(jax.device_get(value))
-                    for name, value in pending_metrics["secure"].items()
-                }
-                sent, recv = sec["digest_sent"], sec["digest_recv"]
-                forged, rejected = sec["forged"], sec["rejected"]
-                if sent.ndim == 2:  # single step -> one-step chunk
-                    sent, recv = sent[None], recv[None]
-                    forged, rejected = forged[None], rejected[None]
-                for i in range(sent.shape[0]):
-                    at_step = pending_start + i + 1
-                    ok = secure_auth.process_step(
-                        at_step, sent[i], recv[i], forged=forged[i]
+            sec = {
+                name: np.asarray(jax.device_get(value))
+                for name, value in pending_metrics["secure"].items()
+            }
+            sent, recv = sec["digest_sent"], sec["digest_recv"]
+            forged, rejected = sec["forged"], sec["rejected"]
+            if sent.ndim == 2:  # single step -> one-step chunk
+                sent, recv = sent[None], recv[None]
+                forged, rejected = forged[None], rejected[None]
+            for i in range(sent.shape[0]):
+                at_step = pending_start + i + 1
+                ok = secure_auth.process_step(
+                    at_step, sent[i], recv[i], forged=forged[i]
+                )
+                if not np.array_equal(~ok, rejected[i].astype(bool)):
+                    # cannot happen by construction (the in-graph
+                    # rejection models exactly the tag-verification
+                    # outcome) — if it does, the simulation drifted
+                    warning(
+                        "secure: host verification disagrees with the "
+                        "in-graph rejection at step %d" % at_step
                     )
-                    if not np.array_equal(~ok, rejected[i].astype(bool)):
-                        # cannot happen by construction (the in-graph
-                        # rejection models exactly the tag-verification
-                        # outcome) — if it does, the simulation drifted
-                        warning(
-                            "secure: host verification disagrees with the "
-                            "in-graph rejection at step %d" % at_step
-                        )
-                    if ledger is not None:
-                        secure_verdicts[at_step] = ~ok
+                if ledger is not None:
+                    secure_verdicts[at_step] = ~ok
 
         # Forensics feed: one ledger observation per completed step, taken
         # from the PREVIOUS dispatch (the same one-step lag as the NaN-abort
@@ -2160,7 +2145,6 @@ def main(argv=None):
                 "reason": reason, "from_step": int(at_step), "to_step": int(rstep),
                 "attempt": attempt, "restored_snapshot": target is not None,
             })
-            g_rollbacks.inc()
             # the ring still holds the diverged timeline's per-step rows —
             # dump them before the restore wipes the state
             flight_postmortem("guardian_rollback", at_step)
@@ -2197,7 +2181,6 @@ def main(argv=None):
                         "rung": rung.describe(), "attempt": attempt,
                         "overrides": overrides.describe(),
                     })
-                    g_escalations.inc()
                     note_escalation(rstep, rung, overrides)
                     if ledger is not None:
                         ledger.note_guardian(rstep, "escalation", {
@@ -2291,7 +2274,6 @@ def main(argv=None):
                         "attempt": watchdog.attempts - 1,
                         "overrides": overrides.describe(),
                     })
-                    g_recoveries.inc()
                     if ledger is not None:
                         ledger.note_guardian(start + i + 1, "recovered", {
                             "attempt": watchdog.attempts - 1,
@@ -2316,6 +2298,7 @@ def main(argv=None):
                 gap["span"].stop()
                 gap["span"] = None
 
+        startup_said = False  # the start-up line, once the first dispatch is out
         # Chaos regime transition logging: host-side tracking of the regime
         # governing the NEXT step to dispatch (under --unroll, transitions
         # inside a chunk surface at the chunk boundary).
@@ -2410,6 +2393,11 @@ def main(argv=None):
                     pending_metrics = metrics
                     pending_start = step
                 step += chunk
+                if startup_said is False:
+                    # the process's way to its first dispatch, by parts
+                    # (docs/observability.md "Reading a start-up")
+                    startup_said = True
+                    info(obs_profiler.startup_summary())
                 c_wire_bytes.inc(chunk * wire_step_bytes)
                 live_state["step"] = step
                 if xprof is not None:

@@ -20,6 +20,7 @@ Experiments self-register by name at import time (reference:
 experiments/__init__.py:76-85).
 """
 
+from ..obs import trace
 from ..utils import ClassRegister, import_directory
 
 experiments = ClassRegister("experiment")
@@ -39,8 +40,11 @@ def get(name):
 
 
 def instantiate(name, args=None):
-    """Build the experiment registered under ``name`` from key:value args."""
-    return experiments.get(name)(args or [])
+    """Build the experiment registered under ``name`` from key:value args: a
+    ``startup.experiment`` of the start-up record (obs/trace.py), with the
+    data set's ``startup.data_host`` inside it."""
+    with trace.startup("startup.experiment", experiment=name):
+        return experiments.get(name)(args or [])
 
 
 class Experiment:

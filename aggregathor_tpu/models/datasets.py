@@ -19,11 +19,13 @@ the engine; on TPU the transfer is one host->device copy per step, the
 equivalent of the reference's dataset-on-task-CPU placement (graph.py:248-252).
 """
 
+import functools
 import os
 import threading
 
 import numpy as np
 
+from ..obs import trace
 from ..utils import UserException, can_access, info, warning
 
 # --------------------------------------------------------------------- #
@@ -153,6 +155,27 @@ class ArrayDataset:
         self.synthetic = synthetic
 
 
+def data_host(rows_and_bytes):
+    """Decorator for whatever materialises a training set on the host: its
+    call is a ``startup.data_host`` of the start-up record (obs/trace.py),
+    with ``rows`` and ``bytes`` of the training split as
+    ``rows_and_bytes(result)`` gives them."""
+    def decorate(load):
+        @functools.wraps(load)
+        def loading(*args, **kwargs):
+            with trace.startup("startup.data_host", loader=load.__name__) as span:
+                made = load(*args, **kwargs)
+                rows, nbytes = rows_and_bytes(made)
+                span.note(rows=int(rows), bytes=int(nbytes))
+            return made
+        return loading
+    return decorate
+
+
+_dataset_host = data_host(
+    lambda dataset: (len(dataset.x_train), dataset.x_train.nbytes + dataset.y_train.nbytes))
+
+
 def _synthetic_classification(name, shape, nb_classes, nb_train, nb_test, seed, separation=2.0):
     """Class-conditional Gaussians around fixed random unit templates."""
     rng = np.random.default_rng(seed)
@@ -218,6 +241,7 @@ def _load_npz(path, shape, scale, nb_classes=None):
     )
 
 
+@_dataset_host
 def load_mnist():
     """28x28x1 digits in [0, 1]; real file or synthetic stand-in."""
     path = _find_npz("mnist.npz")
@@ -226,6 +250,7 @@ def load_mnist():
     return _synthetic_classification("mnist", (28, 28, 1), 10, nb_train=8192, nb_test=2048, seed=7)
 
 
+@_dataset_host
 def load_digits8x8(train_fraction=0.8, seed=11):
     """REAL handwritten digits: the UCI ML hand-written digits set (1797
     8x8 grayscale images, 10 classes) bundled INSIDE scikit-learn — the one
@@ -262,6 +287,7 @@ def load_digits8x8(train_fraction=0.8, seed=11):
     )
 
 
+@_dataset_host
 def load_digits_upscaled(size=32, train_fraction=0.8, seed=11):
     """The REAL digits corpus upscaled to ``size``x``size`` (nearest-
     neighbor, integer factor) — conv-topology input on real data.
@@ -300,6 +326,7 @@ def _find_cifar10_tfrecords():
     return None
 
 
+@_dataset_host
 def load_cifar10():
     """32x32x3 images in [0, 1]; real data (npz, or the reference's slim
     TFRecord shards — experiments/cnnet.py:115-146) or synthetic stand-in."""
@@ -359,6 +386,7 @@ def _find_imagenet_tfrecords():
     return None
 
 
+@_dataset_host
 def load_imagenet(image_size=224, nb_classes=1000, limit_train=4096, limit_test=1024):
     """REAL slim-layout TFRecord ImageNet when shards are on disk
     (reference: experiments/slims.py:98-111 + experiments/datasets/imagenet),

@@ -380,3 +380,12 @@ class SdarExperiment(Experiment):
 
 
 register("sdar", SdarExperiment)
+
+
+# The token rows' start-up span (``startup.data_host``, obs/trace.py) goes on down here, import
+# and all: a line added further up would move the frames of this file that a kernel's
+# serialized body may carry (file and line of each caller), and with them the key of the
+# step program in the persistent compilation cache (PERF.md §6, PR 37).
+from .datasets import data_host  # noqa: E402
+
+seeded_corpus = data_host(lambda rows: (len(rows), rows.nbytes))(seeded_corpus)

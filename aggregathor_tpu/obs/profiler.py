@@ -18,9 +18,12 @@ zero-recompile discipline):
   counter increment plus a tagged ``compile_cache_miss`` summary event
   carrying WHICH executable retraced and the abstract shapes of the
   dispatch that triggered it — the first diagnostic anyone needs when
-  steps/s falls off a cliff.  :func:`install_compile_listener` additionally
-  taps ``jax.monitoring`` for backend-compile totals (catching compiles of
-  executables nobody thought to wrap).
+  steps/s falls off a cliff.  Beside it the process's ONE ``jax.monitoring``
+  listener (:func:`listen_to_compiles`) hears every program traced, lowered,
+  compiled or loaded from the persistent cache, wrapped or not, by name and
+  with its start and end: each is an event of the start-up record
+  (``trace.startup_record``), and :func:`install_compile_listener` points the
+  ``compile_backend_*`` gauges at its totals.
 - :func:`install_memory_gauges` — live/peak device memory bytes from
   ``Device.memory_stats()`` as scrape-time registry gauges (absent on
   backends that do not report, e.g. XLA:CPU).
@@ -31,10 +34,12 @@ import contextlib
 import functools
 import re
 import threading
+import time
 
 import jax
 
 from ..utils import UserException, info
+from . import trace
 
 #: what every step phase's ``jax.named_scope`` starts with (parallel/engine.py
 #: ``phase``): keeps a phase apart from a flax module's or JAX's own
@@ -250,7 +255,7 @@ class ProfilerWindow:
     ``nullcontext`` otherwise, so the inactive path costs one attribute
     read).  :meth:`close` stops a capture left open at shutdown."""
 
-    def __init__(self, spec, trace_dir, registry=None):
+    def __init__(self, spec, trace_dir):
         try:
             begin, _, end = str(spec).partition(":")
             self.begin, self.end = int(begin), int(end)
@@ -263,11 +268,6 @@ class ProfilerWindow:
         self.trace_dir = trace_dir
         self.active = False
         self.done = False
-        if registry is not None:
-            registry.gauge(
-                "profiler_window_active",
-                "1 while a --xprof device capture is recording",
-            ).set_function(lambda: 1.0 if self.active else 0.0)
 
     def maybe_start(self, step):
         """Open the capture when ``step`` enters the window (idempotent;
@@ -321,38 +321,120 @@ class ProfilerWindow:
 # --------------------------------------------------------------------- #
 # compile observability
 
-#: the jax.monitoring duration event emitted once per backend compile
+#: JAX's three stage events (``dispatch.log_elapsed_time``: one time span per
+#: program and stage, ``fun_name`` the program) under the start-up record's
+#: names.  The last fires for a load from the persistent cache as for a compile
 BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+STAGES = {
+    "/jax/core/compile/jaxpr_trace_duration": "compile.trace",
+    "/jax/core/compile/jaxpr_to_mlir_module_duration": "compile.lower",
+    BACKEND_COMPILE_EVENT: "compile.load",
+}
+#: what the persistent cache says of the load under way on the thread
+_CACHE_EVENTS = {"/jax/compilation_cache/cache_hits": "hit",
+                 "/jax/compilation_cache/cache_misses": "miss"}
+_RETRIEVAL_EVENT = "/jax/compilation_cache/cache_retrieval_time_sec"
 
-_monitor = {"installed": False, "count": 0, "seconds": 0.0}
-_monitor_lock = threading.Lock()
+#: the one table of the process's compiles: whether the listener is
+#: registered, ``time.perf_counter`` less ``time.time`` at that moment (JAX's
+#: spans are on the latter, the start-up record on the former), and count and
+#: seconds of the load stage, which outlive the bounded record (the gauges)
+_compiles = {"registered": False, "offset": 0.0, "count": 0, "seconds": 0.0}
+_compiles_lock = threading.Lock()
+_loading = threading.local()  # the cache's word on this thread's load, until its span comes
 
 
-def _monitor_listener(event, duration, **kwargs):
-    if event == BACKEND_COMPILE_EVENT:
-        with _monitor_lock:
-            _monitor["count"] += 1
-            _monitor["seconds"] += float(duration)
+def _monitor_listener(event, *times, fun_name=None, **_):
+    """THE ``jax.monitoring`` listener of the process, registered under all
+    three of its signatures: ``(event)`` for the cache's hit and miss,
+    ``(event, seconds)`` for its retrieval time, ``(event, start, end)`` for a
+    stage of a program.  A stage becomes an event of the start-up record
+    (``trace.startup_event``) under its ``STAGES`` name with ``program`` =
+    ``fun_name`` (``many_p1`` for the trace, ``jit(many_p1)`` for the other
+    two); a load also says ``cache`` (hit, miss, or none where the cache was
+    not asked or did not keep the program) and ``retrieval_s``, both reported
+    inside its span on its thread."""
+    if not times:
+        if event in _CACHE_EVENTS:
+            _loading.cache = _CACHE_EVENTS[event]
+    elif len(times) == 1:
+        if event == _RETRIEVAL_EVENT:
+            _loading.retrieval_s = float(times[0])
+    elif event in STAGES:
+        stage, (start, end) = STAGES[event], times
+        args = {"program": fun_name}
+        if stage == "compile.load":
+            args.update(cache=getattr(_loading, "cache", "none"),
+                        retrieval_s=getattr(_loading, "retrieval_s", None))
+            _loading.cache, _loading.retrieval_s = "none", None
+            with _compiles_lock:
+                _compiles["count"] += 1
+                _compiles["seconds"] += end - start
+        trace.startup_event(stage, start + _compiles["offset"], end - start, **args)
+
+
+def listen_to_compiles():
+    """Register the listener, once a process (``jax.monitoring`` takes no
+    listener back): ``utils.compile_cache.place_compile_cache`` calls this
+    before anything compiles, so every entry point and the benchmark's harness
+    have it without asking."""
+    with _compiles_lock:
+        if _compiles["registered"]:
+            return
+        _compiles["registered"] = True
+        _compiles["offset"] = time.perf_counter() - time.time()
+    jax.monitoring.register_event_listener(_monitor_listener)
+    jax.monitoring.register_event_duration_secs_listener(_monitor_listener)
+    jax.monitoring.register_event_time_span_listener(_monitor_listener)
 
 
 def install_compile_listener(registry):
-    """Count EVERY backend compile in this process (jax.monitoring) into
-    scrape-time gauges ``compile_backend_total`` /
-    ``compile_backend_seconds_total``.  The listener itself installs once
-    per process (jax.monitoring has no per-listener removal); repeated
-    calls only re-point the gauges at the shared accumulator."""
-    with _monitor_lock:
-        if not _monitor["installed"]:
-            jax.monitoring.register_event_duration_secs_listener(_monitor_listener)
-            _monitor["installed"] = True
+    """Point the scrape-time gauges ``compile_backend_total`` /
+    ``compile_backend_seconds_total`` at the listener's totals of the load
+    stage: EVERY program this process compiled or loaded from the persistent
+    cache, wrapped by a ``CompileWatch`` or not.  Repeated calls only re-point
+    the gauges; the trace and lower stages, and each program by name, are in
+    the start-up record (``trace.startup_record``)."""
+    listen_to_compiles()
     registry.gauge(
         "compile_backend_total",
         "Backend compiles observed by jax.monitoring in this process",
-    ).set_function(lambda: float(_monitor["count"]))
+    ).set_function(lambda: float(_compiles["count"]))
     registry.gauge(
         "compile_backend_seconds_total",
         "Wall time jax.monitoring attributes to backend compiles",
-    ).set_function(lambda: _monitor["seconds"])
+    ).set_function(lambda: _compiles["seconds"])
+
+
+def startup_summary():
+    """The start-up record in one line, for an operator: each top-level
+    start-up span by name with its seconds, the three stages of the first
+    dispatcher called (the step program) with the cache's word on its load,
+    and how many programs were loaded in how many seconds altogether
+    (docs/observability.md "Reading a start-up")."""
+    record = trace.startup_record()
+    events = [event for event in record["events"] if event["dur_s"] is not None]
+    parts = {}
+    for event in events:
+        if event["parent"] is None and event["name"].startswith("startup."):
+            parts[event["name"]] = parts.get(event["name"], 0.0) + event["dur_s"]
+    said = ["%s %.2f s" % (name.split(".", 1)[1], seconds) for name, seconds in parts.items()]
+    first = next((event for event in events if event["name"] == "startup.first_call"), None)
+    if first is not None:
+        program = first["args"].get("program")
+        stages = {event["name"]: event for event in events
+                  if event["parent"] == first["id"]
+                  and event["args"].get("program") in (program, "jit(%s)" % program)}
+        told = ["%s %.2f s" % (stage.split(".", 1)[1], stages[stage]["dur_s"])
+                for stage in STAGES.values() if stage in stages]
+        if "compile.load" in stages:
+            told[-1] += " (cache %s)" % stages["compile.load"]["args"]["cache"]
+        said.append("step program %s: %s" % (program, ", ".join(told) or "traced before"))
+    loads = [event["dur_s"] for event in events if event["name"] == "compile.load"]
+    said.append("%d program(s) loaded in %.2f s" % (len(loads), sum(loads)))
+    if record["dropped"]:
+        said.append("%d event(s) dropped" % record["dropped"])
+    return "Start-up: " + "; ".join(said)
 
 
 def describe_abstract(args, kwargs=(), limit=12):

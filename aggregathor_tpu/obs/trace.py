@@ -57,6 +57,11 @@ from install time, and a tracer installing onto a path owned by a LIVE
 sibling writes to a pid-suffixed variant instead — while the trace file
 itself is never touched before the first real save, so a dead writer's
 completed output survives until this run actually has something to say.
+
+One thing is ON from the first import, tracer or none: the **start-up
+record** (:class:`startup`, :func:`startup_record`) — what the process did
+between its start and its first step, named at the program's own boundaries.
+See "the start-up record" below for why it may be.
 """
 
 import functools
@@ -314,11 +319,14 @@ class Tracer:
 def install(path, run_id=None, clock=None):
     """Enable tracing process-wide, writing to ``path`` on :func:`save`.
     Returns the :class:`Tracer`.  Installing over a live tracer replaces it
-    (the old one is saved first)."""
+    (the old one is saved first).  What the start-up record already holds is
+    replayed into the new tracer, so the file shows the process from its first
+    start-up span and not from this call."""
     global _tracer
     if _tracer is not None:
         _tracer.save()
     _tracer = Tracer(path, run_id=run_id, clock=clock)
+    _replay_startup(_tracer)
     return _tracer
 
 
@@ -406,11 +414,11 @@ class span:
         self.__exit__(None, None, None)
 
     def __call__(self, fn):
-        name, cat, args = self.name, self.cat, self.args
+        kind, name, cat, args = type(self), self.name, self.cat, self.args
 
         @functools.wraps(fn)
         def wrapper(*a, **kw):
-            with span(name, cat=cat, **args):
+            with kind(name, cat=cat, **args):
                 return fn(*a, **kw)
 
         return wrapper
@@ -421,6 +429,167 @@ def instant(name, cat="host", **args):
     tracer = _tracer
     if tracer is not None:
         tracer.instant(name, cat=cat, args=args)
+
+
+# --------------------------------------------------------------------- #
+# the start-up record
+
+#: most events the start-up record keeps; past it they are counted as dropped
+#: (the largest set-up measured holds 15,509 at its first step: ResNet-50
+#: under Bulyan, every distinct ``jnp`` call of the step a traced event)
+STARTUP_MAX_EVENTS = 65536
+
+_startup_lock = threading.Lock()
+_startup_events = []
+_startup_dropped = 0
+
+
+def _startup_stack():
+    """This thread's open start-up spans: their places in the record (None
+    for one the full record dropped), innermost last."""
+    stack = getattr(_local, "startup", None)
+    if stack is None:
+        stack = _local.startup = []
+    return stack
+
+
+def _startup_append(name, start_s, dur_s, args):
+    """One event into the record under this thread's innermost open start-up
+    span; returns the event, or None where the record is full."""
+    global _startup_dropped
+    stack = _startup_stack()
+    event = {"name": name, "start_s": start_s, "dur_s": dur_s,
+             "parent": stack[-1] if stack else None,
+             "thread": threading.get_ident(), "args": dict(args)}
+    with _startup_lock:
+        if len(_startup_events) >= STARTUP_MAX_EVENTS:
+            _startup_dropped += 1
+            return None
+        event["id"] = len(_startup_events)
+        _startup_events.append(event)
+    return event
+
+
+class startup(span):
+    """A :class:`span` of category ``startup`` that ALSO goes into the
+    process's start-up record, tracer or none: ``with startup("startup.engine"):``
+    or ``@startup("startup.mesh")``.
+
+    The record is what a restart costs, named from inside: a process-wide
+    list of ``{name, start_s, dur_s, parent, thread, args}`` on
+    ``time.perf_counter``, ``parent`` the place in the record of the enclosing
+    start-up span of the same thread (None at the top), ``dur_s`` None while
+    the span is open.  It is on always, which the rest of this module is not,
+    and may be: it is written at the boundaries a process passes once on its
+    way to the first step (experiment, data, mesh, engine, state, the first
+    call of each dispatcher) and by JAX's own stage events, which fire when a
+    program is traced, lowered or loaded and never when one runs
+    (``obs.profiler.listen_to_compiles``) — a dozen spans a process, and
+    2,400 to 15,500 stage events by its first step in the benchmark's cells
+    (19,800 by the end of a run; nearly all of them the nested traces of
+    ``jnp`` calls) at a few microseconds each, against set-ups of 20 to 65 s
+    in which no pair of runs could tell the record on from off (PERF.md §6,
+    PR 37); none inside the step loop.  It is bounded (``STARTUP_MAX_EVENTS``,
+    then a dropped count), so a process that rebuilds its engine for ever
+    fills it and stops.  A tracer installed
+    later (the runner installs after the backend is up) is handed what the
+    record holds (:func:`install`); one installed already gets each span the
+    usual way, once.
+
+    ``note(bytes=...)`` adds arguments known only when the work is done."""
+
+    __slots__ = ("_event",)
+
+    def __init__(self, name, cat="startup", **args):
+        super().__init__(name, cat=cat, **args)
+        self._event = None
+
+    def __enter__(self):
+        self._event = _startup_append(self.name, time.perf_counter(), None, self.args)
+        _startup_stack().append(None if self._event is None else self._event["id"])
+        return super().__enter__()
+
+    def __exit__(self, exc_type, exc, tb):
+        super().__exit__(exc_type, exc, tb)
+        _startup_stack().pop()
+        if self._event is not None:
+            self._event["dur_s"] = time.perf_counter() - self._event["start_s"]
+        return False
+
+    start = __enter__
+
+    def note(self, **args):
+        self.args = dict(self.args, **args)
+        if self._event is not None:
+            self._event["args"].update(args)
+
+
+def startup_event(name, start_s, dur_s, **args):
+    """A finished event for the start-up record from a clock reading made
+    elsewhere (JAX's stage events, ``obs.profiler``): ``start_s`` on
+    ``time.perf_counter``.  Its parent is this thread's open start-up span;
+    events of this kind that nest in each other are sorted out by time when
+    the record is read (:func:`startup_record`), since an inner one ends, and
+    is reported, before the one round it.  An installed tracer gets it too."""
+    _startup_append(name, start_s, dur_s, args)
+    tracer = _tracer
+    if tracer is not None and tracer._clock is time.perf_counter:
+        tracer.complete_at(name, (start_s - tracer._epoch) * 1e6, dur_s * 1e6,
+                           threading.get_ident(), cat="startup", args=args)
+
+
+def _nest_by_time(events):
+    """Re-parent the finished events that share a thread and a parent and
+    contain one another in time: the innermost container becomes the parent.
+    Spans entered and left on one thread never half-overlap, so one sweep in
+    order of start does it; of two events over the very same interval the one
+    reported later is the outer (it ended later in program order)."""
+    groups = {}
+    for event in events:
+        if event["dur_s"] is not None:
+            groups.setdefault((event["thread"], event["parent"]), []).append(event)
+    for group in groups.values():
+        group.sort(key=lambda e: (e["start_s"], -e["dur_s"], -e["id"]))
+        open_ = []  # (end, id) of the events round the one at hand
+        for event in group:
+            end = event["start_s"] + event["dur_s"]
+            while open_ and open_[-1][0] < end - 1e-6:  # JAX's clock ticks in 0.24 us
+                open_.pop()
+            if open_:
+                event["parent"] = open_[-1][1]
+            open_.append((end, event["id"]))
+
+
+def startup_record():
+    """A copy of the start-up record: ``{"events": [...], "dropped": n,
+    "limit": STARTUP_MAX_EVENTS}``, an event's ``id`` its place in the list
+    and ``parent`` the ``id`` of the event that encloses it — for a start-up
+    span the one open on its thread when it was entered, for JAX's stage
+    events also the stage event round them (a ``jit`` traced inside a traced
+    function).  Self time is an event's ``dur_s`` less its children's."""
+    with _startup_lock:
+        events = [dict(event, args=dict(event["args"])) for event in _startup_events]
+        dropped = _startup_dropped
+    _nest_by_time(events)
+    return {"events": events, "dropped": dropped, "limit": STARTUP_MAX_EVENTS}
+
+
+def _replay_startup(tracer):
+    """Hand a fresh tracer the finished events of the start-up record, on its
+    own clock moved back to the first of them (a ``ts`` is never negative).  A
+    tracer on a clock of its own (tests) shares no epoch with the record and
+    gets nothing."""
+    if tracer._clock is not time.perf_counter:
+        return
+    with _startup_lock:
+        events = [event for event in _startup_events if event["dur_s"] is not None]
+    if not events:
+        return
+    tracer._epoch = min(tracer._epoch, min(event["start_s"] for event in events))
+    for event in events:
+        tracer.complete_at(event["name"], (event["start_s"] - tracer._epoch) * 1e6,
+                           event["dur_s"] * 1e6, event["thread"], cat="startup",
+                           args=dict(event["args"], replayed=True))
 
 
 def _abstract(leaf):
@@ -447,13 +616,15 @@ class TracedCallable:
     (``_cache_size``, ``lower``, ...) falls through to the wrapped function,
     so compile-count assertions and AOT APIs keep working, and the jit
     cache is untouched (tracing adds zero recompiles by construction).
-    ``inner`` is the unwrapped callable (the overhead benchmark's
-    uninstrumented baseline).
+    ``inner`` is the unwrapped callable.
 
     On its FIRST call only (an ``is None`` test on every later one) it
     remembers the abstract signature of its arguments, taken before the call
     since a step donates its state; :meth:`compiled_text` hands the compiled
-    program's text to whoever wants to read it (``profiler.phase_table``)."""
+    program's text to whoever wants to read it (``profiler.phase_table``).
+    That first call is also a ``startup.first_call`` of the start-up record
+    (:class:`startup`): the tracing, lowering and loading of the program
+    happen inside it, and JAX's stage events land under it."""
 
     __slots__ = ("inner", "_name", "_cat", "_signature", "__weakref__")
 
@@ -465,7 +636,13 @@ class TracedCallable:
 
     def __call__(self, *args, **kwargs):
         if self._signature is None:
-            object.__setattr__(self, "_signature", _abstract_signature(args, kwargs))
+            signature = _abstract_signature(args, kwargs)
+            if signature is not None:  # a call under a trace starts nothing
+                object.__setattr__(self, "_signature", signature)
+                with startup("startup.first_call", dispatcher=self._name,
+                             program=getattr(self.inner, "__name__", None)):
+                    with span(self._name, cat=self._cat):
+                        return self.inner(*args, **kwargs)
         with span(self._name, cat=self._cat):
             return self.inner(*args, **kwargs)
 

@@ -2801,3 +2801,53 @@ class ShardedRobustEngine(RobustEngine):
             chaos=chaos, health_probe=health_probe, secure=secure,
             flight=flight, sharding="sharded",
         )
+
+
+# --------------------------------------------------------------------- #
+# Start-up spans (obs/trace.py ``startup``; docs/observability.md "Reading a
+# start-up") at the engine's boundaries: ``startup.engine`` (the constructor),
+# ``startup.build_step`` (the three builders: closures only, ~0 — a guard),
+# ``startup.put`` (``replicate``: the enqueue as the host sees it; the
+# resident data set's at the top level, the state's inside the next; nothing
+# in a step loop calls it) and ``startup.state_init`` (``init_state``, with
+# the programs it loads leaf by leaf inside it).
+#
+# They are put on HERE, below every definition, and not as decorators or
+# ``with`` blocks where the methods stand: a Mosaic kernel's serialized body
+# carries the file and line of every frame that called it, so a line added
+# above a step body would re-key the persistent compilation cache of every
+# step program that holds a kernel (PERF.md §6, PR 37).
+
+import functools  # noqa: E402
+
+
+def _nbytes(tree):
+    return int(sum(getattr(leaf, "nbytes", 0) for leaf in jax.tree_util.tree_leaves(tree)))
+
+
+def _put_span(replicate):
+    @functools.wraps(replicate)
+    def replicating(self, tree):
+        with trace.startup("startup.put", bytes=_nbytes(tree)):
+            return replicate(self, tree)
+
+    return replicating
+
+
+def _state_init_span(init_state):
+    @functools.wraps(init_state)
+    def initialising(self, *args, seed=0):
+        with trace.startup("startup.state_init") as started:
+            state = init_state(self, *args, seed=seed)
+            started.note(leaves=len(jax.tree_util.tree_leaves(state)), bytes=_nbytes(state))
+        return state
+
+    return initialising
+
+
+RobustEngine.__init__ = trace.startup("startup.engine")(RobustEngine.__init__)
+for _builder in ("build_step", "build_multi_step", "build_sampled_multi_step"):
+    setattr(RobustEngine, _builder,
+            trace.startup("startup.build_step")(getattr(RobustEngine, _builder)))
+RobustEngine.replicate = _put_span(RobustEngine.replicate)
+RobustEngine.init_state = _state_init_span(RobustEngine.init_state)

@@ -15,12 +15,14 @@ engine changes between one chip and a multi-host pod.
 import jax
 
 from .. import config
+from ..obs import trace
 
 worker_axis = config.worker_axis
 pipe_axis = config.pipe_axis
 model_axis = config.model_axis
 
 
+@trace.startup("startup.mesh")
 def make_mesh(nb_workers=None, model_parallelism=1, pipeline_parallelism=1, devices=None):
     """Build a Mesh with axes ``(worker, pipe, model)``.
 

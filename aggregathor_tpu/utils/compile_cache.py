@@ -1,8 +1,12 @@
 """Where the persistent compilation cache lives — decided from outside.
 
 Entry points (cli.runner, cli.serve, chip_smoke.py,
-scripts/pallas_tpu_check.py) call :func:`place_compile_cache` before their
-first compile; importing the library sets nothing.  A machine that wants the
+scripts/pallas_tpu_check.py, grid/run.py) call :func:`place_compile_cache`
+before their first compile; importing the library sets nothing and listens to
+nothing.  The call also registers the process's one ``jax.monitoring``
+listener (``obs.profiler.listen_to_compiles``): it is the call that decides
+whether a program's load is a hit, so it is where the record of each
+program's trace, lowering and load starts.  A machine that wants the
 cache to outlive the process exports ``JAX_COMPILATION_CACHE_DIR`` and JAX
 reads it; otherwise the cache sits at one fixed path inside the checkout
 (the path is part of the cache key on some backends, so it never carries a
@@ -29,10 +33,17 @@ def place_compile_cache():
     Every program is cached, however quickly it compiled: a run is dozens of
     sub-second programs around a few large ones, and a warm second process
     should compile nothing.  Initializes the backend (the platform choice
-    must be pinned before the call)."""
+    must be pinned before the call), inside a ``startup.backend`` span of the
+    start-up record: whoever touches the backend first pays for its start,
+    and an entry point that has not yet pays here."""
     import jax
 
-    if jax.default_backend() == "cpu":
+    from ..obs import profiler, trace
+
+    profiler.listen_to_compiles()  # on a CPU backend too: the tests' path is the chip's
+    with trace.startup("startup.backend"):
+        backend = jax.default_backend()
+    if backend == "cpu":
         return None
     directory = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if not directory:

@@ -242,8 +242,7 @@ def test_sharded_engine_ring_matches_metrics():
 
 
 def test_profiler_window_parses_and_rejects():
-    reg = MetricsRegistry()
-    window = profiler.ProfilerWindow("4:8", "/tmp/nowhere", registry=reg)
+    window = profiler.ProfilerWindow("4:8", "/tmp/nowhere")
     assert (window.begin, window.end) == (4, 8)
     assert not window.maybe_start(3)  # outside the window
     for bad in ("8:4", "4", "a:b", "-1:3", "4:4"):

@@ -6,6 +6,7 @@ forensics ledger (attribution on synthetic and real suspicion streams)."""
 import json
 import os
 import threading
+import time
 
 import jax
 import numpy as np
@@ -282,7 +283,7 @@ def test_span_thread_safety(tracer):
         t.join()
     assert not errors
     events = trace.validate_chrome_trace(json.load(open(trace.save())))
-    spans = [e for e in events if e["ph"] == "X"]
+    spans = [e for e in events if e["ph"] == "X" and e["cat"] == "t"]  # not the start-up's
     assert len(spans) == 8 * 50 * 2
     for event in spans:
         name = event["name"]
@@ -359,6 +360,202 @@ def test_engine_instrumentation_zero_extra_compiles():
         assert names.count("train_step.dispatch") == 2
     finally:
         trace.uninstall(save=False)
+
+
+# --------------------------------------------------------------------- #
+# the start-up record (obs/trace.py ``startup``, ``startup_record``)
+
+
+@pytest.fixture
+def startup_fresh(monkeypatch):
+    """An empty start-up record for the test; the process's own comes back."""
+    monkeypatch.setattr(trace, "_startup_events", [])
+    monkeypatch.setattr(trace, "_startup_dropped", 0)
+
+
+def _self_seconds(events):
+    own = {event["id"]: event["dur_s"] for event in events}
+    for event in events:
+        if event["parent"] is not None:
+            own[event["parent"]] -= event["dur_s"]
+    return own
+
+
+def test_startup_record_is_bounded_and_counts_drops(startup_fresh, monkeypatch):
+    monkeypatch.setattr(trace, "STARTUP_MAX_EVENTS", 5)
+    for i in range(4):
+        with trace.startup("startup.part", index=i):
+            trace.startup_event("compile.load", 0.0, 0.0, program="p%d" % i)
+    record = trace.startup_record()
+    assert len(record["events"]) == 5 and record["dropped"] == 3 and record["limit"] == 5
+    assert [event["id"] for event in record["events"]] == list(range(5))
+    # a span the full record dropped is no parent, and leaves the stack as it found it
+    with trace.startup("startup.late"):
+        pass
+    assert trace._startup_stack() == [] and trace.startup_record()["dropped"] == 4
+
+
+def test_startup_parent_and_self_time_under_nesting_and_two_threads(startup_fresh):
+    def work(label):
+        with trace.startup("startup.outer", label=label):
+            with trace.startup("startup.inner", label=label) as inner:
+                time.sleep(0.01)
+                inner.note(bytes=7)
+            with trace.startup("startup.inner", label=label):
+                time.sleep(0.01)
+
+    other = threading.Thread(target=work, args=("other",))
+    other.start()
+    work("main")
+    other.join()
+    events = trace.startup_record()["events"]
+    assert len(events) == 6 and len({event["thread"] for event in events}) == 2
+    by_id = {event["id"]: event for event in events}
+    own = _self_seconds(events)
+    for outer in (event for event in events if event["name"] == "startup.outer"):
+        assert outer["parent"] is None
+        inners = [event for event in events if event["parent"] == outer["id"]]
+        assert [event["name"] for event in inners] == ["startup.inner"] * 2
+        # a thread's spans nest in that thread's spans only
+        assert {event["thread"] for event in inners} == {outer["thread"]}
+        assert {event["args"]["label"] for event in inners} == {outer["args"]["label"]}
+        assert inners[0]["args"]["bytes"] == 7 and "bytes" not in inners[1]["args"]
+        assert sum(event["dur_s"] for event in inners) <= outer["dur_s"]
+        assert 0 <= own[outer["id"]] < outer["dur_s"] - 0.015
+    assert all(by_id[event["parent"]]["name"] == "startup.outer"
+               for event in events if event["name"] == "startup.inner")
+
+
+def test_startup_events_nest_by_time_not_by_order_of_report(startup_fresh):
+    """JAX reports an inner stage when it ends, before the one round it: the
+    record sorts them out by time, within one thread and one start-up span."""
+    with trace.startup("startup.first_call"):
+        trace.startup_event("compile.trace", 1.2, 0.1, program="_where")
+        trace.startup_event("compile.trace", 1.1, 0.3, program="inner")
+        trace.startup_event("compile.trace", 1.5, 0.1, program="add")
+        trace.startup_event("compile.trace", 1.0, 1.0, program="outer")
+        trace.startup_event("compile.lower", 2.0, 0.5, program="jit(outer)")
+    trace.startup_event("compile.trace", 1.05, 0.5, program="elsewhere")  # under no span
+    events = trace.startup_record()["events"]
+    named = {event["args"].get("program"): event for event in events}
+    first_call = events[0]["id"]
+    assert named["outer"]["parent"] == first_call == named["jit(outer)"]["parent"]
+    assert named["inner"]["parent"] == named["outer"]["id"] == named["add"]["parent"]
+    assert named["_where"]["parent"] == named["inner"]["id"]
+    assert named["elsewhere"]["parent"] is None
+    own = _self_seconds(events)
+    assert own[named["outer"]["id"]] == pytest.approx(1.0 - 0.3 - 0.1)
+    assert own[named["inner"]["id"]] == pytest.approx(0.3 - 0.1)
+
+
+def test_startup_records_with_no_tracer_installed(startup_fresh):
+    assert trace.installed() is None
+
+    @trace.startup("startup.mesh", axes=3)
+    def make():
+        return 5
+
+    assert make() == 5 and make() == 5
+    events = trace.startup_record()["events"]
+    assert [(event["name"], event["args"]) for event in events] == [
+        ("startup.mesh", {"axes": 3})] * 2
+    assert all(event["dur_s"] >= 0 and event["parent"] is None for event in events)
+    # the copy is the caller's
+    events[0]["args"]["axes"] = 0
+    assert trace.startup_record()["events"][0]["args"] == {"axes": 3}
+
+
+def test_startup_with_a_tracer_records_once_in_each(startup_fresh, tracer):
+    with trace.startup("startup.engine", workers=4):
+        trace.startup_event("compile.load", time.perf_counter(), 0.25, program="jit(f)")
+    in_record = [event["name"] for event in trace.startup_record()["events"]]
+    assert in_record == ["startup.engine", "compile.load"]
+    events = trace.validate_chrome_trace(json.load(open(trace.save())))
+    in_tracer = [(e["name"], e["cat"]) for e in events if e["ph"] == "X"]
+    assert sorted(in_tracer) == [("compile.load", "startup"), ("startup.engine", "startup")]
+    load = next(e for e in events if e["name"] == "compile.load")
+    assert load["dur"] == pytest.approx(0.25e6) and load["args"] == {"program": "jit(f)"}
+
+
+def test_install_replays_the_startup_record(startup_fresh, tmp_path):
+    with trace.startup("startup.experiment", experiment="mnist"):
+        with trace.startup("startup.data_host"):
+            pass
+    trace.startup_event("compile.load", time.perf_counter() - 1.0, 0.5, program="jit(init)")
+    trace.install(str(tmp_path / "late.trace.json"), run_id="late")
+    try:
+        with trace.span("dispatch", cat="train"):
+            pass
+        payload = json.load(open(trace.save()))
+    finally:
+        trace.uninstall(save=False)
+    spans = [e for e in trace.validate_chrome_trace(payload) if e["ph"] == "X"]
+    assert [e["name"] for e in spans] == [
+        "startup.experiment", "startup.data_host", "compile.load", "dispatch"]
+    replayed = [e for e in spans if e["args"].get("replayed")]
+    assert len(replayed) == 3 and all(e["cat"] == "startup" for e in replayed)
+    # one clock: what came before the install comes before it in the file, from 0 on
+    assert min(e["ts"] for e in spans) == 0.0
+    assert all(e["ts"] + e["dur"] <= spans[-1]["ts"] for e in replayed)
+    outer, inner = spans[0], spans[1]
+    assert outer["ts"] <= inner["ts"] and inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"]
+    # the record is as it was: a replay moves nothing
+    assert len(trace.startup_record()["events"]) == 3
+
+
+def test_startup_summary_is_one_line_for_an_operator(startup_fresh):
+    from aggregathor_tpu.obs import profiler
+
+    with trace.startup("startup.experiment"):
+        with trace.startup("startup.data_host"):
+            pass
+    trace.startup_event("compile.load", 10.0, 0.5, program="jit(init)", cache="hit")
+    with trace.startup("startup.first_call", dispatcher="train_step.dispatch", program="body_p1"):
+        trace.startup_event("compile.trace", 20.5, 0.25, program="add")
+        trace.startup_event("compile.trace", 20.0, 1.5, program="body_p1")
+        trace.startup_event("compile.lower", 21.5, 0.5, program="jit(body_p1)")
+        trace.startup_event("compile.load", 22.0, 3.0, program="jit(body_p1)", cache="miss")
+    line = profiler.startup_summary()
+    assert line.startswith("Start-up: experiment 0.00 s; first_call 0.00 s; ")
+    assert "data_host" not in line  # inside startup.experiment: not a top-level part
+    assert "step program body_p1: trace 1.50 s, lower 0.50 s, load 3.00 s (cache miss); " in line
+    assert line.endswith("2 program(s) loaded in 3.50 s") and "\n" not in line
+
+
+def test_traced_callable_first_call_is_one_startup_event(startup_fresh):
+    jitted = jax.jit(lambda x: x * 3.0)
+    wrapped = trace.traced("triple.dispatch", jitted)
+    # a call under a trace dispatches nothing, and starts nothing
+    spans = lambda: [e for e in trace.startup_record()["events"]
+                     if e["name"].startswith("startup.")]
+    jax.make_jaxpr(wrapped)(jax.ShapeDtypeStruct((2,), np.float32))
+    assert spans() == []
+    assert float(wrapped(np.ones(2, np.float32))[0]) == 3.0
+    first = spans()
+    assert [(e["name"], e["args"]) for e in first] == [
+        ("startup.first_call", {"dispatcher": "triple.dispatch", "program": "<lambda>"})]
+    held, size = len(trace.startup_record()["events"]), wrapped._cache_size()
+    for _ in range(100):
+        wrapped(np.ones(2, np.float32))
+    assert len(trace.startup_record()["events"]) == held
+    assert wrapped._cache_size() == size == 1
+
+
+def test_the_paths_that_were_free_touch_no_startup_record(monkeypatch):
+    """``span`` and ``instant`` with no tracer, and a dispatcher past its first
+    call, run what they ran before the record existed."""
+    wrapped = trace.traced("free.dispatch", jax.jit(lambda x: x + 1.0))
+    wrapped(np.float32(1.0))
+
+    def refuse(*_args, **_kwargs):
+        raise AssertionError("the start-up record was touched")
+
+    for name in ("_startup_append", "_startup_stack", "startup_event", "startup"):
+        monkeypatch.setattr(trace, name, refuse)
+    assert trace.installed() is None
+    with trace.span("plain", cat="test"):
+        trace.instant("tick")
+    assert float(wrapped(np.float32(2.0))) == 3.0
 
 
 # --------------------------------------------------------------------- #

@@ -8,6 +8,7 @@ step by phase.  ``obs.trace.TracedCallable.compiled_text`` hands it the text;
 import glob
 import os
 import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -243,10 +244,10 @@ def test_compiled_text_leaves_the_jit_cache_alone():
     assert text.startswith("HloModule jit_many_p%d," % PHASES_REVISION)
     assert step._cache_size() == 1
     profiler.install_compile_listener(MetricsRegistry())
-    compiles = profiler._monitor["count"]
+    compiles = profiler._compiles["count"]
     state, metrics = step(state, fed)
     jax.block_until_ready(metrics["total_loss"])
-    assert step._cache_size() == 1 and profiler._monitor["count"] == compiles
+    assert step._cache_size() == 1 and profiler._compiles["count"] == compiles
 
 
 def test_compiled_text_before_the_first_call_is_refused():
@@ -279,3 +280,132 @@ def test_program_spans_reach_the_profiler_with_no_tracer_installed(tmp_path):
              for plane in ProfileData.from_file(written[-1]).planes if plane.name.startswith("/host:")
              for line in plane.lines for event in line.events}
     assert {"phase_test.span", "phase_test.dispatch"} <= names
+
+
+# --------------------------------------------------------------------- #
+# JAX's stage events, by program (obs/profiler.py ``listen_to_compiles``)
+
+
+def stages_since(mark):
+    return [event for event in trace.startup_record()["events"]
+            if event["id"] >= mark and event["name"].startswith("compile.")]
+
+
+def test_the_listener_names_each_stage_of_a_program():
+    from aggregathor_tpu.utils.compile_cache import place_compile_cache
+
+    assert place_compile_cache() is None  # a CPU: no cache, and the listener all the same
+    assert profiler._compiles["registered"]
+    mark = len(trace.startup_record()["events"])
+
+    def stage_named(x):
+        return x * 41.5
+
+    jax.jit(stage_named)(jnp.ones(3))
+    mine = [event for event in stages_since(mark)
+            if event["args"]["program"] in ("stage_named", "jit(stage_named)")]
+    assert [(event["name"], event["args"]["program"]) for event in mine] == [
+        ("compile.trace", "stage_named"), ("compile.lower", "jit(stage_named)"),
+        ("compile.load", "jit(stage_named)")]
+    assert mine[2]["args"]["cache"] == "none" and mine[2]["args"]["retrieval_s"] is None
+    # one clock with the program's own spans, and one thread
+    assert all(event["thread"] == mine[0]["thread"] and event["dur_s"] >= 0 for event in mine)
+    assert mine[0]["start_s"] + mine[0]["dur_s"] <= mine[1]["start_s"] + 1e-3
+    assert abs(mine[2]["start_s"] + mine[2]["dur_s"] - time.perf_counter()) < 5.0
+
+
+def test_a_nested_jit_is_a_child_of_the_trace_round_it():
+    profiler.listen_to_compiles()
+    mark = len(trace.startup_record()["events"])
+
+    @jax.jit
+    def nested_inside(x):
+        return jnp.where(x > 0, x, 0.25)
+
+    def nesting_outside(x):
+        return nested_inside(x) + 1.5
+
+    with trace.startup("startup.first_call", dispatcher="test"):
+        jax.jit(nesting_outside)(jnp.ones(5))
+    events = {event["id"]: event for event in trace.startup_record()["events"]}
+    traces = {event["args"]["program"]: event for event in stages_since(mark)
+              if event["name"] == "compile.trace"}
+    outer, inner = traces["nesting_outside"], traces["nested_inside"]
+    assert inner["parent"] == outer["id"]  # a child, not a sibling
+    assert events[outer["parent"]]["name"] == "startup.first_call"
+    assert events[traces["_where"]["parent"]]["args"]["program"] == "nested_inside"
+    children = sum(event["dur_s"] for event in traces.values() if event["parent"] == outer["id"])
+    assert 0 <= outer["dur_s"] - children <= outer["dur_s"]  # self time is never a sum
+
+
+def test_registering_twice_hears_each_event_once():
+    profiler.listen_to_compiles()
+    registry = MetricsRegistry()
+    profiler.install_compile_listener(registry)
+    profiler.install_compile_listener(registry)
+    profiler.listen_to_compiles()
+    from jax._src import monitoring
+
+    for listeners in (monitoring.get_event_listeners(),
+                      monitoring.get_event_duration_listeners(),
+                      monitoring.get_event_time_span_listeners()):
+        assert listeners.count(profiler._monitor_listener) == 1
+    families = {family.name: family for family in registry.families()}
+    fed = jnp.ones(2)  # a program of its own: made before the count is read
+    mark = len(trace.startup_record()["events"])
+    before = families["compile_backend_total"].value
+    seconds = families["compile_backend_seconds_total"].value
+
+    def heard_once(x):
+        return x - 17.25
+
+    jax.jit(heard_once)(fed)
+    mine = [event for event in stages_since(mark) if "heard_once" in event["args"]["program"]]
+    assert [event["name"] for event in mine] == ["compile.trace", "compile.lower", "compile.load"]
+    assert families["compile_backend_total"].value == before + 1
+    assert families["compile_backend_seconds_total"].value == pytest.approx(
+        seconds + mine[2]["dur_s"])
+
+
+def test_the_cache_s_word_lands_on_the_load_it_was_said_of():
+    """A hit, its retrieval time and a miss, as ``compiler.compile_or_get_cached``
+    reports them: inside the load's span, on its thread."""
+    profiler.listen_to_compiles()
+    mark = len(trace.startup_record()["events"])
+    said = jax.monitoring
+    said.record_event("/jax/compilation_cache/cache_hits")
+    said.record_event_duration_secs("/jax/compilation_cache/cache_retrieval_time_sec", 0.5)
+    now = time.time()
+    said.record_event_time_span(profiler.BACKEND_COMPILE_EVENT, now - 1.0, now,
+                                fun_name="jit(from_the_cache)")
+    said.record_event("/jax/compilation_cache/cache_misses")
+    said.record_event_time_span(profiler.BACKEND_COMPILE_EVENT, now, now + 2.0,
+                                fun_name="jit(compiled)")
+    said.record_event_time_span(profiler.BACKEND_COMPILE_EVENT, now, now, fun_name="jit(unasked)")
+    hit, miss, unasked = (event["args"] for event in stages_since(mark))
+    assert hit == {"program": "jit(from_the_cache)", "cache": "hit", "retrieval_s": 0.5}
+    assert miss == {"program": "jit(compiled)", "cache": "miss", "retrieval_s": None}
+    assert unasked == {"program": "jit(unasked)", "cache": "none", "retrieval_s": None}
+
+
+def test_the_step_lowers_to_the_same_text_with_the_record_on_or_off(monkeypatch):
+    """The record is host-side: the program a step lowers to carries nothing of it."""
+    def lowered():
+        engine = RobustEngine(
+            make_mesh(nb_workers=4, devices=jax.devices()[:4]),
+            gars.instantiate("bulyan", NB_WORKERS, NB_BYZ), nb_workers=NB_WORKERS,
+            batch_transform=flip)
+        tx = marked_optimizer()
+        state = engine.init_state(init_params(jax.random.PRNGKey(0)), tx, seed=1)
+        step = engine.build_sampled_multi_step(model_loss, tx, repeat_steps=2, batch_size=BATCH)
+        return step.lower(state, engine.replicate(data_set(jax.random.PRNGKey(1)))).as_text()
+
+    profiler.listen_to_compiles()
+    mark = len(trace.startup_record()["events"])
+    with_record = lowered()
+    assert len(trace.startup_record()["events"]) > mark
+    monkeypatch.setattr(trace, "_startup_append", lambda *_args: None)
+    mark = len(trace.startup_record()["events"])
+    without = lowered()
+    assert len(trace.startup_record()["events"]) == mark
+    assert with_record == without
