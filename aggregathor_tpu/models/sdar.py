@@ -29,12 +29,16 @@ The layer, every width as published:
   half; loss = (1/L) sum over masked i of (1/t) * -log softmax(logits_i)[x_0^i].
 
 How it is computed here: the layers run under ``lax.scan`` with
-``jax.checkpoint``; attention takes the queries a chunk at a time and folds
-the two key ranges a chunk may read (the clean prefix, its own noisy chunk)
-into the running softmax it shares with models/transformer.py, so no 2L x 2L
-score tensor exists and nothing is computed for the clean->noisy quarter or
-above the block diagonal's chunk; each held expert runs over every position
-under the weight the router gave it there (``moe`` says why).
+``jax.checkpoint``; attention hands q, k, v and the mask as a predicate
+(``BlockDiffusion``) to ops/attention.py, whose fused kernel runs on a TPU at
+the shapes it takes; everywhere else (the CPU, and the kernel's oracle in the
+tests) it is ``chunked_attention``, plain XLA: the queries a chunk at a time,
+each folding the two key ranges a chunk may read (the clean prefix, its own
+noisy chunk) into the running softmax it shares with models/transformer.py.
+Either way no 2L x 2L score tensor exists and nothing is computed for the
+clean->noisy quarter or above the block diagonal's chunk (the kernel's tile
+table skips those tiles); each held expert runs over every position under the
+weight the router gave it there (``moe`` says why).
 """
 
 import dataclasses
@@ -47,6 +51,7 @@ import numpy as np
 
 from . import Experiment, register
 from ..utils import UserException, parse_keyval
+from ..ops.attention import attend
 from .common import check_dtype
 from .transformer import _NEG, online_softmax_step, rms_norm, rope, rope_frequencies
 
@@ -144,11 +149,50 @@ def allowed(q_pos, q_noisy, k_pos, k_noisy, block):
     return jnp.where(qn, jnp.where(kn, kb == qb, kb < qb), ~kn & (kb <= qb))
 
 
+@dataclasses.dataclass(frozen=True)
+class BlockDiffusion:
+    """``allowed`` as a predicate of ops/attention.py, over the indices of a
+    sequence [noisy ; clean] of ``half`` positions each cut into blocks of
+    ``block``: a noisy query reads its own noisy block and the clean blocks
+    before it, a clean query the clean blocks up to its own.
+
+    It runs on numpy indices at trace time (``tile_table``) and on int32 iotas
+    inside the Mosaic kernel (``_scores``), so it is written in what lowers
+    there: ``index % half`` as a compare and a subtract (an index lies under
+    ``2 * half``), ``// block`` as a shift where ``block`` is a power of two,
+    and the four rules as comparisons joined by ``&`` and ``|`` — no select
+    over booleans."""
+
+    half: int
+    block: int
+
+    def _block_of(self, index, clean):
+        position = jnp.where(clean, index - self.half, index)
+        if self.block & (self.block - 1) == 0:
+            return position >> (self.block.bit_length() - 1)
+        return position // self.block
+
+    def __call__(self, q_index, k_index):
+        q_noisy, q_clean = q_index < self.half, q_index >= self.half
+        k_noisy, k_clean = k_index < self.half, k_index >= self.half
+        q_block, k_block = self._block_of(q_index, q_clean), self._block_of(k_index, k_clean)
+        same, earlier = k_block == q_block, k_block < q_block
+        return (q_noisy & k_noisy & same) | (k_clean & (earlier | (q_clean & same)))
+
+
 def masked_attention(q, k, v, cfg):
     """q (B, 2L, G, R, Dh), k and v (B, 2L, G, Dh), halves [noisy ; clean] ->
-    (B, 2L, G * R * Dh).  Chunk i of the queries (its noisy and its clean
-    positions together) reads the clean keys up to its own end and its own
-    noisy keys: two folds of the running softmax."""
+    (B, 2L, G * R * Dh): ops/attention.py's fused kernel under the
+    ``BlockDiffusion`` predicate where ``attention_form`` says so (a TPU, at
+    a shape the kernel takes), ``chunked_attention`` everywhere else."""
+    return attend(q, k, v, BlockDiffusion(q.shape[1] // 2, cfg.block),
+                  lambda q, k, v: chunked_attention(q, k, v, cfg))
+
+
+def chunked_attention(q, k, v, cfg):
+    """The XLA form, and the kernel's oracle.  Chunk i of the queries (its
+    noisy and its clean positions together) reads the clean keys up to its own
+    end and its own noisy keys: two folds of the running softmax."""
     b, two_l, g, r, dh = q.shape
     length, chunk = two_l // 2, cfg.attn_chunk
     scale = 1.0 / math.sqrt(dh)

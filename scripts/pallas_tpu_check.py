@@ -23,14 +23,18 @@ Exit code 0 iff every parity check passed; 2 when there is no TPU.
 ``run_check`` is the same thing as a callable (chip_smoke.py's leg C).
 
 The attention column (``run_attention_check``; ``--columns gar,attention``):
-ops/attention.py's fused kernel against models/laguna.py's chunked XLA form at
-the two shapes of ``laguna_avgmedian_causal4k`` (24 query heads over 4, full;
-32 over 4 under a window of 512; L = 4096, three workers vmapped as the engine
-does) — output and the gradients of q, k and v at ``highest`` precision (what
-separates the two is then the order of float32 sums) and at the default (what
-the step runs), and each form alone, forward and forward + backward, on a
-device-synchronised host clock, least of ``--attention-reps``.
-``--attention-tiles 128x256,256x512`` times the kernel alone at other tiles.
+ops/attention.py's fused kernel against the models' chunked XLA forms at the
+two shapes of ``laguna_avgmedian_causal4k`` (24 query heads over 4, full; 32
+over 4 under a window of 512; L = 4096, three workers vmapped as the engine
+does; models/laguna.py) and at ``sdar30b_median_blockdiff``'s (32 over 4 under
+the block-diffusion mask over [noisy ; clean] of 2,048 each, blocks of 4, four
+workers; models/sdar.py) — output and the gradients of q, k and v at
+``highest`` precision (what separates the two is then the order of float32
+sums) and at the default (what the step runs), and each form alone, forward
+and forward + backward, on a device-synchronised host clock, least of
+``--attention-reps``.  ``--attention-shapes block-diffusion`` keeps some of
+the rows; ``--attention-tiles 128x256,256x512`` times the kernel alone at
+other tiles, ``--attention-tiles sweep`` at the five of ``TILE_SWEEP``.
 """
 
 import argparse
@@ -161,9 +165,41 @@ def run_check(n, f, dims, rules=RULES, reps=10, nan_workers=2,
     return failed
 
 
-#: (name, query heads a kv head, window) of the grid's Laguna cell: layers 0
-#: and 4, layers 1-3 (grid/configs/laguna-xs2-ep32-n3.json).
-ATTENTION_SHAPES = (("full", 6, None), ("window", 8, 512))
+def _laguna_attention(window):
+    """``(length, kv_heads, head_dim) -> (mask, the model's attention)``."""
+    def make(length, kv_heads, head_dim):
+        from aggregathor_tpu.models import laguna
+        from aggregathor_tpu.ops import attention
+
+        cfg = laguna.LagunaConfig(seq=length, head_dim=head_dim, kv_heads=kv_heads,
+                                  attn_chunk=min(length, laguna.LagunaConfig.attn_chunk))
+        return (attention.Causal(window),
+                lambda q, k, v: laguna.causal_attention(q, k, v, cfg, window))
+    return make
+
+
+def _sdar_attention(block):
+    def make(length, kv_heads, head_dim):
+        from aggregathor_tpu.models import sdar
+
+        half = length // 2
+        cfg = sdar.SdarConfig(seq=half, block=block, head_dim=head_dim, kv_heads=kv_heads,
+                              attn_chunk=min(half, sdar.SdarConfig.attn_chunk))
+        return (sdar.BlockDiffusion(half, block),
+                lambda q, k, v: sdar.masked_attention(q, k, v, cfg))
+    return make
+
+
+#: (name, workers, query heads a kv head, the model's mask and attention) of
+#: the grid's Laguna cell — layers 0 and 4, layers 1-3
+#: (grid/configs/laguna-xs2-ep32-n3.json) — and of its SDAR cell
+#: (grid/configs/sdar-30b-a3b-ep16-n4.json).
+ATTENTION_SHAPES = (("full", 3, 6, _laguna_attention(None)),
+                    ("window", 3, 8, _laguna_attention(512)),
+                    ("block-diffusion", 4, 8, _sdar_attention(4)))
+
+#: ``--attention-tiles sweep``: queries x keys a tile
+TILE_SWEEP = ((128, 256), (256, 256), (512, 256), (256, 512), (512, 512))
 
 
 def _least_ms(fn, reps):
@@ -181,31 +217,29 @@ def _least_ms(fn, reps):
     return min(times) * 1e3
 
 
-def run_attention_check(reps=5, tiles=(), length=4096, kv_heads=4, head_dim=128, workers=3,
+def run_attention_check(reps=5, tiles=(), length=4096, kv_heads=4, head_dim=128,
                         shapes=ATTENTION_SHAPES, allow_interpret=False, emit=_print_row):
-    """Parity and time of the fused attention kernel against the chunked XLA
-    form at each of ``shapes``; ``emit(row)`` per shape, then per (shape, tile)
-    of ``tiles``.  Returns the rows whose parity is not ``"ok"``."""
+    """Parity and time of the fused attention kernel against the model's
+    chunked XLA form at each of ``shapes``; ``emit(row)`` per shape, then per
+    (shape, tile) of ``tiles``.  Returns the rows whose parity is not
+    ``"ok"``."""
     import jax
     import jax.numpy as jnp
 
-    from aggregathor_tpu.models import laguna
     from aggregathor_tpu.ops import attention
 
     if not allow_interpret and attention._interpret():
         raise RuntimeError("the attention column needs a TPU backend (the kernel would "
                            "interpret on %r)" % jax.default_backend())
-    cfg = laguna.LagunaConfig(seq=length, head_dim=head_dim, kv_heads=kv_heads,
-                              attn_chunk=min(length, laguna.LagunaConfig.attn_chunk))
     failed = []
-    for name, rep, window in shapes:
+    for name, workers, rep, make in shapes:
+        mask, model_attention = make(length, kv_heads, head_dim)
         key = jax.random.PRNGKey(11)
         normal = lambda place, *dims: jax.random.normal(
             jax.random.fold_in(key, place), (workers, 1, length) + dims, jnp.float32)
         q, k, v = normal(0, kv_heads, rep, head_dim), normal(1, kv_heads, head_dim), normal(
             2, kv_heads, head_dim)
         weight = normal(3, kv_heads * rep * head_dim)
-        mask = attention.Causal(window)
 
         def forms(attend):
             forward = jax.vmap(attend)
@@ -219,12 +253,11 @@ def run_attention_check(reps=5, tiles=(), length=4096, kv_heads=4, head_dim=128,
                     with attention.forced_form(form), jax.default_matmul_precision(precision):
                         return fn(*args)
                 return call
-            return [enter(fn) for fn in forms(
-                lambda q, k, v: laguna.causal_attention(q, k, v, cfg, window))]
+            return [enter(fn) for fn in forms(model_attention)]
 
         q_tile, k_tile = attention.tiles_for(length)
         row = {"metric": "pallas_tpu_check", "rule": "attention-" + name, "workers": workers,
-               "length": length, "heads": "%d/%d" % (kv_heads * rep, kv_heads), "window": window,
+               "length": length, "heads": "%d/%d" % (kv_heads * rep, kv_heads), "mask": repr(mask),
                "tiles": "%dx%d" % (q_tile, k_tile),
                **attention.table_counts(attention.tile_table(mask, length, q_tile, k_tile))}
         try:
@@ -288,8 +321,11 @@ def main():
     ap.add_argument("--columns", default="gar,attention",
                     help="which checks run: 'gar' (the rules), 'attention' (the fused kernel)")
     ap.add_argument("--attention-reps", type=int, default=5)
+    ap.add_argument("--attention-shapes", default=",".join(name for name, *_ in ATTENTION_SHAPES),
+                    help="which rows of the attention column run")
     ap.add_argument("--attention-tiles", default="",
-                    help="QxK,... : also time the attention kernel alone at these tiles")
+                    help="QxK,... : also time the attention kernel alone at these tiles; "
+                         "'sweep': at the five of TILE_SWEEP")
     args = ap.parse_args()
 
     import jax
@@ -310,10 +346,13 @@ def main():
             nan_workers=args.nan_workers, allow_interpret=args.allow_interpret,
         )
     if "attention" in columns:
-        tiles = [tuple(int(size) for size in pair.split("x"))
-                 for pair in args.attention_tiles.split(",") if pair]
-        failed += run_attention_check(args.attention_reps, tiles,
-                                      allow_interpret=args.allow_interpret)
+        tiles = TILE_SWEEP if args.attention_tiles == "sweep" else [
+            tuple(int(size) for size in pair.split("x"))
+            for pair in args.attention_tiles.split(",") if pair]
+        failed += run_attention_check(
+            args.attention_reps, tiles, allow_interpret=args.allow_interpret,
+            shapes=[shape for shape in ATTENTION_SHAPES
+                    if shape[0] in args.attention_shapes.split(",")])
     sys.exit(1 if failed else 0)
 
 

@@ -2,13 +2,13 @@
 CPU, at small shapes: against one dense masked softmax (output and the
 gradients of q, k and v), called as the step calls it (``vmap`` over workers
 inside ``checkpoint`` inside ``scan``); the tile table against a brute-force
-count of the mask; the chooser and its seam; and the products the float32
-Laguna loss holds with the kernel forced, the kernel's body included.
+count of the mask; the chooser and its seam; the products the float32
+Laguna and SDAR losses hold with the kernel forced, the kernel's body
+included; and models/sdar.py's ``masked_attention`` through the kernel against
+its own XLA form.
 Products run at ``highest`` precision, so what separates kernel and reference
 is the order of float32 sums.  (The kernels compiled for the described chip at
 the cell's shapes: tests/test_reshard.py, where every such program lives.)"""
-
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -16,9 +16,10 @@ import numpy as np
 import pytest
 
 from aggregathor_tpu import models
-from aggregathor_tpu.models import laguna
+from aggregathor_tpu.models import laguna, sdar
 from aggregathor_tpu.ops import attention
 from aggregathor_tpu.ops.attention import CLEAR, EDGED, SKIPPED, Causal
+from aggregathor_tpu.models.sdar import BlockDiffusion
 
 LENGTH, KV_HEADS, HEAD_DIM, WORKERS, LAYERS = 32, 2, 16, 3, 2
 
@@ -29,23 +30,8 @@ def highest_precision():
         yield
 
 
-@dataclasses.dataclass(frozen=True)
-class BlockDiffusion:
-    """Another predicate than the model's: models/sdar.py's mask over a
-    sequence [noisy ; clean] of two halves cut into blocks — a noisy query reads
-    its own noisy block and the clean blocks before it, a clean one the clean
-    blocks up to its own.  Its rows of the tile table have gaps and two runs."""
-
-    half: int
-    block: int
-
-    def __call__(self, q_index, k_index):
-        q_noisy, k_noisy = q_index < self.half, k_index < self.half
-        q_block, k_block = (q_index % self.half) // self.block, (k_index % self.half) // self.block
-        return jnp.where(q_noisy, jnp.where(k_noisy, k_block == q_block, k_block < q_block),
-                         ~k_noisy & (k_block <= q_block))
-
-
+# models/sdar.py's mask: another predicate than Causal, whose rows of the tile table have gaps
+# and two runs
 MASKS = [Causal(None), Causal(12), Causal(20), Causal(5), BlockDiffusion(LENGTH // 2, 4)]
 MASK_IDS = ["full", "window-12", "window-20", "window-5", "block-diffusion"]
 
@@ -106,19 +92,24 @@ def test_kernel_is_a_dense_masked_softmax(mask, rep, tiles):
     (Causal(512), 4096, (128, 512), None),
     (Causal(20), 32, (8, 8), None),
     (Causal(5), 32, (16, 8), None),
-    (BlockDiffusion(2048, 4), 4096, (256, 256), None),  # models/sdar.py's, at its cell's length
+    (BlockDiffusion(2048, 4), 4096, (256, 256), (56, 24, 176)),  # models/sdar.py's, at its cell's shape
     (BlockDiffusion(16, 4), 32, (8, 8), None),
+    (BlockDiffusion(2048, 8), 4096, (256, 256), (56, 24, 176)),  # the harness's planted fault
+    (BlockDiffusion(18, 6), 36, (12, 6), None),                  # a block that is no power of two
 ])
 def test_the_table_counts_what_the_mask_allows(mask, length, tiles, counts):
-    """Every tile's class against the mask over its pairs (``laguna.allowed``
-    for the model's), the three counts at the cell's shapes, and the kernel's
+    """Every tile's class against the mask over its pairs (the models' own
+    ``allowed``), the three counts at the cells' shapes, and the kernel's
     loops: each tile that is not SKIPPED in exactly one range, the even slots
     EDGED and the odd CLEAR."""
     q_tile, k_tile = tiles
     table = attention.tile_table(mask, length, q_tile, k_tile)
     index = np.arange(length)
-    ok = np.asarray(laguna.allowed(index, index, mask.window) if isinstance(mask, Causal)
-                    else mask(index[:, None], index[None, :]))
+    if isinstance(mask, Causal):
+        ok = np.asarray(laguna.allowed(index, index, mask.window))
+    else:
+        position, noisy = index % mask.half, index < mask.half
+        ok = np.asarray(sdar.allowed(position, noisy, position, noisy, mask.block))
     for i in range(length // q_tile):
         for j in range(length // k_tile):
             tile = ok[i * q_tile:(i + 1) * q_tile, j * k_tile:(j + 1) * k_tile]
@@ -249,3 +240,87 @@ def test_a_narrower_input_is_widened_inside_the_kernel(window):
         assert bool(jnp.all(jnp.isfinite(narrow.astype(jnp.float32))))
         scale = float(jnp.max(jnp.abs(exact)))
         assert float(jnp.max(jnp.abs(narrow.astype(jnp.float32) - exact))) <= 2e-2 * scale
+
+
+SDAR_ARGS = ["vocab:50", "hidden:64", "heads:16", "kv-heads:2", "head-dim:16", "layers:2",
+             "experts:16", "experts-per-token:4", "expert-width:24", "experts-held:1,4,7,12",
+             "seq:%d" % (LENGTH // 2), "block:4", "attn-chunk:8", "batch-size:1", "corpus:4"]
+
+
+@pytest.mark.parametrize("tile", [LENGTH, 8], ids=["one-tile", "8x8"])
+@pytest.mark.parametrize("block", [4, 2, 8])
+def test_sdar_attention_through_the_kernel_is_its_own_xla_form(monkeypatch, block, tile):
+    """``sdar.masked_attention`` traced inside ``forced_form("kernel")``
+    (interpreted) against the same call inside ``forced_form("xla")`` — the
+    chunked form the CPU runs: output and the gradients of q, k and v, under
+    ``vmap`` + ``checkpoint`` + ``scan`` as the step calls it; [noisy ; clean]
+    of 16 positions each, 8 query heads a kv head."""
+    monkeypatch.setattr(attention, "Q_TILE", tile)
+    monkeypatch.setattr(attention, "K_TILE", tile)
+    cfg = sdar.SdarConfig(seq=LENGTH // 2, block=block, attn_chunk=8, heads=16, kv_heads=KV_HEADS,
+                          head_dim=HEAD_DIM).check()
+    q, k, v, w = seeded(8)
+
+    def under(form):
+        def attend(q, k, v):
+            with attention.forced_form(form):
+                return sdar.masked_attention(q, k, v, cfg)
+        return attend
+
+    assert kernels_in(jax.make_jaxpr(under("kernel"))(q[0, 0], k[0, 0], v[0, 0]).jaxpr) == [
+        "causal_attention_fwd"]
+    (ours, ours_grads), (theirs, theirs_grads) = (stepped(under("kernel"))(q, k, v, w),
+                                                  stepped(under("xla"))(q, k, v, w))
+    assert abs(float(ours) - float(theirs)) <= 1e-5 * abs(float(theirs))
+    for mine, chunked in zip(ours_grads, theirs_grads):
+        assert mine.shape == chunked.shape and mine.dtype == chunked.dtype
+        np.testing.assert_allclose(np.asarray(mine), np.asarray(chunked), rtol=1e-4, atol=2e-5)
+    out = jax.vmap(under("kernel"))(q[0], k[0], v[0])
+    np.testing.assert_allclose(np.asarray(out), np.asarray(jax.vmap(under("xla"))(q[0], k[0], v[0])),
+                               rtol=1e-5, atol=1e-5)
+
+
+def test_the_float32_sdar_loss_with_the_kernel_forced_holds_no_narrow_product():
+    """As for Laguna: ``narrow_products`` stays 0 in ``sdar30b_median_blockdiff``
+    with its attention on the kernel."""
+    experiment = models.instantiate("sdar", SDAR_ARGS)
+    params = experiment.init(jax.random.PRNGKey(0))
+    batch = experiment.device_transform()({"tokens": jnp.asarray(experiment.corpus[:1])},
+                                          jax.random.PRNGKey(1))
+    with attention.forced_form("kernel"):
+        jaxpr = jax.make_jaxpr(jax.grad(lambda p: experiment.loss(p, batch)[0]))(params)
+    assert {"causal_attention_fwd", "causal_attention_bwd"} <= set(kernels_in(jaxpr.jaxpr))
+    products = products_of(jaxpr.jaxpr)
+    assert len([operands for operands, inside in products if inside]) >= 2 + 5
+    assert all(jnp.finfo(dtype).bits >= 32 for operands, _ in products for dtype in operands)
+
+
+@pytest.mark.parametrize("name", ["full", "window", "block-diffusion"])
+def test_the_check_scripts_attention_column_runs_interpreted(name):
+    """scripts/pallas_tpu_check.py ``run_attention_check`` — the chip's parity
+    and timing of the kernel against each model's XLA form — off a TPU at a
+    small size: a row a shape with the mask's name and parity ``ok``, a row a
+    swept tile (the times mean nothing here)."""
+    import os
+    import sys
+
+    scripts = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "scripts")
+    sys.path.insert(0, scripts)
+    try:
+        import pallas_tpu_check
+    finally:
+        sys.path.remove(scripts)
+    rows = []
+    shapes = [shape for shape in pallas_tpu_check.ATTENTION_SHAPES if shape[0] == name]
+    failed = pallas_tpu_check.run_attention_check(
+        reps=1, tiles=((8, 8),), length=LENGTH, kv_heads=KV_HEADS, head_dim=HEAD_DIM,
+        shapes=shapes, allow_interpret=True, emit=rows.append)
+    sys.modules.pop("pallas_tpu_check", None)
+    assert failed == [] and [row["rule"] for row in rows] == [
+        "attention-" + name, "attention-%s-tiles" % name]
+    assert rows[0]["parity"] == "ok" and rows[0]["workers"] == shapes[0][1]
+    assert rows[0]["mask"] == {"full": "Causal(window=None)", "window": "Causal(window=512)",
+                               "block-diffusion": "BlockDiffusion(half=16, block=4)"}[name]
+    assert "kernel_fwd_bwd_ms" in rows[1] and "error" not in rows[1]
+    with pytest.raises(RuntimeError):
+        pallas_tpu_check.run_attention_check(reps=1, length=LENGTH, shapes=shapes)

@@ -229,28 +229,36 @@ def test_crop_neither_loops_nor_slices_on_the_chip(v5e_2x2):
     assert compiled.memory_analysis().temp_size_in_bytes < 300e6
 
 
-@pytest.mark.parametrize("rep,window", [(6, None), (8, 512)], ids=["full", "window"])
-def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, monkeypatch, rep, window):
+@pytest.mark.parametrize("workers,rep,mask", [(3, 6, "full"), (3, 8, "window"), (4, 8, "block")],
+                         ids=["full", "window", "block-diffusion"])
+def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, monkeypatch, workers,
+                                                                    rep, mask):
     """The fused attention kernel and its backward pass as the step of
     ``laguna_avgmedian_causal4k`` calls them — three workers under ``vmap``,
     L = 4096, 4 kv heads of 128 serving 6 (full) or 8 (window 512) query heads
-    each — compile for the described chip: the tiles fit VMEM, every slice is
+    each — and as ``sdar30b_median_blockdiff``'s does — four workers, 8 query
+    heads a kv head, models/sdar.py's predicate over [noisy ; clean] of 2,048
+    each in blocks of 4, which has to lower inside the Mosaic kernel — compile
+    for the described chip: the tiles fit VMEM, every slice is
     on a tile boundary, and what the two leave in HBM beside q, k, v, the
     output and their gradients is one log-sum-exp a query a head (128 lanes
     wide as the chip stores it) and a row-major copy of q: nothing
     score-shaped, nothing a fold."""
     from jax.sharding import SingleDeviceSharding
 
+    from aggregathor_tpu.models.sdar import BlockDiffusion
     from aggregathor_tpu.ops import attention
 
     monkeypatch.setattr(attention.hw, "on_tpu", lambda: True)  # compile the kernels, not interpret
     monkeypatch.setattr(attention, "info", lambda *_: None)
-    workers, length, kv_heads, head_dim = 3, 4096, 4, 128
+    length, kv_heads, head_dim = 4096, 4, 128
+    mask = {"full": attention.Causal(None), "window": attention.Causal(512),
+            "block": BlockDiffusion(length // 2, 4)}[mask]
     assert attention.attention_form(length, head_dim) == "kernel"
     one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
     shape = lambda *dims: jax.ShapeDtypeStruct((workers, 1, length) + dims, jnp.float32,
                                                sharding=one_chip)
-    attend = jax.vmap(lambda q, k, v: attention.attend(q, k, v, attention.Causal(window), None))
+    attend = jax.vmap(lambda q, k, v: attention.attend(q, k, v, mask, None))
     compiled = compile_uncached(
         jax.jit(jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) ** 2), argnums=(0, 1, 2))),
         shape(kv_heads, rep, head_dim), shape(kv_heads, head_dim), shape(kv_heads, head_dim))

@@ -149,6 +149,44 @@ def test_the_mask_is_the_four_written_rules():
     assert grid_module("flops", "sdar_moe").allowed_pairs(length, block) == int(ours.sum())
 
 
+@pytest.mark.parametrize("half,block", [(2048, 4), (16, 4), (12, 3), (30, 6), (2048, 8)])
+def test_the_predicate_is_allowed_on_every_pair(half, block):
+    """``BlockDiffusion`` — what ops/attention.py is handed — against
+    ``allowed`` over all (2 * half)^2 pairs: at the cell's shape, at a small
+    one, at blocks that are no power of two (a division, not a shift) and at
+    the planted fault's block; on numpy indices, as ``tile_table`` calls it,
+    and on int32 device arrays, as the kernel does."""
+    positions, is_noisy = jnp.tile(jnp.arange(half), 2), jnp.arange(2 * half) < half
+    theirs = np.asarray(sdar.allowed(positions, is_noisy, positions, is_noisy, block))
+    mask, index = sdar.BlockDiffusion(half, block), np.arange(2 * half)
+    assert np.array_equal(np.asarray(mask(index[:, None], index[None, :])), theirs)
+    on_device = jax.jit(mask)(jnp.arange(2 * half, dtype=jnp.int32)[:, None],
+                              jnp.arange(2 * half, dtype=jnp.int32)[None, :])
+    assert on_device.dtype == jnp.bool_ and np.array_equal(np.asarray(on_device), theirs)
+    assert hash(mask) == hash(sdar.BlockDiffusion(half, block))  # a static argument of the kernels
+    assert repr(mask) == "BlockDiffusion(half=%d, block=%d)" % (half, block)
+
+
+def test_on_the_cpu_the_loss_runs_the_chunked_form_bit_for_bit(experiment, monkeypatch):
+    """Off a TPU ``attention_form`` answers ``xla`` at the cell's shape and at
+    the test's, the loss traces no kernel, and its value and gradients are
+    those of ``chunked_attention`` called with nothing in between — the
+    parent's ``masked_attention`` under its new name."""
+    from aggregathor_tpu.ops import attention
+
+    assert attention.attention_form(4096, 128) == "xla"
+    assert attention.attention_form(2 * LENGTH, 16) == "xla"
+    params, batch = seeded_params(), noised(experiment)
+    value_and_grad = lambda: jax.jit(jax.value_and_grad(experiment.loss, has_aux=True))(params, batch)
+    assert "pallas_call" not in str(jax.make_jaxpr(experiment.loss)(params, batch))
+    (loss, _), grads = value_and_grad()
+    monkeypatch.setattr(sdar, "masked_attention", sdar.chunked_attention)
+    (plain_loss, _), plain_grads = value_and_grad()
+    assert float(loss) == float(plain_loss)
+    assert all(np.array_equal(np.asarray(grads[name]), np.asarray(plain_grads[name]))
+               for name in grads)
+
+
 def test_chunked_attention_is_a_dense_masked_softmax(experiment):
     """(c) the running softmax over a chunk's two key ranges against one
     softmax over all 2L keys under the boolean matrix."""
