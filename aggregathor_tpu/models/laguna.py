@@ -188,10 +188,15 @@ def leaf_shapes(cfg):
 
 
 def init_params(cfg, key):
-    """Norm scales at one, every matrix N(0, INIT_STD^2), leaf by leaf from
-    ``fold_in(key, its place)``: the top-level leaves by sorted name, then each
-    run's by sorted name, run after run."""
-    shapes = leaf_shapes(cfg)
+    return seeded_leaves(leaf_shapes(cfg), key)
+
+
+def seeded_leaves(shapes, key):
+    """A tree of shapes like ``leaf_shapes``'s into parameters: norm scales at
+    one, every other leaf N(0, INIT_STD^2), leaf by leaf from ``fold_in(key,
+    its place)``: the top-level leaves by sorted name, then each run's by sorted
+    name, run after run (models/deepseek_v3.py's tree too)."""
+    shapes = dict(shapes)
     runs = shapes.pop("layers")
     place = 0
 
@@ -262,6 +267,8 @@ def causal_attention(q, k, v, cfg, window):
 
 def chunked_attention(q, k, v, cfg, window):
     """The same in plain XLA: the CPU's form, and what the kernel is held to.
+    v may be narrower than q and k (models/deepseek_v3.py: scores over 192,
+    values of 128): the scale is q's width's, the accumulator as wide as v.
 
     A scan over the query chunks; chunk i folds its own keys under the mask,
     then, in two inner scans, the clear and the edged key chunks behind it
@@ -269,7 +276,7 @@ def chunked_attention(q, k, v, cfg, window):
     compiled body a kind of fold, whatever L is, and no work for a pair of
     chunks the mask forbids whole.  The chunk is checkpointed: a layer's
     backward pass holds one chunk's scores."""
-    b, length, g, r, dh = q.shape
+    (b, length, g, r, dh), dv = q.shape, v.shape[-1]
     chunk, nb_chunks = cfg.attn_chunk, length // cfg.attn_chunk
     within = jnp.arange(chunk)
 
@@ -281,7 +288,7 @@ def chunked_attention(q, k, v, cfg, window):
     def one_chunk(_, numbered):
         i, qi = numbered
         q_pos = i * chunk + within
-        carry = (jnp.zeros((b, g, r, chunk, dh), jnp.float32),
+        carry = (jnp.zeros((b, g, r, chunk, dv), jnp.float32),
                  jnp.zeros((b, g, r, chunk), jnp.float32),
                  jnp.full((b, g, r, chunk), _NEG, jnp.float32))
         carry = _fold(carry, qi, *keys_at(i), allowed(q_pos, q_pos, window))
@@ -297,11 +304,11 @@ def chunked_attention(q, k, v, cfg, window):
                 carry, _ = jax.lax.scan(behind, carry, jnp.asarray(offsets))
         num, den, _ = carry
         out = (num / jnp.maximum(den[..., None], 1e-30)).astype(q.dtype)
-        return None, out.transpose(0, 3, 1, 2, 4).reshape(b, chunk, g * r * dh)
+        return None, out.transpose(0, 3, 1, 2, 4).reshape(b, chunk, g * r * dv)
 
     chunks = q.reshape(b, nb_chunks, chunk, g, r, dh).swapaxes(0, 1)
     _, outs = jax.lax.scan(one_chunk, None, (jnp.arange(nb_chunks), chunks))
-    return outs.swapaxes(0, 1).reshape(b, length, g * r * dh)
+    return outs.swapaxes(0, 1).reshape(b, length, g * r * dv)
 
 
 def attention(u, layer, cfg, kind):
@@ -374,31 +381,45 @@ def decoder_layer(x, layer, cfg, kind):
     return x + y, routed, idle
 
 
+def layer_runs(x, counters, runs, groups, layer):
+    """``x`` through the model's runs of stacked layers: ``layer(x, leaves,
+    kind) -> (x, *counts)`` once a layer under ``jax.checkpoint``, a run of
+    several under ``lax.scan``; each count is added to its place in
+    ``counters``.  Returns ``(x, *counters)``."""
+    carry = (x,) + tuple(counters)
+    for (kind, count), group in zip(runs, groups):
+
+        @jax.checkpoint
+        def body(carry, leaves, kind=kind):
+            x, *counts = layer(carry[0], leaves, kind)
+            return (x,) + tuple(so_far + more for so_far, more in zip(carry[1:], counts)), None
+
+        if count == 1:
+            carry, _ = body(carry, jax.tree.map(lambda leaf: leaf[0], group))
+        else:
+            carry, _ = jax.lax.scan(body, carry, group)
+    return carry
+
+
+def next_token_loss(x, params, targets, cfg):
+    """The mean over the positions of ``-log softmax(logits)[target]``."""
+    with jax.named_scope("model.head"):
+        hidden = rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
+        logp = jax.nn.log_softmax((hidden @ params["head"].astype(cfg.dtype)).astype(jnp.float32))
+        return -jnp.mean(jnp.take_along_axis(logp, targets[..., None], axis=-1))
+
+
 def loss_and_counters(params, batch, cfg):
     """``batch``: ``tokens`` (B, L + 1).  Returns the next-token loss (mean
     over the B x L positions) and the step's counters."""
     inputs, targets = batch["tokens"][:, :-1], batch["tokens"][:, 1:]
     with jax.named_scope("model.embed"):
         x = params["embed"][inputs].astype(cfg.dtype)
-    carry = (x, jnp.float32(0), jnp.float32(0))
-    for (kind, count), group in zip(cfg.runs(), params["layers"]):
-
-        @jax.checkpoint
-        def body(carry, layer, kind=kind):
-            x, routed, idle = carry
-            x, r, i = decoder_layer(x, layer, cfg, kind)
-            return (x, routed + r, idle + i), None
-
-        if count == 1:
-            carry, _ = body(carry, jax.tree.map(lambda leaf: leaf[0], group))
-        else:
-            carry, _ = jax.lax.scan(body, carry, group)
-    x, routed, idle = carry
-    with jax.named_scope("model.head"):
-        hidden = rms_norm(x, params["final_norm"].astype(cfg.dtype), cfg.norm_eps)
-        logp = jax.nn.log_softmax((hidden @ params["head"].astype(cfg.dtype)).astype(jnp.float32))
-        loss = -jnp.mean(jnp.take_along_axis(logp, targets[..., None], axis=-1))
-    return loss, {"routed_positions": routed, "idle_held_experts": idle}
+    x, routed, idle = layer_runs(
+        x, (jnp.float32(0), jnp.float32(0)), cfg.runs(), params["layers"],
+        lambda x, leaves, kind: decoder_layer(x, leaves, cfg, kind))
+    return next_token_loss(x, params, targets, cfg), {
+        "routed_positions": routed, "idle_held_experts": idle}
 
 
 def seeded_corpus(rows, length, vocab, seed=0):
@@ -443,18 +464,23 @@ class LagunaExperiment(Experiment):
         self.batch_size = kv["batch-size"]
         self.corpus = seeded_corpus(kv["corpus"], self.cfg.seq, self.cfg.vocab)
 
+    #: the family's own two functions of ``cfg``; another family trained on the next token
+    #: from the same seeded rows (models/deepseek_v3.py) names its own and its ``__init__``
+    init_params = staticmethod(init_params)
+    loss_and_counters = staticmethod(loss_and_counters)
+
     def init(self, rng):
-        return init_params(self.cfg, rng)
+        return self.init_params(self.cfg, rng)
 
     def loss(self, params, batch):
         """(loss, counters): the engine carries the counters with the loss
         (``has_aux``, parallel/engine.py ``_worker_gradients``)."""
-        return loss_and_counters(params, batch, self.cfg)
+        return self.loss_and_counters(params, batch, self.cfg)
 
     loss.has_aux = True
 
     def metrics(self, params, batch):
-        loss, _counters = loss_and_counters(params, batch, self.cfg)
+        loss, _counters = self.loss_and_counters(params, batch, self.cfg)
         return {"loss": (loss, jnp.float32(1))}
 
     def device_transform(self):

@@ -40,6 +40,12 @@ products carry the process's matmul precision as XLA's do: at the default the
 chip multiplies the float32 operands in one bfloat16 pass (read on the chip:
 0.4 % from ``highest``, PERF.md section 6, PR 36), under ``highest`` in full.
 
+**Two widths by padding.**  The kernels carry one head width; scores wider
+than values (models/deepseek_v3.py's latent attention: 192 over 128) run at the
+next multiple of the lanes that holds both, zeros in the rest
+(``fused_attention``): a third of the score product and half of the value
+product multiply zeros until a kernel carries the two widths itself.
+
 **One chooser** (``attention_form``): on a TPU, for shapes the kernel takes,
 the kernel; else the caller's XLA form.  No flag and no environment variable;
 ``forced_form`` is the one scoped seam, for the tests and
@@ -344,13 +350,26 @@ _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
 def fused_attention(q, k, v, mask, q_tile, k_tile):
-    """q (B, L, G, R, Dh), k and v (B, L, G, Dh) -> (B, L, G * R * Dh): the
-    softmax over the keys ``mask`` allows of ``q . k / sqrt(Dh)``, times v,
-    with its own backward pass.  ``L`` is a multiple of both tiles; every query
-    reads some key.  The tile table is made here, at trace time, from the
-    static arguments alone; the kernels are handed its loops."""
-    slots = _slots(tile_table(mask, q.shape[1], q_tile, k_tile))
-    return _fused(q, k, v, (mask, slots, q_tile, k_tile))
+    """q (B, L, G, R, Dqk), k (B, L, G, Dqk) and v (B, L, G, Dv) -> (B, L, G *
+    R * Dv): the softmax over the keys ``mask`` allows of ``q . k / sqrt(Dqk)``,
+    times v, with its own backward pass.  ``L`` is a multiple of both tiles;
+    every query reads some key.  The tile table is made here, at trace time,
+    from the static arguments alone; the kernels are handed its loops.
+
+    The kernels carry ONE head width.  Where scores and values differ in width
+    (latent attention: 192 over 128) q, k and v are padded with zeros to
+    ``kernel_width``, ``sqrt(width / Dqk)`` goes into q so that the kernel's own
+    ``1 / sqrt(width)`` gives ``1 / sqrt(Dqk)``, and the padded end of each
+    head's output is dropped: the same numbers, with the zeros multiplied."""
+    plan = (mask, _slots(tile_table(mask, q.shape[1], q_tile, k_tile)), q_tile, k_tile)
+    qk_dim, v_dim = q.shape[-1], v.shape[-1]
+    if qk_dim == v_dim:
+        return _fused(q, k, v, plan)
+    width = kernel_width(qk_dim, v_dim)
+    widened = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, width - a.shape[-1])])
+    out = _fused(widened(q * math.sqrt(width / qk_dim)), widened(k), widened(v), plan)
+    b, length, g, rep, _ = q.shape
+    return out.reshape(b, length, g * rep, width)[..., :v_dim].reshape(b, length, g * rep * v_dim)
 
 
 # --------------------------------------------------------------------------- #
@@ -382,13 +401,21 @@ def tiles_for(length):
     return min(Q_TILE, length), min(K_TILE, length)
 
 
-def attention_form(length, head_dim):
+def kernel_width(qk_dim, v_dim):
+    """The one head width the kernel runs q, k and v at: their own where they
+    agree, else the next multiple of the 128 lanes that holds both."""
+    return qk_dim if qk_dim == v_dim else -(-max(qk_dim, v_dim) // LANE) * LANE
+
+
+def attention_form(length, head_dim, v_dim=None):
     """``"kernel"`` or ``"xla"`` for a sequence of ``length`` under heads of
-    ``head_dim``: the kernel on a TPU (``utils.hw.on_tpu``) where it takes the
-    shape — ``length`` a multiple of both tiles and of the 8 sublanes,
-    ``head_dim`` of the 128 lanes, a head's K and V within ``RESIDENT_MAX`` —
-    and the caller's XLA form everywhere else.  Inside ``forced_form`` the
-    forced form answers, for any shape whose length divides into the tiles."""
+    ``head_dim`` (values of ``v_dim``, where they differ): the kernel on a TPU
+    (``utils.hw.on_tpu``) where it takes the shape — ``length`` a multiple of
+    both tiles and of the 8 sublanes, the head as the kernel runs it
+    (``kernel_width``) of the 128 lanes, a head's K and V at that width within
+    ``RESIDENT_MAX`` — and the caller's XLA form everywhere else.  Inside
+    ``forced_form`` the forced form answers, for any shape whose length
+    divides into the tiles."""
     q_tile, k_tile = tiles_for(length)
     divides = length % q_tile == 0 and length % k_tile == 0
     if _forced is not None:
@@ -396,26 +423,31 @@ def attention_form(length, head_dim):
             raise ValueError("the attention kernel takes a length that divides into its tiles "
                              "(%d, %d), not %d" % (q_tile, k_tile, length))
         return _forced
-    takes = (divides and length % 8 == 0 and head_dim % 128 == 0
-             and length * head_dim <= RESIDENT_MAX)
+    width = kernel_width(head_dim, v_dim or head_dim)
+    takes = divides and length % 8 == 0 and width % LANE == 0 and length * width <= RESIDENT_MAX
     return "kernel" if hw.on_tpu() and takes else "xla"
 
 
 @functools.lru_cache(maxsize=None)
-def _announce(form, shape, mask, tiles):
-    counts = ""
+def _announce(form, shape, v_dim, mask, tiles):
+    counts, widths = "", ""
     if form == "kernel":
         counts = "; tiles of %dx%d a head: %d clear, %d edged, %d skipped" % (
             tiles + tuple(table_counts(tile_table(mask, shape[1], *tiles)).values()))
-    info("attention form for q %s under %r: %s%s" % ("x".join(map(str, shape)), mask, form, counts))
+    if v_dim != shape[-1]:
+        widths = " over values of %d" % v_dim + (
+            ", padded to %d" % kernel_width(shape[-1], v_dim) if form == "kernel" else "")
+    info("attention form for q %s%s under %r: %s%s"
+         % ("x".join(map(str, shape)), widths, mask, form, counts))
 
 
 def attend(q, k, v, mask, xla_form):
     """``fused_attention`` where ``attention_form`` says so, else
-    ``xla_form(q, k, v)``.  On a TPU each decision is logged once a shape and
-    mask, with the tile table's three counts."""
-    length, head_dim = q.shape[1], q.shape[-1]
-    form, tiles = attention_form(length, head_dim), tiles_for(length)
+    ``xla_form(q, k, v)``; v may be narrower than q and k (``fused_attention``).
+    On a TPU each decision is logged once a shape and mask, with the tile
+    table's three counts."""
+    length = q.shape[1]
+    form, tiles = attention_form(length, q.shape[-1], v.shape[-1]), tiles_for(length)
     if hw.on_tpu():
-        _announce(form, tuple(q.shape), mask, tiles)
+        _announce(form, tuple(q.shape), v.shape[-1], mask, tiles)
     return fused_attention(q, k, v, mask, *tiles) if form == "kernel" else xla_form(q, k, v)

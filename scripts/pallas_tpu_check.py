@@ -166,7 +166,8 @@ def run_check(n, f, dims, rules=RULES, reps=10, nan_workers=2,
 
 
 def _laguna_attention(window):
-    """``(length, kv_heads, head_dim) -> (mask, the model's attention)``."""
+    """``(length, kv_heads, head_dim) -> (mask, the model's attention, (key
+    heads, the scores' width, the values'))``."""
     def make(length, kv_heads, head_dim):
         from aggregathor_tpu.models import laguna
         from aggregathor_tpu.ops import attention
@@ -174,7 +175,8 @@ def _laguna_attention(window):
         cfg = laguna.LagunaConfig(seq=length, head_dim=head_dim, kv_heads=kv_heads,
                                   attn_chunk=min(length, laguna.LagunaConfig.attn_chunk))
         return (attention.Causal(window),
-                lambda q, k, v: laguna.causal_attention(q, k, v, cfg, window))
+                lambda q, k, v: laguna.causal_attention(q, k, v, cfg, window),
+                (kv_heads, head_dim, head_dim))
     return make
 
 
@@ -186,17 +188,35 @@ def _sdar_attention(block):
         cfg = sdar.SdarConfig(seq=half, block=block, head_dim=head_dim, kv_heads=kv_heads,
                               attn_chunk=min(half, sdar.SdarConfig.attn_chunk))
         return (sdar.BlockDiffusion(half, block),
-                lambda q, k, v: sdar.masked_attention(q, k, v, cfg))
+                lambda q, k, v: sdar.masked_attention(q, k, v, cfg),
+                (kv_heads, head_dim, head_dim))
     return make
+
+
+def _latent_attention(length, kv_heads, head_dim):
+    """models/deepseek_v3.py's: every head its own key head (four times the
+    others' ``kv_heads``: 16), scores half again as wide as the values (192
+    over 128), which the kernel runs padded to one width."""
+    from aggregathor_tpu.models import deepseek_v3, laguna
+    from aggregathor_tpu.ops import attention
+
+    cfg = deepseek_v3.DeepseekV3Config(seq=length, attn_chunk=min(length, 256))
+    return (attention.Causal(),
+            lambda q, k, v: attention.attend(
+                q, k, v, attention.Causal(),
+                lambda q, k, v: laguna.chunked_attention(q, k, v, cfg, None)),
+            (4 * kv_heads, head_dim * 3 // 2, head_dim))
 
 
 #: (name, workers, query heads a kv head, the model's mask and attention) of
 #: the grid's Laguna cell — layers 0 and 4, layers 1-3
-#: (grid/configs/laguna-xs2-ep32-n3.json) — and of its SDAR cell
-#: (grid/configs/sdar-30b-a3b-ep16-n4.json).
+#: (grid/configs/laguna-xs2-ep32-n3.json) — of its SDAR cell
+#: (grid/configs/sdar-30b-a3b-ep16-n4.json) and of its Kanana cell
+#: (grid/configs/kanana2-30b-a3b-ep16-n3.json: G 16, R 1, 192 / 128).
 ATTENTION_SHAPES = (("full", 3, 6, _laguna_attention(None)),
                     ("window", 3, 8, _laguna_attention(512)),
-                    ("block-diffusion", 4, 8, _sdar_attention(4)))
+                    ("block-diffusion", 4, 8, _sdar_attention(4)),
+                    ("latent", 3, 1, _latent_attention))
 
 #: ``--attention-tiles sweep``: queries x keys a tile
 TILE_SWEEP = ((128, 256), (256, 256), (512, 256), (256, 512), (512, 512))
@@ -233,13 +253,13 @@ def run_attention_check(reps=5, tiles=(), length=4096, kv_heads=4, head_dim=128,
                            "interpret on %r)" % jax.default_backend())
     failed = []
     for name, workers, rep, make in shapes:
-        mask, model_attention = make(length, kv_heads, head_dim)
+        mask, model_attention, (key_heads, qk_dim, v_dim) = make(length, kv_heads, head_dim)
         key = jax.random.PRNGKey(11)
         normal = lambda place, *dims: jax.random.normal(
             jax.random.fold_in(key, place), (workers, 1, length) + dims, jnp.float32)
-        q, k, v = normal(0, kv_heads, rep, head_dim), normal(1, kv_heads, head_dim), normal(
-            2, kv_heads, head_dim)
-        weight = normal(3, kv_heads * rep * head_dim)
+        q, k, v = normal(0, key_heads, rep, qk_dim), normal(1, key_heads, qk_dim), normal(
+            2, key_heads, v_dim)
+        weight = normal(3, key_heads * rep * v_dim)
 
         def forms(attend):
             forward = jax.vmap(attend)
@@ -257,7 +277,8 @@ def run_attention_check(reps=5, tiles=(), length=4096, kv_heads=4, head_dim=128,
 
         q_tile, k_tile = attention.tiles_for(length)
         row = {"metric": "pallas_tpu_check", "rule": "attention-" + name, "workers": workers,
-               "length": length, "heads": "%d/%d" % (kv_heads * rep, kv_heads), "mask": repr(mask),
+               "length": length, "heads": "%d/%d" % (key_heads * rep, key_heads),
+               "widths": "%d/%d" % (qk_dim, v_dim), "mask": repr(mask),
                "tiles": "%dx%d" % (q_tile, k_tile),
                **attention.table_counts(attention.tile_table(mask, length, q_tile, k_tile))}
         try:

@@ -324,3 +324,99 @@ def test_the_check_scripts_attention_column_runs_interpreted(name):
     assert "kernel_fwd_bwd_ms" in rows[1] and "error" not in rows[1]
     with pytest.raises(RuntimeError):
         pallas_tpu_check.run_attention_check(reps=1, length=LENGTH, shapes=shapes)
+
+
+# --------------------------------------------------------------------------- #
+#  Two widths: scores wider than values (models/deepseek_v3.py)               #
+# --------------------------------------------------------------------------- #
+
+
+def naive_attention(q, k, v):
+    """``dense_attention`` under the causal mask: its scale is q's true width's
+    and its output as wide as v."""
+    return dense_attention(q, k, v, Causal())
+
+
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+@pytest.mark.parametrize("widths,rep", [((192, 128), 1), ((24, 16), 3)],
+                         ids=["192-over-128", "24-over-16-grouped"])
+def test_two_widths_are_a_naive_softmax(widths, rep, form):
+    """q and k of 192, v of 128 (latent attention's widths; and a small pair
+    with grouped queries) through ``attend``: the interpreted kernel by padding
+    to one width of whole lanes (256; 128), and models/laguna.py's
+    ``chunked_attention`` with its accumulator as wide as v, against the naive
+    softmax — output and the gradients of q, k and v, under ``vmap`` +
+    ``checkpoint`` + ``scan`` as the step calls it.  The scale is the true
+    width's: ``1 / sqrt(192)``, not the padded 256's."""
+    qk_dim, v_dim = widths
+    key = jax.random.PRNGKey(9)
+    normal = lambda place, *dims: jax.random.normal(
+        jax.random.fold_in(key, place), (LAYERS, WORKERS, 1, LENGTH) + dims)
+    q, k, v, w = (normal(0, KV_HEADS, rep, qk_dim), normal(1, KV_HEADS, qk_dim),
+                  normal(2, KV_HEADS, v_dim), normal(3, KV_HEADS * rep * v_dim))
+    cfg = laguna.LagunaConfig(seq=LENGTH, attn_chunk=8)
+
+    def attend(q, k, v):
+        with attention.forced_form(form):
+            return attention.attend(q, k, v, Causal(),
+                                    lambda q, k, v: laguna.chunked_attention(q, k, v, cfg, None))
+
+    traced = kernels_in(jax.make_jaxpr(attend)(q[0, 0], k[0, 0], v[0, 0]).jaxpr)
+    assert traced == (["causal_attention_fwd"] if form == "kernel" else [])
+    (ours, ours_grads), (theirs, theirs_grads) = (stepped(attend)(q, k, v, w),
+                                                  stepped(naive_attention)(q, k, v, w))
+    assert abs(float(ours) - float(theirs)) <= 1e-5 * abs(float(theirs))
+    for mine, naive in zip(ours_grads, theirs_grads):
+        assert mine.shape == naive.shape and mine.dtype == naive.dtype
+        np.testing.assert_allclose(np.asarray(mine), np.asarray(naive), rtol=1e-4, atol=2e-5)
+    out = jax.vmap(attend)(q[0], k[0], v[0])
+    assert out.shape == (WORKERS, 1, LENGTH, KV_HEADS * rep * v_dim)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(jax.vmap(naive_attention)(q[0], k[0], v[0])),
+                               rtol=1e-5, atol=1e-5)
+
+
+@pytest.mark.parametrize("length,head_dim,v_dim,form", [
+    (4096, 192, 128, "kernel"),   # the grid's Kanana cell: padded to 256, K and V exactly RESIDENT_MAX
+    (4096, 192, 192, "xla"),      # equal widths that are not whole lanes: no padding is tried
+    (4096 + 256, 192, 128, "xla"),  # padded, a head's K and V would not stay in VMEM
+    (4096, 128, 64, "kernel"),    # padded to 128
+    (4096, 128, 128, "kernel"),   # equal widths of whole lanes: as before
+])
+def test_the_chooser_admits_two_widths_by_their_padded_width(monkeypatch, length, head_dim, v_dim,
+                                                             form):
+    monkeypatch.setattr(attention.hw, "on_tpu", lambda: True)
+    assert attention.attention_form(length, head_dim, v_dim) == form
+    assert attention.kernel_width(192, 128) == 256 and attention.kernel_width(128, 128) == 128
+    assert attention.kernel_width(16, 16) == 16 and attention.kernel_width(24, 16) == 128
+
+
+#: sha256 (first 16 hex digits) of the jaxpr of loss-and-gradient of the tiny float32 Laguna and
+#: SDAR experiments below, read at the parent of the PR that gave ``attend`` two widths (PR 40)
+PARENT_JAXPRS = {("laguna", "kernel"): "0e5667dacc0f1d38", ("laguna", "xla"): "9ff6822bddfffb14",
+                 ("sdar", "kernel"): "4f34e6666579e587", ("sdar", "xla"): "1ccb145e6db057f4"}
+LAGUNA_ARGS = ["vocab:50", "hidden:64", "kv-heads:2", "head-dim:16",
+               "layer-types:full,sliding,sliding,sliding,full",
+               "mlp-types:dense,sparse,sparse,sparse,sparse", "heads:6,8,8,8,6", "window:12",
+               "dense-width:96", "experts:16", "experts-per-token:4", "expert-width:24",
+               "shared-width:24", "experts-held:1,4,7,12", "seq:%d" % LENGTH, "attn-chunk:8",
+               "batch-size:1", "corpus:4"]
+
+
+@pytest.mark.parametrize("name,form", sorted(PARENT_JAXPRS))
+def test_equal_widths_trace_the_program_they_traced(name, form):
+    """Laguna's and SDAR's steps are untouched by the two-width route: the
+    jaxpr of each one's loss and gradient, the kernels' bodies included where
+    the kernel is forced, is to the byte what the parent traced (the text
+    carries no file and no line; models/laguna.py's helpers that
+    models/deepseek_v3.py now shares trace to the same equations)."""
+    import hashlib
+
+    experiment = models.instantiate(name, LAGUNA_ARGS if name == "laguna" else SDAR_ARGS)
+    params = experiment.init(jax.random.PRNGKey(0))
+    batch = {"tokens": jnp.asarray(experiment.corpus[:1])}
+    if experiment.device_transform() is not None:
+        batch = experiment.device_transform()(batch, jax.random.PRNGKey(1))
+    with jax.default_matmul_precision("default"), attention.forced_form(form):
+        text = str(jax.make_jaxpr(jax.value_and_grad(
+            lambda p: experiment.loss(p, batch), has_aux=True))(params))
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_JAXPRS[name, form]

@@ -229,16 +229,20 @@ def test_crop_neither_loops_nor_slices_on_the_chip(v5e_2x2):
     assert compiled.memory_analysis().temp_size_in_bytes < 300e6
 
 
-@pytest.mark.parametrize("workers,rep,mask", [(3, 6, "full"), (3, 8, "window"), (4, 8, "block")],
-                         ids=["full", "window", "block-diffusion"])
+@pytest.mark.parametrize("workers,rep,mask,kv_heads,widths", [
+    (3, 6, "full", 4, (128, 128)), (3, 8, "window", 4, (128, 128)), (4, 8, "block", 4, (128, 128)),
+    (3, 1, "full", 16, (192, 128))], ids=["full", "window", "block-diffusion", "latent"])
 def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, monkeypatch, workers,
-                                                                    rep, mask):
+                                                                    rep, mask, kv_heads, widths):
     """The fused attention kernel and its backward pass as the step of
     ``laguna_avgmedian_causal4k`` calls them — three workers under ``vmap``,
     L = 4096, 4 kv heads of 128 serving 6 (full) or 8 (window 512) query heads
     each — and as ``sdar30b_median_blockdiff``'s does — four workers, 8 query
     heads a kv head, models/sdar.py's predicate over [noisy ; clean] of 2,048
-    each in blocks of 4, which has to lower inside the Mosaic kernel — compile
+    each in blocks of 4, which has to lower inside the Mosaic kernel — and as
+    ``kanana_avgmedian_causal4k``'s does — three workers, 16 heads each its own
+    key head, scores over 192 and values of 128 padded to the kernel's one
+    width of 256, a head's K and V exactly ``RESIDENT_MAX`` — compile
     for the described chip: the tiles fit VMEM, every slice is
     on a tile boundary, and what the two leave in HBM beside q, k, v, the
     output and their gradients is one log-sum-exp a query a head (128 lanes
@@ -251,23 +255,24 @@ def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, mon
 
     monkeypatch.setattr(attention.hw, "on_tpu", lambda: True)  # compile the kernels, not interpret
     monkeypatch.setattr(attention, "info", lambda *_: None)
-    length, kv_heads, head_dim = 4096, 4, 128
+    length, (head_dim, v_dim) = 4096, widths
     mask = {"full": attention.Causal(None), "window": attention.Causal(512),
             "block": BlockDiffusion(length // 2, 4)}[mask]
-    assert attention.attention_form(length, head_dim) == "kernel"
+    assert attention.attention_form(length, head_dim, v_dim) == "kernel"
     one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
     shape = lambda *dims: jax.ShapeDtypeStruct((workers, 1, length) + dims, jnp.float32,
                                                sharding=one_chip)
     attend = jax.vmap(lambda q, k, v: attention.attend(q, k, v, mask, None))
     compiled = compile_uncached(
         jax.jit(jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) ** 2), argnums=(0, 1, 2))),
-        shape(kv_heads, rep, head_dim), shape(kv_heads, head_dim), shape(kv_heads, head_dim))
+        shape(kv_heads, rep, head_dim), shape(kv_heads, head_dim), shape(kv_heads, v_dim))
     text = compiled.as_text()
     calls = re.findall(r"^ *%?([\w.-]*causal_attention_(?:fwd|bwd)[\w.-]*) = .* custom-call\(.*"
                        r'custom_call_target="tpu_custom_call"', text, re.M)
     assert len(calls) == 2 and any("fwd" in name for name in calls) and any(
         "bwd" in name for name in calls), calls
     assert " while(" not in text
-    q_bytes = workers * length * kv_heads * rep * head_dim * 4
-    # the log-sum-exp (as wide as q in HBM), q's copy, the output, its cotangent: 4 q's, and room
-    assert compiled.memory_analysis().temp_size_in_bytes < 5 * q_bytes
+    q_bytes = workers * length * kv_heads * rep * attention.kernel_width(head_dim, v_dim) * 4
+    # the log-sum-exp (as wide as q in HBM), q's copy, the output, its cotangent: 4 q's, and room;
+    # padded, k and v (as large as q at one query head a key head) and the three gradients too
+    assert compiled.memory_analysis().temp_size_in_bytes < (5 if head_dim == v_dim else 9) * q_bytes
