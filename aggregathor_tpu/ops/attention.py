@@ -40,11 +40,16 @@ products carry the process's matmul precision as XLA's do: at the default the
 chip multiplies the float32 operands in one bfloat16 pass (read on the chip:
 0.4 % from ``highest``, PERF.md section 6, PR 36), under ``highest`` in full.
 
-**Two widths by padding.**  The kernels carry one head width; scores wider
-than values (models/deepseek_v3.py's latent attention: 192 over 128) run at the
-next multiple of the lanes that holds both, zeros in the rest
-(``fused_attention``): a third of the score product and half of the value
-product multiply zeros until a kernel carries the two widths itself.
+**Two widths, each its own.**  Scores may be wider than values
+(models/deepseek_v3.py's latent attention: 192 over 128): q, k, dq and dk are
+cut at ``Dqk``, v, the output, its cotangent and dv at ``Dv``, out of the (B, L,
+G * D) arrays as they lie in HBM — no padded copy in, no slice out.  A width
+that is not whole lanes takes the fewest heads a grid step whose widths side by
+side are (two at 192: blocks of 384 and 256 lanes), one head after another; a
+head's scores, dq and dk run over the 128-lane columns it touches (``_span``:
+256 lanes, 64 of them its neighbour's) against queries that hold zeros there,
+so every fold reads and writes whole vregs and the MXU, which contracts 128
+deep a pass, multiplies what it would for 192.
 
 **One chooser** (``attention_form``): on a TPU, for shapes the kernel takes,
 the kernel; else the caller's XLA form.  No flag and no environment variable;
@@ -77,6 +82,14 @@ SKIPPED, CLEAR, EDGED = 0, 1, 2
 #: section 6, PR 36).
 Q_TILE = 256
 K_TILE = 256
+
+#: Queries and keys a tile where a key head serves ONE query head (latent
+#: attention): a tile's products then stream Q_TILE rows, not R x Q_TILE, past
+#: each key tile's weights, and at 256 the fold is the MXU's weight loads and
+#: the row statistics; at 512 x 512 the forward kernel takes 0.56 of its time
+#: and the backward 0.84 (a sweep on the chip over 128-2,048 x 256-1,024,
+#: PERF.md section 6, PR 41).
+LONE_TILE = 512
 
 #: The kernel keeps a head's whole K and V (the backward pass dk and dv too) in
 #: VMEM: L x Dh elements each.  Beyond this many the XLA form runs.
@@ -159,18 +172,58 @@ def _interpret():
     return not hw.on_tpu()
 
 
-def _stack(ref, scr, rep, q_tile, dh, scale=None):
-    """The (q_tile, R * Dh) block of ``ref``, head beside head, into the rows
-    of ``scr`` (R * q_tile, Dh), head under head, as float32."""
+def _whole_lanes(width):
+    """``width`` rounded up to whole 128-lane columns."""
+    return -(-width // LANE) * LANE
+
+
+def _heads_a_step(kv_heads, dqk, dv):
+    """Key heads a grid step: the fewest, dividing ``kv_heads``, whose scores'
+    and values' widths side by side are whole lanes (one at widths of whole
+    lanes; two at 192 over 128), so that a block of them is cut out of the
+    (B, L, G * D) arrays as they lie; one where no count does (a shape only
+    the interpreter takes)."""
+    return next((h for h in range(1, kv_heads + 1)
+                 if kv_heads % h == 0 and h * dqk % LANE == 0 and h * dv % LANE == 0), 1)
+
+
+def _span(head, width, heads):
+    """(first lane, lanes, lanes in front of the head) of the whole lanes that
+    hold head ``head`` of ``width`` in a block of ``heads``: the 128-lane
+    columns it touches — its own where the width is whole lanes; at 192, lanes
+    0-255 for head 0 and 128-383 for head 1, 64 of the other head's in each.
+    A block that is not whole lanes (the interpreter's) is cut lane by lane."""
+    unit = 1 if heads * width % LANE else LANE
+    lo = head * width
+    first = lo // unit * unit
+    return first, -(-(lo + width) // unit) * unit - first, lo - first
+
+
+def _rows_of(head, heads, rows):
+    """Where head ``head`` of a step's ``heads`` lies in the log-sum-exp's
+    (heads * rows, lanes) block, head under head: all of it for a lone head,
+    said as the kernel always said it (equal widths trace the text they
+    traced: tests/test_attention_kernel.py holds its hash)."""
+    return Ellipsis if heads == 1 else slice(head * rows, (head + 1) * rows)
+
+
+def _stack(ref, scr, head, rep, q_tile, width, scale=None, front=0):
+    """The ``rep`` query heads of key head ``head`` in the (q_tile, heads * R *
+    width) block of ``ref``, head beside head, into the rows of ``scr`` (R *
+    q_tile, at least front + width), head under head, ``front`` lanes in, as
+    float32."""
     for r in range(rep):
-        piece = ref[:, r * dh:(r + 1) * dh].astype(jnp.float32)
-        scr[r * q_tile:(r + 1) * q_tile, :] = piece if scale is None else piece * scale
+        at = (head * rep + r) * width
+        piece = ref[:, at:at + width].astype(jnp.float32)
+        scr[r * q_tile:(r + 1) * q_tile, front:front + width] = (
+            piece if scale is None else piece * scale)
 
 
-def _scores(qs, k_ref, j, i, edged, mask, rep, q_tile, k_tile):
+def _scores(qs, k_ref, lanes, j, i, edged, mask, rep, q_tile, k_tile):
     """(R * q_tile, k_tile) float32 scores of the stacked, scaled queries
-    against key tile ``j``, forbidden pairs at NEG where the tile is EDGED."""
-    keys = k_ref[pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile), :].astype(jnp.float32)
+    against ``lanes`` of key tile ``j``, forbidden pairs at NEG where the tile
+    is EDGED."""
+    keys = k_ref[pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile), lanes].astype(jnp.float32)
     s = jax.lax.dot_general(qs, keys, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if not edged:
@@ -200,86 +253,107 @@ def _wide(stat, width):
     return stat if width == lanes else jnp.concatenate([stat] * (width // lanes), axis=1)
 
 
-def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs, m, l, acc, *, mask, slots, rep,
-                q_tile, k_tile, dh):
+def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs, m, l, acc, *, mask, slots, rep, heads,
+                q_tile, k_tile, dqk, dv):
     """The running maximum ``m`` is kept equal along 128 lanes, so no fold
     spreads it again; the running sum ``l`` is kept lane by lane (lane c holds
     the sum over the keys c, c + 128, ... of every tile) and summed across the
-    lanes once, at the end."""
-    i, lanes = pl.program_id(2), l.shape[1]
-    _stack(q_ref, qs, rep, q_tile, dh, 1.0 / math.sqrt(dh))
-    m[...] = jnp.full(m.shape, NEG, jnp.float32)
-    l[...] = jnp.zeros(l.shape, jnp.float32)
-    acc[...] = jnp.zeros(acc.shape, jnp.float32)
+    lanes once, at the end.  The step's ``heads`` key heads one after another,
+    each over its ``_span`` of the keys' lanes: the queries' lanes beside the
+    head's own are zeros, so what the span holds of a neighbour multiplies
+    nothing into the scores."""
+    i, lanes, rows = pl.program_id(2), l.shape[1], rep * q_tile
+    for h in range(heads):
+        first, span, front = _span(h, dqk, heads)
+        if span != dqk:
+            qs[...] = jnp.zeros(qs.shape, jnp.float32)
+        _stack(q_ref, qs, h, rep, q_tile, dqk, 1.0 / math.sqrt(dqk), front)
+        m[...] = jnp.full(m.shape, NEG, jnp.float32)
+        l[...] = jnp.zeros(l.shape, jnp.float32)
+        acc[...] = jnp.zeros(acc.shape, jnp.float32)
 
-    def fold(j, edged):
-        s = _scores(qs[...], k_ref, j, i, edged, mask, rep, q_tile, k_tile)
-        values = v_ref[pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile), :].astype(jnp.float32)
-        new_m = jnp.maximum(m[...], jnp.max(s, axis=1, keepdims=True))
-        corr = jnp.exp(m[...] - new_m)
-        p = jnp.exp(s - _wide(new_m, k_tile))
-        l[...] = l[...] * corr + sum(p[:, c:c + lanes] for c in range(0, k_tile, lanes))
-        acc[...] = acc[...] * _wide(corr, dh) + jnp.dot(p, values,
-                                                        preferred_element_type=jnp.float32)
-        m[...] = new_m
+        def fold(j, edged, across=slice(first, first + span), own=slice(h * dv, (h + 1) * dv)):
+            s = _scores(qs[...], k_ref, across, j, i, edged, mask, rep, q_tile, k_tile)
+            values = v_ref[pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile), own].astype(
+                jnp.float32)
+            new_m = jnp.maximum(m[...], jnp.max(s, axis=1, keepdims=True))
+            corr = jnp.exp(m[...] - new_m)
+            p = jnp.exp(s - _wide(new_m, k_tile))
+            l[...] = l[...] * corr + sum(p[:, c:c + lanes] for c in range(0, k_tile, lanes))
+            acc[...] = acc[...] * _wide(corr, dv) + jnp.dot(p, values,
+                                                            preferred_element_type=jnp.float32)
+            m[...] = new_m
 
-    _loops(i, slots, fold)
-    total = jnp.broadcast_to(jnp.sum(l[...], axis=1, keepdims=True), l.shape)
-    out = acc[...] / _wide(total, dh)
-    for r in range(rep):
-        o_ref[:, r * dh:(r + 1) * dh] = out[r * q_tile:(r + 1) * q_tile].astype(o_ref.dtype)
-    lse_ref[...] = m[...] + jnp.log(total)
+        _loops(i, slots, fold)
+        total = jnp.broadcast_to(jnp.sum(l[...], axis=1, keepdims=True), l.shape)
+        out = acc[...] / _wide(total, dv)
+        for r in range(rep):
+            at = (h * rep + r) * dv
+            o_ref[:, at:at + dv] = out[r * q_tile:(r + 1) * q_tile].astype(o_ref.dtype)
+        lse_ref[_rows_of(h, heads, rows)] = m[...] + jnp.log(total)
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref, dk_ref, dv_ref, qs, dos,
-                delta, dq, *, mask, slots, rep, q_tile, k_tile, dh):
-    i = pl.program_id(2)
-    scale = 1.0 / math.sqrt(dh)
-    _stack(q_ref, qs, rep, q_tile, dh, scale)
-    _stack(do_ref, dos, rep, q_tile, dh)
-    _stack(o_ref, dq, rep, q_tile, dh)   # the output, in dq's room for a moment
-    delta[...] = jnp.broadcast_to(jnp.sum(dos[...] * dq[...], axis=1, keepdims=True), delta.shape)
-    dq[...] = jnp.zeros(dq.shape, jnp.float32)
+                delta, dq, *, mask, slots, rep, heads, q_tile, k_tile, dqk, dv):
+    """Scores, dq and dk over a head's ``_span`` of the keys' lanes (the lanes
+    beside the head's own: zeros in ``qs``, so nothing into the scores and
+    zeros into the neighbour's dk; in ``dq`` what is dropped on the way out);
+    dP and dv over the values' own width."""
+    i, rows = pl.program_id(2), rep * q_tile
+    scale = 1.0 / math.sqrt(dqk)
+    for h in range(heads):
+        first, span, front = _span(h, dqk, heads)
+        if span != dqk:
+            qs[...] = jnp.zeros(qs.shape, jnp.float32)
+        _stack(q_ref, qs, h, rep, q_tile, dqk, scale, front)
+        _stack(do_ref, dos, h, rep, q_tile, dv)
+        _stack(o_ref, dq, h, rep, q_tile, dv)   # the output, in dq's room for a moment
+        delta[...] = jnp.broadcast_to(
+            jnp.sum(dos[...] * (dq[...] if span == dv else dq[:, :dv]), axis=1, keepdims=True),
+            delta.shape)
+        dq[...] = jnp.zeros(dq.shape, jnp.float32)
 
-    @pl.when(i == 0)
-    def _():
-        dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
-        dv_ref[...] = jnp.zeros(dv_ref.shape, jnp.float32)
+        if h == 0:
+            @pl.when(i == 0)
+            def _():
+                dk_ref[...] = jnp.zeros(dk_ref.shape, jnp.float32)
+                dv_ref[...] = jnp.zeros(dv_ref.shape, jnp.float32)
 
-    def fold(j, edged):
-        rows = pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile)
-        s = _scores(qs[...], k_ref, j, i, edged, mask, rep, q_tile, k_tile)
-        p = jnp.exp(s - _wide(lse_ref[...], k_tile))
-        dp = jax.lax.dot_general(dos[...], v_ref[rows, :].astype(jnp.float32),
-                                 (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
-        ds = p * (dp - _wide(delta[...], k_tile))
-        dq[...] += jnp.dot(ds, k_ref[rows, :].astype(jnp.float32),
-                           preferred_element_type=jnp.float32)
-        over_rows = (((0,), (0,)), ((), ()))
-        dk_ref[rows, :] += jax.lax.dot_general(ds, qs[...], over_rows,
-                                               preferred_element_type=jnp.float32)
-        dv_ref[rows, :] += jax.lax.dot_general(p, dos[...], over_rows,
-                                               preferred_element_type=jnp.float32)
+        def fold(j, edged, across=slice(first, first + span), own=slice(h * dv, (h + 1) * dv),
+                 stats=_rows_of(h, heads, rows)):
+            tile = pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile)
+            s = _scores(qs[...], k_ref, across, j, i, edged, mask, rep, q_tile, k_tile)
+            p = jnp.exp(s - _wide(lse_ref[stats], k_tile))
+            dp = jax.lax.dot_general(dos[...], v_ref[tile, own].astype(jnp.float32),
+                                     (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+            ds = p * (dp - _wide(delta[...], k_tile))
+            dq[...] += jnp.dot(ds, k_ref[tile, across].astype(jnp.float32),
+                               preferred_element_type=jnp.float32)
+            over_rows = (((0,), (0,)), ((), ()))
+            dk_ref[tile, across] += jax.lax.dot_general(ds, qs[...], over_rows,
+                                                        preferred_element_type=jnp.float32)
+            dv_ref[tile, own] += jax.lax.dot_general(p, dos[...], over_rows,
+                                                     preferred_element_type=jnp.float32)
 
-    _loops(i, slots, fold)
-    for r in range(rep):
-        dq_ref[:, r * dh:(r + 1) * dh] = (dq[r * q_tile:(r + 1) * q_tile] * scale).astype(
-            dq_ref.dtype)
+        _loops(i, slots, fold)
+        for r in range(rep):
+            at = (h * rep + r) * dqk
+            dq_ref[:, at:at + dqk] = (dq[r * q_tile:(r + 1) * q_tile, front:front + dqk]
+                                      * scale).astype(dq_ref.dtype)
 
 
-def _lanes(k_tile, dh):
+def _lanes(k_tile, dv):
     """Lanes a row statistic is kept along: a vreg's 128 at the shapes the
     compiled kernel takes, fewer under the interpreter's small tiles."""
-    return math.gcd(LANE, k_tile, dh)
+    return math.gcd(LANE, k_tile, dv)
 
 
-def _specs(length, rep, dh, q_tile, lanes):
-    """Block specs of the (B, L, G * R * Dh) queries' kind, the (B, L, G * Dh)
-    keys' kind and the (B, G, L * R, lanes) log-sum-exp, on the grid (b, g, i)."""
-    per_query = pl.BlockSpec((None, q_tile, rep * dh), lambda b, g, i: (b, i, g))
-    per_head = pl.BlockSpec((None, length, dh), lambda b, g, i: (b, 0, g))
-    per_row = pl.BlockSpec((None, None, rep * q_tile, lanes), lambda b, g, i: (b, g, i, 0))
-    return per_query, per_head, per_row
+def _specs(length, rep, heads, width, q_tile):
+    """Block specs of the (B, L, G * R * width) queries' kind and the (B, L, G *
+    width) keys' kind, ``heads`` key heads a step, on the grid (b, g, i)."""
+    per_query = pl.BlockSpec((None, q_tile, heads * rep * width), lambda b, g, i: (b, i, g))
+    per_head = pl.BlockSpec((None, length, heads * width), lambda b, g, i: (b, 0, g))
+    return per_query, per_head
 
 
 def _call(kernel, name, out_shape, in_specs, out_specs, scratch, grid, sequential, **static):
@@ -291,30 +365,38 @@ def _call(kernel, name, out_shape, in_specs, out_specs, scratch, grid, sequentia
             vmem_limit_bytes=VMEM_LIMIT))
 
 
-def _static(q, plan):
+def _static(q, v, plan):
     """What both kernels are told of the call, from its ``plan`` — (mask, its
-    loops, queries a tile, keys a tile): (keyword arguments, block specs,
-    stacked rows a tile, lanes of a row statistic)."""
+    loops, queries a tile, keys a tile): (keyword arguments; block specs at the
+    scores' width, at the values' and of the log-sum-exp; the grid; lanes of a
+    row statistic; lanes of a head's span; float32 scratch of a head's stacked
+    rows a tile, by width)."""
     mask, slots, q_tile, k_tile = plan
-    _, length, _, rep, dh = q.shape
-    lanes = _lanes(k_tile, dh)
-    return (dict(mask=mask, slots=slots, rep=rep, q_tile=q_tile, k_tile=k_tile, dh=dh),
-            _specs(length, rep, dh, q_tile, lanes), rep * q_tile, lanes)
+    b, length, g, rep, dqk = q.shape
+    dv = v.shape[-1]
+    heads, lanes, rows = _heads_a_step(g, dqk, dv), _lanes(k_tile, dv), rep * q_tile
+    per_row = pl.BlockSpec((None, None, heads * rows, lanes), lambda b, g, i: (b, g, i, 0))
+    return (dict(mask=mask, slots=slots, rep=rep, heads=heads, q_tile=q_tile, k_tile=k_tile,
+                 dqk=dqk, dv=dv),
+            _specs(length, rep, heads, dqk, q_tile) + _specs(length, rep, heads, dv, q_tile)
+            + (per_row,), (b, g // heads, length // q_tile), lanes, _span(0, dqk, heads)[1],
+            lambda width: pltpu.VMEM((rows, width), jnp.float32))
 
 
 def _forward(q, k, v, plan):
-    b, length, g, rep, dh = q.shape
-    static, (per_query, per_head, per_row), rows, lanes = _static(q, plan)
+    """(the output (B, L, G * R * Dv), the log-sum-exp (B, G / heads a step, L *
+    R * heads a step, lanes): a query tile's rows head under head)."""
+    b, length, g, rep, dqk = q.shape
+    dv = v.shape[-1]
+    static, (q_wide, k_wide, o_wide, v_wide, per_row), grid, lanes, span, room = _static(q, v, plan)
     return _call(
         _fwd_kernel, "causal_attention_fwd",
-        (jax.ShapeDtypeStruct((b, length, g * rep * dh), q.dtype),
-         jax.ShapeDtypeStruct((b, g, length * rep, lanes), jnp.float32)),
-        [per_query, per_head, per_head], (per_query, per_row),
-        [pltpu.VMEM((rows, dh), jnp.float32), pltpu.VMEM((rows, lanes), jnp.float32),
-         pltpu.VMEM((rows, lanes), jnp.float32), pltpu.VMEM((rows, dh), jnp.float32)],
-        (b, g, length // plan[2]), False, **static)(
-            q.reshape(b, length, g * rep * dh), k.reshape(b, length, g * dh),
-            v.reshape(b, length, g * dh))
+        (jax.ShapeDtypeStruct((b, length, g * rep * dv), q.dtype),
+         jax.ShapeDtypeStruct((b, grid[1], length * rep * static["heads"], lanes), jnp.float32)),
+        [q_wide, k_wide, v_wide], (o_wide, per_row),
+        [room(span), room(lanes), room(lanes), room(dv)], grid, False, **static)(
+            q.reshape(b, length, g * rep * dqk), k.reshape(b, length, g * dqk),
+            v.reshape(b, length, g * dv))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -329,47 +411,33 @@ def _fused_fwd(q, k, v, plan):
 
 def _fused_bwd(plan, kept, dout):
     q, k, v, out, lse = kept
-    b, length, g, rep, dh = q.shape
-    static, (per_query, per_head, per_row), rows, lanes = _static(q, plan)
-    summed = jax.ShapeDtypeStruct((b, length, g * dh), jnp.float32)
-    dq, dk, dv = _call(
+    b, length, g, rep, dqk = q.shape
+    dv = v.shape[-1]
+    static, (q_wide, k_wide, o_wide, v_wide, per_row), grid, lanes, span, room = _static(q, v, plan)
+    grads = _call(
         _bwd_kernel, "causal_attention_bwd",
-        (jax.ShapeDtypeStruct((b, length, g * rep * dh), q.dtype), summed, summed),
-        [per_query, per_head, per_head, per_query, per_row, per_query],
-        (per_query, per_head, per_head),
-        [pltpu.VMEM((rows, dh), jnp.float32), pltpu.VMEM((rows, dh), jnp.float32),
-         pltpu.VMEM((rows, lanes), jnp.float32), pltpu.VMEM((rows, dh), jnp.float32)],
-        (b, g, length // plan[2]), True, **static)(
-            q.reshape(b, length, g * rep * dh), k.reshape(b, length, g * dh),
-            v.reshape(b, length, g * dh), out, lse, dout)
-    return (dq.reshape(q.shape), dk.reshape(k.shape).astype(k.dtype),
-            dv.reshape(v.shape).astype(v.dtype))
+        (jax.ShapeDtypeStruct((b, length, g * rep * dqk), q.dtype),
+         jax.ShapeDtypeStruct((b, length, g * dqk), jnp.float32),
+         jax.ShapeDtypeStruct((b, length, g * dv), jnp.float32)),
+        [q_wide, k_wide, v_wide, o_wide, per_row, o_wide], (q_wide, k_wide, v_wide),
+        [room(span), room(dv), room(lanes), room(span)], grid, True, **static)(
+            q.reshape(b, length, g * rep * dqk), k.reshape(b, length, g * dqk),
+            v.reshape(b, length, g * dv), out, lse, dout)
+    return tuple(grad.reshape(a.shape).astype(a.dtype) for grad, a in zip(grads, (q, k, v)))
 
 
 _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
 def fused_attention(q, k, v, mask, q_tile, k_tile):
-    """q (B, L, G, R, Dqk), k (B, L, G, Dqk) and v (B, L, G, Dv) -> (B, L, G *
-    R * Dv): the softmax over the keys ``mask`` allows of ``q . k / sqrt(Dqk)``,
-    times v, with its own backward pass.  ``L`` is a multiple of both tiles;
-    every query reads some key.  The tile table is made here, at trace time,
-    from the static arguments alone; the kernels are handed its loops.
-
-    The kernels carry ONE head width.  Where scores and values differ in width
-    (latent attention: 192 over 128) q, k and v are padded with zeros to
-    ``kernel_width``, ``sqrt(width / Dqk)`` goes into q so that the kernel's own
-    ``1 / sqrt(width)`` gives ``1 / sqrt(Dqk)``, and the padded end of each
-    head's output is dropped: the same numbers, with the zeros multiplied."""
+    """q (B, L, G, R, Dqk), k (B, L, G, Dqk) and v (B, L, G, Dv), Dv no wider
+    than Dqk -> (B, L, G * R * Dv): the softmax over the keys ``mask`` allows
+    of ``q . k / sqrt(Dqk)``, times v, with its own backward pass.  ``L`` is a
+    multiple of both tiles; every query reads some key.  The tile table is made
+    here, at trace time, from the static arguments alone; the kernels are
+    handed its loops, and q, k and v at the widths they have."""
     plan = (mask, _slots(tile_table(mask, q.shape[1], q_tile, k_tile)), q_tile, k_tile)
-    qk_dim, v_dim = q.shape[-1], v.shape[-1]
-    if qk_dim == v_dim:
-        return _fused(q, k, v, plan)
-    width = kernel_width(qk_dim, v_dim)
-    widened = lambda a: jnp.pad(a, [(0, 0)] * (a.ndim - 1) + [(0, width - a.shape[-1])])
-    out = _fused(widened(q * math.sqrt(width / qk_dim)), widened(k), widened(v), plan)
-    b, length, g, rep, _ = q.shape
-    return out.reshape(b, length, g * rep, width)[..., :v_dim].reshape(b, length, g * rep * v_dim)
+    return _fused(q, k, v, plan)
 
 
 # --------------------------------------------------------------------------- #
@@ -395,36 +463,38 @@ def forced_form(form):
         _forced = previous
 
 
-def tiles_for(length):
-    """(queries, keys) a tile for a sequence of ``length``: the kernel's own,
-    or the whole of a shorter sequence."""
+def tiles_for(length, rep):
+    """(queries, keys) a tile for a sequence of ``length`` under ``rep`` query
+    heads a key head: ``LONE_TILE`` for one, where it divides the length; else
+    the kernel's own, or the whole of a shorter sequence."""
+    if rep == 1 and length % LONE_TILE == 0:
+        return LONE_TILE, LONE_TILE
     return min(Q_TILE, length), min(K_TILE, length)
 
 
-def kernel_width(qk_dim, v_dim):
-    """The one head width the kernel runs q, k and v at: their own where they
-    agree, else the next multiple of the 128 lanes that holds both."""
-    return qk_dim if qk_dim == v_dim else -(-max(qk_dim, v_dim) // LANE) * LANE
-
-
-def attention_form(length, head_dim, v_dim=None):
-    """``"kernel"`` or ``"xla"`` for a sequence of ``length`` under heads of
-    ``head_dim`` (values of ``v_dim``, where they differ): the kernel on a TPU
-    (``utils.hw.on_tpu``) where it takes the shape — ``length`` a multiple of
-    both tiles and of the 8 sublanes, the head as the kernel runs it
-    (``kernel_width``) of the 128 lanes, a head's K and V at that width within
-    ``RESIDENT_MAX`` — and the caller's XLA form everywhere else.  Inside
-    ``forced_form`` the forced form answers, for any shape whose length
-    divides into the tiles."""
-    q_tile, k_tile = tiles_for(length)
+def attention_form(length, head_dim, v_dim=None, kv_heads=1, rep=1):
+    """``"kernel"`` or ``"xla"`` for a sequence of ``length`` under ``kv_heads``
+    key heads of ``head_dim`` (values of ``v_dim``, where they differ), ``rep``
+    query heads each: the kernel on a TPU (``utils.hw.on_tpu``) where it takes
+    the shape — ``length`` a multiple of both tiles and of the 8 sublanes;
+    values of whole lanes, no wider than the scores; scores of whole lanes, or
+    one query head a key head and a count of key heads that ``_heads_a_step``
+    divides into blocks of whole lanes (192: an even count); a head's K and V,
+    each at its width rounded up to whole lanes, within ``RESIDENT_MAX`` a piece
+    — and the caller's XLA form everywhere else.  Inside ``forced_form`` the
+    forced form answers, for any shape whose length divides into the tiles."""
+    q_tile, k_tile = tiles_for(length, rep)
     divides = length % q_tile == 0 and length % k_tile == 0
     if _forced is not None:
         if _forced == "kernel" and not divides:
             raise ValueError("the attention kernel takes a length that divides into its tiles "
                              "(%d, %d), not %d" % (q_tile, k_tile, length))
         return _forced
-    width = kernel_width(head_dim, v_dim or head_dim)
-    takes = divides and length % 8 == 0 and width % LANE == 0 and length * width <= RESIDENT_MAX
+    v_dim = v_dim or head_dim
+    blocks = head_dim % LANE == 0 or (
+        rep == 1 and _heads_a_step(kv_heads, head_dim, v_dim) * head_dim % LANE == 0)
+    takes = (divides and length % 8 == 0 and v_dim % LANE == 0 and v_dim <= head_dim and blocks
+             and length * (_whole_lanes(head_dim) + _whole_lanes(v_dim)) <= 2 * RESIDENT_MAX)
     return "kernel" if hw.on_tpu() and takes else "xla"
 
 
@@ -435,8 +505,9 @@ def _announce(form, shape, v_dim, mask, tiles):
         counts = "; tiles of %dx%d a head: %d clear, %d edged, %d skipped" % (
             tiles + tuple(table_counts(tile_table(mask, shape[1], *tiles)).values()))
     if v_dim != shape[-1]:
-        widths = " over values of %d" % v_dim + (
-            ", padded to %d" % kernel_width(shape[-1], v_dim) if form == "kernel" else "")
+        widths = " over values of %d" % v_dim
+        if form == "kernel":
+            form = "kernel at %d / %d" % (shape[-1], v_dim)
     info("attention form for q %s%s under %r: %s%s"
          % ("x".join(map(str, shape)), widths, mask, form, counts))
 
@@ -446,8 +517,9 @@ def attend(q, k, v, mask, xla_form):
     ``xla_form(q, k, v)``; v may be narrower than q and k (``fused_attention``).
     On a TPU each decision is logged once a shape and mask, with the tile
     table's three counts."""
-    length = q.shape[1]
-    form, tiles = attention_form(length, q.shape[-1], v.shape[-1]), tiles_for(length)
+    _, length, kv_heads, rep, head_dim = q.shape
+    form = attention_form(length, head_dim, v.shape[-1], kv_heads, rep)
+    tiles = tiles_for(length, rep)
     if hw.on_tpu():
         _announce(form, tuple(q.shape), v.shape[-1], mask, tiles)
     return fused_attention(q, k, v, mask, *tiles) if form == "kernel" else xla_form(q, k, v)

@@ -196,7 +196,7 @@ def _sdar_attention(block):
 def _latent_attention(length, kv_heads, head_dim):
     """models/deepseek_v3.py's: every head its own key head (four times the
     others' ``kv_heads``: 16), scores half again as wide as the values (192
-    over 128), which the kernel runs padded to one width."""
+    over 128), each of which the kernel carries at its own width."""
     from aggregathor_tpu.models import deepseek_v3, laguna
     from aggregathor_tpu.ops import attention
 
@@ -275,7 +275,7 @@ def run_attention_check(reps=5, tiles=(), length=4096, kv_heads=4, head_dim=128,
                 return call
             return [enter(fn) for fn in forms(model_attention)]
 
-        q_tile, k_tile = attention.tiles_for(length)
+        q_tile, k_tile = attention.tiles_for(length, rep)
         row = {"metric": "pallas_tpu_check", "rule": "attention-" + name, "workers": workers,
                "length": length, "heads": "%d/%d" % (key_heads * rep, key_heads),
                "widths": "%d/%d" % (qk_dim, v_dim), "mask": repr(mask),

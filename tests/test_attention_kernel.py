@@ -60,12 +60,14 @@ def stepped(attend):
     return jax.jit(jax.value_and_grad(total, argnums=(0, 1, 2)))
 
 
-def seeded(rep, dtype=jnp.float32, lead=(LAYERS, WORKERS, 1)):
-    key = jax.random.PRNGKey(5)
+def seeded(rep, dtype=jnp.float32, lead=(LAYERS, WORKERS, 1), widths=(HEAD_DIM, HEAD_DIM),
+           kv_heads=KV_HEADS, seed=5):
+    """q, k, v and a weight as wide as the output; ``widths`` the scores' and the values'."""
+    key, (qk_dim, v_dim) = jax.random.PRNGKey(seed), widths
     normal = lambda place, *dims: jax.random.normal(
         jax.random.fold_in(key, place), lead + (LENGTH,) + dims).astype(dtype)
-    return (normal(0, KV_HEADS, rep, HEAD_DIM), normal(1, KV_HEADS, HEAD_DIM),
-            normal(2, KV_HEADS, HEAD_DIM), normal(3, KV_HEADS * rep * HEAD_DIM))
+    return (normal(0, kv_heads, rep, qk_dim), normal(1, kv_heads, qk_dim),
+            normal(2, kv_heads, v_dim), normal(3, kv_heads * rep * v_dim))
 
 
 # a window of 12 cuts every tile of 8 it touches, 20 leaves one clear, 5 is under a tile
@@ -295,7 +297,7 @@ def test_the_float32_sdar_loss_with_the_kernel_forced_holds_no_narrow_product():
     assert all(jnp.finfo(dtype).bits >= 32 for operands, _ in products for dtype in operands)
 
 
-@pytest.mark.parametrize("name", ["full", "window", "block-diffusion"])
+@pytest.mark.parametrize("name", ["full", "window", "block-diffusion", "latent"])
 def test_the_check_scripts_attention_column_runs_interpreted(name):
     """scripts/pallas_tpu_check.py ``run_attention_check`` — the chip's parity
     and timing of the kernel against each model's XLA form — off a TPU at a
@@ -320,7 +322,8 @@ def test_the_check_scripts_attention_column_runs_interpreted(name):
         "attention-" + name, "attention-%s-tiles" % name]
     assert rows[0]["parity"] == "ok" and rows[0]["workers"] == shapes[0][1]
     assert rows[0]["mask"] == {"full": "Causal(window=None)", "window": "Causal(window=512)",
-                               "block-diffusion": "BlockDiffusion(half=16, block=4)"}[name]
+                               "block-diffusion": "BlockDiffusion(half=16, block=4)",
+                               "latent": "Causal(window=None)"}[name]
     assert "kernel_fwd_bwd_ms" in rows[1] and "error" not in rows[1]
     with pytest.raises(RuntimeError):
         pallas_tpu_check.run_attention_check(reps=1, length=LENGTH, shapes=shapes)
@@ -337,32 +340,18 @@ def naive_attention(q, k, v):
     return dense_attention(q, k, v, Causal())
 
 
-@pytest.mark.parametrize("form", ["kernel", "xla"])
-@pytest.mark.parametrize("widths,rep", [((192, 128), 1), ((24, 16), 3)],
-                         ids=["192-over-128", "24-over-16-grouped"])
-def test_two_widths_are_a_naive_softmax(widths, rep, form):
-    """q and k of 192, v of 128 (latent attention's widths; and a small pair
-    with grouped queries) through ``attend``: the interpreted kernel by padding
-    to one width of whole lanes (256; 128), and models/laguna.py's
-    ``chunked_attention`` with its accumulator as wide as v, against the naive
-    softmax — output and the gradients of q, k and v, under ``vmap`` +
-    ``checkpoint`` + ``scan`` as the step calls it.  The scale is the true
-    width's: ``1 / sqrt(192)``, not the padded 256's."""
-    qk_dim, v_dim = widths
-    key = jax.random.PRNGKey(9)
-    normal = lambda place, *dims: jax.random.normal(
-        jax.random.fold_in(key, place), (LAYERS, WORKERS, 1, LENGTH) + dims)
-    q, k, v, w = (normal(0, KV_HEADS, rep, qk_dim), normal(1, KV_HEADS, qk_dim),
-                  normal(2, KV_HEADS, v_dim), normal(3, KV_HEADS * rep * v_dim))
-    cfg = laguna.LagunaConfig(seq=LENGTH, attn_chunk=8)
+def equations_of(jaxpr):
+    """Every equation of a jaxpr, inner jaxprs included, kernels' bodies not."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        if eqn.primitive.name != "pallas_call":
+            for inner in jax.core.jaxprs_in_params(eqn.params):
+                yield from equations_of(inner)
 
-    def attend(q, k, v):
-        with attention.forced_form(form):
-            return attention.attend(q, k, v, Causal(),
-                                    lambda q, k, v: laguna.chunked_attention(q, k, v, cfg, None))
 
-    traced = kernels_in(jax.make_jaxpr(attend)(q[0, 0], k[0, 0], v[0, 0]).jaxpr)
-    assert traced == (["causal_attention_fwd"] if form == "kernel" else [])
+def assert_a_naive_softmax(attend, q, k, v, w):
+    """Output and the gradients of q, k and v against the naive softmax, under
+    ``vmap`` + ``checkpoint`` + ``scan`` as the step calls it."""
     (ours, ours_grads), (theirs, theirs_grads) = (stepped(attend)(q, k, v, w),
                                                   stepped(naive_attention)(q, k, v, w))
     assert abs(float(ours) - float(theirs)) <= 1e-5 * abs(float(theirs))
@@ -370,24 +359,95 @@ def test_two_widths_are_a_naive_softmax(widths, rep, form):
         assert mine.shape == naive.shape and mine.dtype == naive.dtype
         np.testing.assert_allclose(np.asarray(mine), np.asarray(naive), rtol=1e-4, atol=2e-5)
     out = jax.vmap(attend)(q[0], k[0], v[0])
-    assert out.shape == (WORKERS, 1, LENGTH, KV_HEADS * rep * v_dim)
+    assert out.shape == w.shape[1:]
     np.testing.assert_allclose(np.asarray(out), np.asarray(jax.vmap(naive_attention)(q[0], k[0], v[0])),
                                rtol=1e-5, atol=1e-5)
 
 
-@pytest.mark.parametrize("length,head_dim,v_dim,form", [
-    (4096, 192, 128, "kernel"),   # the grid's Kanana cell: padded to 256, K and V exactly RESIDENT_MAX
-    (4096, 192, 192, "xla"),      # equal widths that are not whole lanes: no padding is tried
-    (4096 + 256, 192, 128, "xla"),  # padded, a head's K and V would not stay in VMEM
-    (4096, 128, 64, "kernel"),    # padded to 128
-    (4096, 128, 128, "kernel"),   # equal widths of whole lanes: as before
+@pytest.mark.parametrize("form", ["kernel", "xla"])
+@pytest.mark.parametrize("widths,rep", [((192, 128), 1), ((24, 16), 3)],
+                         ids=["192-over-128", "24-over-16-grouped"])
+def test_two_widths_are_a_naive_softmax(widths, rep, form):
+    """q and k of 192, v of 128 (latent attention's widths; and a small pair
+    with grouped queries) through ``attend``: the interpreted kernel at the two
+    widths as they are (192 / 128: the two key heads in one grid step, each
+    over its span of 256 of the block's 384 lanes; 24 / 16: a head a step), and
+    models/laguna.py's ``chunked_attention`` with its accumulator as wide as v,
+    against the naive softmax.  The scale is ``1 / sqrt(192)``.  Nothing is
+    padded in front of the kernel and what it writes is as wide as v."""
+    qk_dim, v_dim = widths
+    q, k, v, w = seeded(rep, widths=widths, seed=9)
+    cfg = laguna.LagunaConfig(seq=LENGTH, attn_chunk=8)
+
+    def attend(q, k, v):
+        with attention.forced_form(form):
+            return attention.attend(q, k, v, Causal(),
+                                    lambda q, k, v: laguna.chunked_attention(q, k, v, cfg, None))
+
+    traced = jax.make_jaxpr(attend)(q[0, 0], k[0, 0], v[0, 0]).jaxpr
+    assert kernels_in(traced) == (["causal_attention_fwd"] if form == "kernel" else [])
+    if form == "kernel":
+        equations = list(equations_of(jax.make_jaxpr(jax.grad(
+            lambda q, k, v: jnp.sum(attend(q, k, v)), argnums=(0, 1, 2)))(
+                q[0, 0], k[0, 0], v[0, 0]).jaxpr))
+        assert not [eqn for eqn in equations if eqn.primitive.name in ("pad", "slice")]
+        wide = {eqn.params["name"]: [out.aval.shape[-1] for out in eqn.outvars]
+                for eqn in equations if eqn.primitive.name == "pallas_call"}
+        assert wide["causal_attention_fwd"][0] == KV_HEADS * rep * v_dim
+        assert wide["causal_attention_bwd"] == [KV_HEADS * rep * qk_dim, KV_HEADS * qk_dim,
+                                                KV_HEADS * v_dim]
+    assert_a_naive_softmax(attend, q, k, v, w)
+
+
+@pytest.mark.parametrize("on_tpu", [False, True], ids=["forced-kernel", "as-the-chooser-says"])
+def test_an_odd_head_count_at_two_widths_is_a_naive_softmax(monkeypatch, on_tpu):
+    """Three key heads of 192 over 128 divide into no block of whole lanes:
+    forced (interpreted), the kernel takes them a head a step; on a TPU the
+    chooser says ``xla`` — as for a second query head a key head — and
+    ``attend`` traces the caller's form.  Either way, the naive softmax."""
+    kv_heads, (qk_dim, v_dim) = 3, (192, 128)
+    assert attention._heads_a_step(kv_heads, qk_dim, v_dim) == 1
+    q, k, v, w = seeded(1, widths=(qk_dim, v_dim), kv_heads=kv_heads, seed=9)
+    cfg = laguna.LagunaConfig(seq=LENGTH, attn_chunk=8)
+    chunked = lambda q, k, v: laguna.chunked_attention(q, k, v, cfg, None)
+    if on_tpu:
+        monkeypatch.setattr(attention.hw, "on_tpu", lambda: True)
+        monkeypatch.setattr(attention, "info", lambda *_: None)
+        assert attention.attention_form(4096, qk_dim, v_dim, kv_heads, 1) == "xla"
+        assert attention.attention_form(4096, qk_dim, v_dim, 16, 2) == "xla"
+        assert attention.attention_form(4096, qk_dim, v_dim, 16, 1) == "kernel"
+        attend = lambda q, k, v: attention.attend(q, k, v, Causal(), chunked)
+    else:
+        def attend(q, k, v):
+            with attention.forced_form("kernel"):
+                return attention.attend(q, k, v, Causal(), chunked)
+    traced = kernels_in(jax.make_jaxpr(attend)(q[0, 0], k[0, 0], v[0, 0]).jaxpr)
+    assert traced == ([] if on_tpu else ["causal_attention_fwd"])
+    assert_a_naive_softmax(attend, q, k, v, w)
+
+
+@pytest.mark.parametrize("length,head_dim,v_dim,kv_heads,form", [
+    (4096, 192, 128, 16, "kernel"),   # the grid's Kanana cell: two heads a step, 4096 x (256 + 128)
+    (4096, 192, 192, 16, "xla"),      # values that are not whole lanes
+    (5632, 192, 128, 16, "xla"),      # 5632 x (256 + 128): a head's K and V would not stay in VMEM
+    (4096, 128, 64, 16, "xla"),       # values that are not whole lanes, under scores that are
+    (4096, 128, 128, 4, "kernel"),    # equal widths of whole lanes: as before
 ])
-def test_the_chooser_admits_two_widths_by_their_padded_width(monkeypatch, length, head_dim, v_dim,
-                                                             form):
+def test_the_chooser_admits_two_widths_by_what_is_resident(monkeypatch, length, head_dim, v_dim,
+                                                           kv_heads, form):
     monkeypatch.setattr(attention.hw, "on_tpu", lambda: True)
-    assert attention.attention_form(length, head_dim, v_dim) == form
-    assert attention.kernel_width(192, 128) == 256 and attention.kernel_width(128, 128) == 128
-    assert attention.kernel_width(16, 16) == 16 and attention.kernel_width(24, 16) == 128
+    assert attention.attention_form(length, head_dim, v_dim, kv_heads) == form
+    # equal widths: the rule they had, to the number
+    assert attention.attention_form(8192, 128) == "kernel"
+    assert attention.attention_form(8192 + 256, 128) == "xla"
+    assert attention.attention_form(5376, 192, 128, 16) == "kernel"   # 5376 x 384 <= 2 x RESIDENT_MAX
+    # heads a grid step, and the whole lanes a head's scores run over inside the block
+    assert attention._heads_a_step(16, 192, 128) == 2 and attention._heads_a_step(4, 128, 128) == 1
+    assert attention._span(0, 192, 2) == (0, 256, 0) and attention._span(1, 192, 2) == (128, 256, 64)
+    assert attention._span(3, 128, 1) == (384, 128, 0) and attention._span(1, 24, 1) == (24, 24, 0)
+    # tiles: 512 where a key head serves one query head and the length divides, else 256
+    assert attention.tiles_for(4096, 1) == (512, 512) and attention.tiles_for(4096, 6) == (256, 256)
+    assert attention.tiles_for(4096 + 256, 1) == (256, 256) and attention.tiles_for(32, 1) == (32, 32)
 
 
 #: sha256 (first 16 hex digits) of the jaxpr of loss-and-gradient of the tiny float32 Laguna and

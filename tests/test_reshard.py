@@ -241,13 +241,14 @@ def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, mon
     heads a kv head, models/sdar.py's predicate over [noisy ; clean] of 2,048
     each in blocks of 4, which has to lower inside the Mosaic kernel — and as
     ``kanana_avgmedian_causal4k``'s does — three workers, 16 heads each its own
-    key head, scores over 192 and values of 128 padded to the kernel's one
-    width of 256, a head's K and V exactly ``RESIDENT_MAX`` — compile
-    for the described chip: the tiles fit VMEM, every slice is
-    on a tile boundary, and what the two leave in HBM beside q, k, v, the
+    key head, scores over 192 and values of 128, each at its own width: two
+    heads a grid step, blocks of 384 and 256 lanes cut out of q, k and v as
+    they lie — compile for the described chip: the tiles fit VMEM, every slice
+    is on a tile boundary, and what the two leave in HBM beside q, k, v, the
     output and their gradients is one log-sum-exp a query a head (128 lanes
     wide as the chip stores it) and a row-major copy of q: nothing
-    score-shaped, nothing a fold."""
+    score-shaped, nothing a fold, and at two widths no padded copy of q, k or
+    v in and nothing 256 lanes a head out."""
     from jax.sharding import SingleDeviceSharding
 
     from aggregathor_tpu.models.sdar import BlockDiffusion
@@ -258,7 +259,7 @@ def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, mon
     length, (head_dim, v_dim) = 4096, widths
     mask = {"full": attention.Causal(None), "window": attention.Causal(512),
             "block": BlockDiffusion(length // 2, 4)}[mask]
-    assert attention.attention_form(length, head_dim, v_dim) == "kernel"
+    assert attention.attention_form(length, head_dim, v_dim, kv_heads, rep) == "kernel"
     one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
     shape = lambda *dims: jax.ShapeDtypeStruct((workers, 1, length) + dims, jnp.float32,
                                                sharding=one_chip)
@@ -272,7 +273,15 @@ def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, mon
     assert len(calls) == 2 and any("fwd" in name for name in calls) and any(
         "bwd" in name for name in calls), calls
     assert " while(" not in text
-    q_bytes = workers * length * kv_heads * rep * attention.kernel_width(head_dim, v_dim) * 4
-    # the log-sum-exp (as wide as q in HBM), q's copy, the output, its cotangent: 4 q's, and room;
-    # padded, k and v (as large as q at one query head a key head) and the three gradients too
-    assert compiled.memory_analysis().temp_size_in_bytes < (5 if head_dim == v_dim else 9) * q_bytes
+    q_bytes = workers * length * kv_heads * rep * head_dim * 4
+    # the log-sum-exp (128 lanes a query a head in HBM), q's copy, the output, its cotangent: 4 q's
+    # at equal widths, and room; no more at two
+    assert compiled.memory_analysis().temp_size_in_bytes < 5 * q_bytes
+    if head_dim != v_dim:
+        padded = kv_heads * rep * attention._whole_lanes(head_dim)
+        assert " pad(" not in text and not re.search(r"f32\[[\d,]*\b(%d|%d)\]" % (
+            padded, attention._whole_lanes(head_dim)), text)
+        wide = lambda width: "f32[%d,1,%d,%d]" % (workers, length, kv_heads * rep * width)
+        kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
+        assert all(wide(v_dim) in line for line in kernels)     # the output; v's gradient
+        assert any(line.count(wide(head_dim)) >= 2 for line in kernels)  # q's and k's
