@@ -11,9 +11,11 @@ The model's parts are a cut of ``step.grad`` and ``step.augment`` only, so
 they cover far less than the step and no cover is demanded of them; what no
 part names (the scan's own copies, the sampling, the rows, the rule, the
 update) is ``unnamed``; the step's own phase table is printed beside them
-(phase_reduce.py ``phases``), which in this cell no other metric asks for.  A program without ``MODEL_PREFIX`` (the parent of
-PR 31), or whose model names no part, gives nothing to read: every reader
-returns None and the harness leaves the metric out.
+(phase_reduce.py ``phases``: since PR 42 the six phase metrics list the three
+language cells and have read it by then; in a cell that a later PR adds, and
+that they do not list, nothing else prints it).  A program without
+``MODEL_PREFIX`` (the parent of PR 31), or whose model names no part, gives
+nothing to read: every reader returns None and the harness leaves the metric out.
 """
 
 import collections
@@ -37,8 +39,8 @@ def parts(ctx):
               "(obs.profiler.MODEL_PREFIX): nothing to read", flush=True)
         return None
     reduced, raw = ctx["trace"], ctx["raw_trace"]
-    try:  # the step's own phases beside the model's parts (the "grid phases" line):
-        phases(ctx)  # no accepted metric of this cell reads them, so nothing else prints them
+    try:  # the step's own phases beside the model's parts (the "grid phases" line), for a
+        phases(ctx)  # cell the phase metrics do not list: there nothing else prints them
     except TraceContradiction as contradiction:
         print("grid phases: %s" % contradiction, flush=True)
     try:

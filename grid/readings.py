@@ -71,6 +71,15 @@ def main():
     reference = check.PlainReference(cell)
     followed = {}
 
+    def reference_steps(seed, later):
+        """The reference's steps from ``seed``, kept only while one of the
+        ``later`` passes' seeds will ask for them again: a seed's vectors are
+        2.4 GB of the host's memory at d = 305 M."""
+        steps = followed.pop(seed, None) or reference.follow(seed)
+        if seed in later:
+            followed[seed] = steps
+        return steps
+
     def emit(reading, steps, record):
         print("grid reading %s" % json.dumps(reading), flush=True)
         if args.out:
@@ -80,7 +89,7 @@ def main():
                     reference_losses=list(steps["losses"]),
                     reference_grad_norms=list(steps["grad_norms"]))) + "\n")
 
-    def read(cell, seeds, what):
+    def read(cell, seeds, what, later):
         cell.feed.start()
         narrow = check.narrow_products(cell, cell.seeded_state(0), cell.feed.next())
         for seed in seeds:
@@ -90,28 +99,27 @@ def main():
             del state, metrics
             program_s = time.perf_counter() - begin
             begin = time.perf_counter()
-            if seed not in followed:
-                followed[seed] = reference.follow(seed)
-            numbers = dict(check.compare(record, followed[seed], wanted), narrow_products=narrow)
+            steps = reference_steps(seed, later)
+            numbers = dict(check.compare(record, steps, wanted), narrow_products=narrow)
             within = check.verdict(numbers, limits)
             emit(dict(numbers, what=what, workload=spec["name"], seed=seed, correct=within,
                       program_s=program_s, reference_s=time.perf_counter() - begin),
-                 followed[seed], record)
+                 steps, record)
         cell.feed.close()
 
-    read(cell, listed(args.seeds), "program")
-    for seed in listed(args.swap_seeds):
-        if seed not in followed:
-            followed[seed] = reference.follow(seed)
+    swap_seeds, control_seeds = listed(args.swap_seeds), listed(args.control_seeds)
+    read(cell, listed(args.seeds), "program", later=set(swap_seeds + control_seeds))
+    for seed in swap_seeds:
+        steps = reference_steps(seed, later=set(control_seeds))
         swapped = reference.follow(seed, aggregate=lambda rows, f, step: (
             krum_swapped(rows, f) if step == 0 else reference.rule.aggregate(rows, f)))
         record = check.stand_in_record(swapped)
-        emit(dict(check.compare(record, followed[seed], wanted), what="reference_swapped",
-                  workload=spec["name"], seed=seed), followed[seed], record)
-    if listed(args.control_seeds):
+        emit(dict(check.compare(record, steps, wanted), what="reference_swapped",
+                  workload=spec["name"], seed=seed), steps, record)
+    if control_seeds:
         del cell
         read(Cell(spec, devices, extra_experiment_args=BF16_PATH_ARGS),
-             listed(args.control_seeds), "control_bf16_path")
+             control_seeds, "control_bf16_path", later=set())
 
 
 if __name__ == "__main__":

@@ -8,9 +8,12 @@ first dispatch from the seeded state, whose record the comparison uses later,
 and a second one that is timed to size the window.  The window is a number of
 whole K-step dispatches fixed beforehand, ended by a wait on the last one's
 loss.  With ``--trace 1`` a profiler session is then bracketed round two or
-three further dispatches, and another round the rule alone.  Last, with the
+three further dispatches (once more round one fewer where the device's buffer
+dropped too many events), and another round the rule alone.  Last, with the
 program's state freed, the plain reference decides ``correct`` (check.py).
-The last line of standard output is the result.  No TPU, no number.
+The last line of standard output is the result.  No TPU, no number; and where
+the traced run's numbers contradict each other, no number either: the run says
+``grid contradiction: ...`` and exits with ``CONTRADICTION_EXIT``.
 """
 
 import time
@@ -27,6 +30,9 @@ import tempfile  # noqa: E402
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
 
+#: The exit code of a run whose trace reduction contradicted itself (not 1, a
+#: crash's; not 2, argparse's and the chip tool's; not 3, the chip tool's).
+CONTRADICTION_EXIT = 4
 COUNTERS = {"cache_hits": 0, "cache_misses": 0, "programs_loaded": 0}
 _LISTENING = []
 
@@ -109,6 +115,25 @@ def traced_dispatches(cell, state, nb_dispatches):
                 wait_loss(metrics)
 
     return state, profiled(body)
+
+
+def capture(cell, state, nb_dispatches, steps_per_s):
+    """The traced dispatches and their reduction: (state, neutral trace,
+    reduced).  A capture whose leaves cover too little of the spans
+    (``trace_reduce.DroppedEvents``) is taken once more with one dispatch
+    fewer, as its message says; a second one stands or falls as it is."""
+    from trace_reduce import DroppedEvents, reduce
+
+    for last in (False, True):
+        state, raw = traced_dispatches(cell, state, nb_dispatches)
+        try:
+            return state, raw, reduce(raw, nb_dispatches * cell.unroll, steps_per_s)
+        except DroppedEvents as dropped:
+            if last:
+                raise
+            nb_dispatches = max(1, nb_dispatches - 1)
+            print("grid capture: %s; tracing %d dispatch(es) instead" % (dropped, nb_dispatches),
+                  flush=True)
 
 
 def gar_probe(cell):
@@ -206,11 +231,8 @@ def run_cell(spec, seed, seconds, trace, devices, *, device_metrics=True,
             "setup_s": setup_s,
         }
         if trace:
-            from trace_reduce import reduce
-
             nb_traced = 2 if dispatch_s > 1.5 else 3  # a few seconds of device time
-            state, raw = traced_dispatches(cell, state, nb_traced)
-            reduced = reduce(raw, nb_traced * cell.unroll, steps_per_s)
+            state, raw, reduced = capture(cell, state, nb_traced, steps_per_s)
             # raw_trace: every device operation, module span and host annotation of the
             # traced dispatches, for a reader that the reduction does not serve
             ctx = {"trace": reduced, "raw_trace": raw, "counters": dict(COUNTERS),
@@ -247,6 +269,13 @@ def run_cell(spec, seed, seconds, trace, devices, *, device_metrics=True,
     if loaded_in_window:
         print("grid compare: %d program(s) compiled or loaded inside the window"
               % loaded_in_window, flush=True)
+    # each number compared beside its limit: the result's last key, standard error's last lines
+    result["compared"] = dict(
+        {name: [float(numbers.get(name, math.nan)), limit]
+         for name, limit in spec["limits"]["limits"].items()},
+        failed=[failed, 0], programs_loaded=[loaded_in_window, 0])
+    for name, (value, limit) in result["compared"].items():
+        print("grid compared %s %r limit %r" % (name, value, limit), file=sys.stderr, flush=True)
     return result
 
 
@@ -275,7 +304,14 @@ def main(argv=None):
     require_chips(devices, spec["chips"])
     print("grid compile cache at %s" % place_compile_cache(), flush=True)
     parts["backend_s"] = time.perf_counter() - T0 - parts["imports_s"]
-    result = run_cell(spec, args.seed, args.seconds, bool(args.trace), devices, parts=parts)
+    from trace_reduce import TraceContradiction
+
+    try:
+        result = run_cell(spec, args.seed, args.seconds, bool(args.trace), devices, parts=parts)
+    except TraceContradiction as contradiction:
+        # none of the reduction's numbers may be printed beside it: no result line
+        print("grid contradiction: %s" % contradiction, flush=True)
+        raise SystemExit(CONTRADICTION_EXIT)
     print(json.dumps(result), flush=True)
 
 

@@ -21,6 +21,16 @@ def test_flops_per_step_counts_every_image_forward_and_backward():
         "flops", "resnet_v1_50").forward_macs(224, 1000)
 
 
+def test_step_mfu_is_the_model_s_flops_over_the_device_s_step_time():
+    """On four chips at the ledger's PR 41 reading: 68.575 ms a step, where
+    ``mfu_pct`` reads 5.811 at 14.581 steps/s (idle share 2.6-3.1 %)."""
+    spec = cell_spec("resnet50_bulyan_4chip")
+    ctx = {"cell": spec, "peaks": peaks("TPU v5 lite"), "trace": {"device_step_ms": 68.575}}
+    value = load_module("layer_metrics", "step_mfu_pct").read(ctx)
+    assert value == 100 * flops_per_step(spec) / 68.575e-3 / (4 * 1.97e14)
+    assert 5.811 < value < 5.811 * 1.001  # 1000 / 68.575 = 14.5826 steps/s for 14.581
+
+
 def test_rule_bytes():
     n, f, d = 32, 7, 1000
     assert load_module("rules", "average").least_bytes(n, f, d) == 33 * d * 4

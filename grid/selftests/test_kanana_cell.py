@@ -76,14 +76,15 @@ def test_the_three_readers_on_a_recorded_table():
     read = lambda name, ctx: load_module("layer_metrics", name).read(ctx)
     assert read("latent_attend_ms_per_step", {"model_parts": table}) == 90.5
     assert read("latent_project_ms_per_step", {"model_parts": table}) == 60.25
-    assert read("routed_ffn_ms_per_step", {"model_parts": table}) == 159.5
-    for name in ("latent_attend_ms_per_step", "latent_project_ms_per_step",
-                 "routed_ffn_ms_per_step"):
+    assert read("ffn_ms_per_step", {"model_parts": table}) == 159.5
+    for name in ("latent_attend_ms_per_step", "latent_project_ms_per_step", "ffn_ms_per_step"):
         assert read(name, {"model_parts": None}) is None
     listed = {m["name"]: m for m in cell_spec(WORKLOAD)["manifest"]["per_layer"]}
-    for name in ("latent_attend_ms_per_step", "latent_project_ms_per_step",
-                 "routed_ffn_ms_per_step"):
+    for name in ("latent_attend_ms_per_step", "latent_project_ms_per_step"):
         assert listed[name]["workloads"] == [WORKLOAD] and listed[name]["moves"] == "steps_per_s"
+    # one reader for the feed-forward layers of both models that name these parts (PR 42)
+    assert listed["ffn_ms_per_step"]["workloads"] == ["laguna_avgmedian_causal4k", WORKLOAD]
+    assert "routed_ffn_ms_per_step" not in listed
 
 
 TINY_ARGS = ["batch-size:1", "vocab:50", "hidden:64", "heads:8", "heads-held:4",

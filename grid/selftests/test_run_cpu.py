@@ -2,7 +2,8 @@
 look for a chip at a tiny size, decides ``correct`` — true for the sound path,
 false for a timed path broken underneath (a state returned unchanged, an update
 of twice the size or of the wrong sign) and false for the control, the
-program's own ``dtype:bfloat16`` path."""
+program's own ``dtype:bfloat16`` path; and a trace reduction that contradicts
+itself ends the run in words."""
 
 import copy
 import json
@@ -96,21 +97,51 @@ def test_sound_path_is_correct_and_prints_no_metric(capsys):
     result = run_tiny()
     assert result["correct"] is True and result["failed"] == 0
     assert result["metrics"] == {} and "memory_peak_bytes" not in result["device"]
-    assert set(compared(capsys)) == {"narrow_products", "loss_gap", "grad_norm_gap", "dparam_gap"}
+    said = capsys.readouterr()
+    numbers = {"narrow_products", "loss_gap", "grad_norm_gap", "dparam_gap"}
+    assert {json.loads(line.split(" ", 2)[2])["number"] for line in said.out.splitlines()
+            if line.startswith("grid compare {")} == numbers
+    # each number beside its limit: the result's last key and standard error's last lines
+    assert list(result)[-1] == "compared"
+    assert set(result["compared"]) == numbers | {"failed", "programs_loaded"}
+    assert all(value <= limit for value, limit in result["compared"].values())
+    assert [line.split()[2] for line in said.err.splitlines()[-6:]] == list(result["compared"])
 
 
 @pytest.mark.parametrize("broken, number", [
     (unchanged_state, "dparam_gap"), (rate_times(2.0), "dparam_gap"),
     (rate_times(-1.0), "loss_gap")], ids=["unchanged", "twice_the_rate", "wrong_sign"])
 def test_broken_timed_path_is_not_correct(broken, number, capsys):
-    assert run_tiny(broken)["correct"] is False
+    result = run_tiny(broken)
+    assert result["correct"] is False
     assert compared(capsys)[number]["within"] is False
+    assert result["compared"][number][0] > result["compared"][number][1]
 
 
 def test_control_is_not_correct(capsys):
     """The lower precision fails the exact number, whatever the seed."""
     assert run_tiny(bf16_path)["correct"] is False
     assert compared(capsys)["narrow_products"]["value"] > 0
+
+
+def test_a_contradiction_that_stands_leaves_in_words(monkeypatch, capsys):
+    """``main`` past the look for a chip, the run's reduction contradicting
+    itself: the words on standard output, a code of their own, no result line."""
+    import run
+    import trace_reduce
+
+    def contradicted(*_args, **_kwargs):
+        raise trace_reduce.TraceContradiction("phases cover 0.450 of the step's operations")
+
+    monkeypatch.setattr(run, "require_chips", lambda devices, chips: None)
+    monkeypatch.setattr(run, "run_cell", contradicted)
+    with pytest.raises(SystemExit) as left:
+        run.main(["--workload", "cnnet_krum_sampled", "--seed", "1", "--seconds", "1",
+                  "--trace", "1"])
+    assert left.value.code == run.CONTRADICTION_EXIT and run.CONTRADICTION_EXIT not in (0, 1, 2)
+    said = capsys.readouterr().out.splitlines()
+    assert said[-1] == "grid contradiction: phases cover 0.450 of the step's operations"
+    assert not any(line.startswith("{") for line in said)
 
 
 @pytest.mark.parametrize("workload", ["resnet50_bulyan_4chip"])
@@ -132,7 +163,7 @@ def test_narrow_products_on_the_four_chip_program(workload):
 
 @pytest.mark.parametrize("key, name, kind", [
     ("input_source", "stream", "feeds"), ("attack", "little", "attacks"),
-    ("aggregator", "median", "rules")])
+    ("aggregator", "brute", "rules")])
 def test_a_missing_file_fails_by_name(key, name, kind):
     import jax
 

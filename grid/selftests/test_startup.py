@@ -165,13 +165,12 @@ def test_the_manifest_lists_the_six_for_every_cell():
     from cell import load_json, ROOT
 
     manifest = load_json(os.path.join(ROOT, "BENCHMARK.json"), "manifest")
-    six = [metric for metric in manifest["per_layer"] if metric["moves"] == "setup_s"
-           and metric["name"] != "cache_misses_warm"]
+    # their order among themselves, wherever later PRs' metrics come to stand
+    six = [metric for metric in manifest["per_layer"] if metric["moves"] == "setup_s"]
     assert [metric["name"] for metric in six] == [
         "step_trace_s", "step_lower_s", "step_load_s", "other_programs_s", "data_host_s",
         "state_init_s"]
     assert all("workloads" not in metric and metric["unit"] == "s"
                and metric["source"] == "program_counter" for metric in six)
-    assert manifest["per_layer"][-6:] == six
     assert all(os.path.exists(os.path.join(GRID, "layer_metrics", metric["name"] + ".py"))
                for metric in six)

@@ -31,8 +31,11 @@ import re
 #: spans.  By hand, on two dispatches of each one-chip cell (my chip runs,
 #: PR 23: 1.33 million operation events in config 2's two), they cover 0.9846,
 #: 0.9877 and 0.9845 of them: what is missing is the sequencer's hand-over
-#: between operations.  A trace whose device buffer overflowed keeps the module
-#: spans and loses operations, and reads far below: trace fewer dispatches.
+#: between operations, and on four chips the loops' own time and the waits
+#: inside them (0.966 to 0.972 there).  A trace whose device buffer overflowed
+#: keeps the module spans and loses operations, and reads far below: trace
+#: fewer dispatches.  This is the ONE judgement of the leaves' cover:
+#: phase_reduce.py compares nothing with the leaves.
 COVER_MIN = 0.90
 #: Busy time per step against the module span per step, and the busy share the
 #: timed window implies (device_step_ms x steps_per_s) against 1 - idle share,
@@ -49,6 +52,11 @@ COLLECTIVE_PREFIXES = ("all-to-all", "all-reduce", "all-gather", "reduce-scatter
 
 class TraceContradiction(ValueError):
     """The reduction's numbers contradict each other: none may be printed."""
+
+
+class DroppedEvents(TraceContradiction):
+    """The capture lost too many operation events to be read: a shorter one may
+    not (run.py ``capture`` takes it once more, with a dispatch fewer)."""
 
 
 def short_name(name):
@@ -199,7 +207,7 @@ def reduce(trace, steps_traced, steps_per_s=None):
     idle_share = 1.0 - busy_s / window_s
     cover = min(d["cover"] for d in per_device)
     if cover < COVER_MIN:
-        raise TraceContradiction(
+        raise DroppedEvents(
             "operations cover %.3f of the step program's spans, under %.2f: the device's "
             "event buffer dropped events; trace fewer dispatches" % (cover, COVER_MIN))
     if abs(busy_step_ms / device_step_ms - 1.0) > AGREE:
