@@ -381,15 +381,15 @@ def decoder_layer(x, layer, cfg, kind):
     return x + y, routed, idle
 
 
-def layer_runs(x, counters, runs, groups, layer):
+def layer_runs(x, counters, runs, groups, layer, policy=None):
     """``x`` through the model's runs of stacked layers: ``layer(x, leaves,
     kind) -> (x, *counts)`` once a layer under ``jax.checkpoint``, a run of
     several under ``lax.scan``; each count is added to its place in
-    ``counters``.  Returns ``(x, *counters)``."""
+    ``counters``.  Returns ``(x, *counters)``.  (``policy``: what a layer keeps.)"""
     carry = (x,) + tuple(counters)
     for (kind, count), group in zip(runs, groups):
 
-        @jax.checkpoint
+        @(lambda body: jax.checkpoint(body, policy=policy))
         def body(carry, leaves, kind=kind):
             x, *counts = layer(carry[0], leaves, kind)
             return (x,) + tuple(so_far + more for so_far, more in zip(carry[1:], counts)), None

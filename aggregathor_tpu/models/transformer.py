@@ -175,17 +175,26 @@ def rope_frequencies(width, theta):
     return jnp.exp(-jnp.arange(0, width, 2, dtype=jnp.float32) * (math.log(theta) / width))
 
 
-def rope(x, positions, inv_freq, factor=None):
+def rope(x, positions, inv_freq, factor=None, sections=None):
     """Rotary embedding; ``positions`` are *global* so SP blocks stay aligned.
 
     ``inv_freq`` is one inverse frequency a rotated pair (``rope_frequencies``,
     or a table of the caller's own such as YaRN's blend): the first
     ``2 * len(inv_freq)`` dims of each head turn, pair (2i, 2i + 1) by
     ``position * inv_freq[i]``, and the rest pass as they are; ``factor``,
-    where given, scales cos and sin."""
+    where given, scales cos and sin.  With ``sections`` (``mrope_section`` of a
+    config: how many pairs turn by each kind of position id, adding up to
+    ``len(inv_freq)``) a token has one id a section and ``positions`` is
+    (len(sections), s): pair i turns by the id of the section it lies in
+    (models/keye_vl2.py: temporal, height, width; three equal ids are one)."""
     b, s, h, dh = x.shape
     width = 2 * inv_freq.shape[0]
-    angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # (s, width/2)
+    if sections is not None:  # (s, width/2): each pair's own kind of position
+        by_pair = jnp.concatenate([jnp.broadcast_to(ids[:, None], (s, pairs))
+                                   for ids, pairs in zip(positions, sections)], axis=1)
+        angles = by_pair.astype(jnp.float32) * inv_freq[None, :]
+    else:
+        angles = positions.astype(jnp.float32)[:, None] * inv_freq[None, :]  # (s, width/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
     if factor is not None:
         cos, sin = factor * cos, factor * sin

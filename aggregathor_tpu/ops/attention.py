@@ -33,6 +33,20 @@ tile's row of it into a fixed sequence of key-tile ranges, edged and clear by
 turns, whose bounds the kernel picks by its grid index.  A block-diffusion
 mask is one more predicate, not another kernel.
 
+**A mask may be data.**  ``Selected(k)`` (models/keye_vl2.py: each query reads
+the ``k`` keys a learned indexer chose for it) names no predicate of two
+indices: its allowed pairs are an array the step computes, handed to ``attend``
+/ ``fused_attention`` as the operand ``pairs`` — int8, nonzero where the query
+reads the key, one (L, L) a batch entry for all heads.  What is known of it at
+trace time is its structure, the causal triangle: its tile table is
+``Causal()``'s with every tile under the diagonal EDGED, and where an EDGED
+tile of a predicate asks the predicate, one of ``Selected`` reads the pairs'
+(q_tile, k_tile) block of the (batch, query tile) at hand (a query tile's whole
+row of key tiles rides in VMEM beside it).  The same two kernels, under names
+of their own (``selected_attention_fwd`` / ``_bwd``); every query has to read
+some key, in any tile: rows of a tile that read none of its keys fold
+nothing, whichever tile comes first.
+
 **The same arithmetic as the XLA form, and no less**: float32 operands into
 every product, float32 accumulation, scores, maxima, exponentials and sums; a
 narrower input is widened on load, so its scores are float32 too.  The
@@ -113,6 +127,19 @@ class Causal:
         return back >= 0 if self.window is None else (back >= 0) & (back < self.window)
 
 
+@dataclasses.dataclass(frozen=True)
+class Selected:
+    """Query i reads key j iff the call's ``pairs`` operand is nonzero at (i,
+    j): ``k`` keys a query chosen by data among those up to its own (every one
+    of them while there are no more than ``k``).  Called as a predicate it says
+    where a pair MAY be allowed, which is all that trace time knows."""
+
+    k: int
+
+    def __call__(self, q_index, k_index):
+        return q_index >= k_index
+
+
 @functools.lru_cache(maxsize=None)
 def tile_table(mask, length, q_tile, k_tile):
     """(length / q_tile, length / k_tile) of SKIPPED, CLEAR or EDGED: ``mask``
@@ -123,6 +150,8 @@ def tile_table(mask, length, q_tile, k_tile):
     tiles = allowed.reshape(length // q_tile, q_tile, length // k_tile, k_tile)
     table = np.where(tiles.all(axis=(1, 3)), CLEAR,
                      np.where(tiles.any(axis=(1, 3)), EDGED, SKIPPED)).astype(np.int8)
+    if isinstance(mask, Selected):  # which of the pairs that may be allowed are: only the data says
+        table[table == CLEAR] = EDGED
     table.setflags(write=False)
     return table
 
@@ -219,18 +248,23 @@ def _stack(ref, scr, head, rep, q_tile, width, scale=None, front=0):
             piece if scale is None else piece * scale)
 
 
-def _scores(qs, k_ref, lanes, j, i, edged, mask, rep, q_tile, k_tile):
+def _scores(qs, k_ref, lanes, j, i, edged, mask, rep, q_tile, k_tile, pairs_ref=None):
     """(R * q_tile, k_tile) float32 scores of the stacked, scaled queries
     against ``lanes`` of key tile ``j``, forbidden pairs at NEG where the tile
-    is EDGED."""
+    is EDGED: those ``mask`` forbids, or with ``pairs_ref`` (the query tile's
+    (q_tile, L) int8 rows of a mask that is data) those it holds a zero for."""
     keys = k_ref[pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile), lanes].astype(jnp.float32)
     s = jax.lax.dot_general(qs, keys, (((1,), (1,)), ((), ())),
                             preferred_element_type=jnp.float32)
     if not edged:
         return s
-    q_index = i * q_tile + jax.lax.broadcasted_iota(jnp.int32, (q_tile, k_tile), 0)
-    k_index = j * k_tile + jax.lax.broadcasted_iota(jnp.int32, (q_tile, k_tile), 1)
-    ok = mask(q_index, k_index)
+    if pairs_ref is None:
+        q_index = i * q_tile + jax.lax.broadcasted_iota(jnp.int32, (q_tile, k_tile), 0)
+        k_index = j * k_tile + jax.lax.broadcasted_iota(jnp.int32, (q_tile, k_tile), 1)
+        ok = mask(q_index, k_index)
+    else:
+        tile = pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile)
+        ok = pairs_ref[:, tile].astype(jnp.int32) != 0
     return jnp.concatenate([jnp.where(ok, s[r * q_tile:(r + 1) * q_tile], NEG)
                             for r in range(rep)], axis=0)
 
@@ -254,7 +288,7 @@ def _wide(stat, width):
 
 
 def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs, m, l, acc, *, mask, slots, rep, heads,
-                q_tile, k_tile, dqk, dv):
+                q_tile, k_tile, dqk, dv, pairs_ref=None):
     """The running maximum ``m`` is kept equal along 128 lanes, so no fold
     spreads it again; the running sum ``l`` is kept lane by lane (lane c holds
     the sum over the keys c, c + 128, ... of every tile) and summed across the
@@ -273,7 +307,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs, m, l, acc, *, mask, slo
         acc[...] = jnp.zeros(acc.shape, jnp.float32)
 
         def fold(j, edged, across=slice(first, first + span), own=slice(h * dv, (h + 1) * dv)):
-            s = _scores(qs[...], k_ref, across, j, i, edged, mask, rep, q_tile, k_tile)
+            s = _scores(qs[...], k_ref, across, j, i, edged, mask, rep, q_tile, k_tile, pairs_ref)
             values = v_ref[pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile), own].astype(
                 jnp.float32)
             new_m = jnp.maximum(m[...], jnp.max(s, axis=1, keepdims=True))
@@ -294,7 +328,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs, m, l, acc, *, mask, slo
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref, dk_ref, dv_ref, qs, dos,
-                delta, dq, *, mask, slots, rep, heads, q_tile, k_tile, dqk, dv):
+                delta, dq, *, mask, slots, rep, heads, q_tile, k_tile, dqk, dv, pairs_ref=None):
     """Scores, dq and dk over a head's ``_span`` of the keys' lanes (the lanes
     beside the head's own: zeros in ``qs``, so nothing into the scores and
     zeros into the neighbour's dk; in ``dq`` what is dropped on the way out);
@@ -322,7 +356,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref, dk_ref, dv_
         def fold(j, edged, across=slice(first, first + span), own=slice(h * dv, (h + 1) * dv),
                  stats=_rows_of(h, heads, rows)):
             tile = pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile)
-            s = _scores(qs[...], k_ref, across, j, i, edged, mask, rep, q_tile, k_tile)
+            s = _scores(qs[...], k_ref, across, j, i, edged, mask, rep, q_tile, k_tile, pairs_ref)
             p = jnp.exp(s - _wide(lse_ref[stats], k_tile))
             dp = jax.lax.dot_general(dos[...], v_ref[tile, own].astype(jnp.float32),
                                      (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
@@ -383,20 +417,58 @@ def _static(q, v, plan):
             lambda width: pltpu.VMEM((rows, width), jnp.float32))
 
 
-def _forward(q, k, v, plan):
+def _pairs_at(kernel, place, q_tile, length):
+    """(``kernel`` taking the pairs' ref as its input number ``place``, that
+    input's block spec): a query tile's (q_tile, L) rows of the (B, L, L) int8
+    pairs, the same for every key head of the grid."""
+    def with_pairs(*refs, **static):
+        return kernel(*refs[:place], *refs[place + 1:], pairs_ref=refs[place], **static)
+
+    return with_pairs, pl.BlockSpec((None, q_tile, length), lambda b, g, i: (b, i, 0))
+
+
+def _forward(q, k, v, plan, pairs=None):
     """(the output (B, L, G * R * Dv), the log-sum-exp (B, G / heads a step, L *
-    R * heads a step, lanes): a query tile's rows head under head)."""
+    R * heads a step, lanes): a query tile's rows head under head).  With
+    ``pairs`` (a mask that is data) they are one more input, and the call has
+    its own name."""
     b, length, g, rep, dqk = q.shape
     dv = v.shape[-1]
     static, (q_wide, k_wide, o_wide, v_wide, per_row), grid, lanes, span, room = _static(q, v, plan)
+    kernel, name, inputs, operands = _fwd_kernel, "causal_attention_fwd", [q_wide, k_wide, v_wide], ()
+    if pairs is not None:
+        kernel, spec = _pairs_at(_fwd_kernel, 3, static["q_tile"], length)
+        name, inputs, operands = "selected_attention_fwd", inputs + [spec], (pairs,)
     return _call(
-        _fwd_kernel, "causal_attention_fwd",
+        kernel, name,
         (jax.ShapeDtypeStruct((b, length, g * rep * dv), q.dtype),
          jax.ShapeDtypeStruct((b, grid[1], length * rep * static["heads"], lanes), jnp.float32)),
-        [q_wide, k_wide, v_wide], (o_wide, per_row),
+        inputs, (o_wide, per_row),
         [room(span), room(lanes), room(lanes), room(dv)], grid, False, **static)(
             q.reshape(b, length, g * rep * dqk), k.reshape(b, length, g * dqk),
-            v.reshape(b, length, g * dv))
+            v.reshape(b, length, g * dv), *operands)
+
+
+def _backward(plan, kept, dout, pairs=None):
+    q, k, v, out, lse = kept
+    b, length, g, rep, dqk = q.shape
+    dv = v.shape[-1]
+    static, (q_wide, k_wide, o_wide, v_wide, per_row), grid, lanes, span, room = _static(q, v, plan)
+    kernel, name, operands = _bwd_kernel, "causal_attention_bwd", ()
+    inputs = [q_wide, k_wide, v_wide, o_wide, per_row, o_wide]
+    if pairs is not None:
+        kernel, spec = _pairs_at(_bwd_kernel, 6, static["q_tile"], length)
+        name, inputs, operands = "selected_attention_bwd", inputs + [spec], (pairs,)
+    grads = _call(
+        kernel, name,
+        (jax.ShapeDtypeStruct((b, length, g * rep * dqk), q.dtype),
+         jax.ShapeDtypeStruct((b, length, g * dqk), jnp.float32),
+         jax.ShapeDtypeStruct((b, length, g * dv), jnp.float32)),
+        inputs, (q_wide, k_wide, v_wide),
+        [room(span), room(dv), room(lanes), room(span)], grid, True, **static)(
+            q.reshape(b, length, g * rep * dqk), k.reshape(b, length, g * dqk),
+            v.reshape(b, length, g * dv), out, lse, dout, *operands)
+    return tuple(grad.reshape(a.shape).astype(a.dtype) for grad, a in zip(grads, (q, k, v)))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -409,35 +481,41 @@ def _fused_fwd(q, k, v, plan):
     return out, (q, k, v, out, lse)
 
 
-def _fused_bwd(plan, kept, dout):
-    q, k, v, out, lse = kept
-    b, length, g, rep, dqk = q.shape
-    dv = v.shape[-1]
-    static, (q_wide, k_wide, o_wide, v_wide, per_row), grid, lanes, span, room = _static(q, v, plan)
-    grads = _call(
-        _bwd_kernel, "causal_attention_bwd",
-        (jax.ShapeDtypeStruct((b, length, g * rep * dqk), q.dtype),
-         jax.ShapeDtypeStruct((b, length, g * dqk), jnp.float32),
-         jax.ShapeDtypeStruct((b, length, g * dv), jnp.float32)),
-        [q_wide, k_wide, v_wide, o_wide, per_row, o_wide], (q_wide, k_wide, v_wide),
-        [room(span), room(dv), room(lanes), room(span)], grid, True, **static)(
-            q.reshape(b, length, g * rep * dqk), k.reshape(b, length, g * dqk),
-            v.reshape(b, length, g * dv), out, lse, dout)
-    return tuple(grad.reshape(a.shape).astype(a.dtype) for grad, a in zip(grads, (q, k, v)))
+_fused.defvjp(_fused_fwd, _backward)
 
 
-_fused.defvjp(_fused_fwd, _fused_bwd)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _fused_by_pairs(q, k, v, pairs, plan):
+    return _forward(q, k, v, plan, pairs)[0]
 
 
-def fused_attention(q, k, v, mask, q_tile, k_tile):
+def _fused_by_pairs_fwd(q, k, v, pairs, plan):
+    out, lse = _forward(q, k, v, plan, pairs)
+    return out, (q, k, v, out, lse, pairs)
+
+
+def _fused_by_pairs_bwd(plan, kept, dout):
+    *kept, pairs = kept
+    return _backward(plan, kept, dout, pairs) + (np.zeros(pairs.shape, jax.dtypes.float0),)
+
+
+_fused_by_pairs.defvjp(_fused_by_pairs_fwd, _fused_by_pairs_bwd)
+
+
+def fused_attention(q, k, v, mask, q_tile, k_tile, pairs=None):
     """q (B, L, G, R, Dqk), k (B, L, G, Dqk) and v (B, L, G, Dv), Dv no wider
     than Dqk -> (B, L, G * R * Dv): the softmax over the keys ``mask`` allows
     of ``q . k / sqrt(Dqk)``, times v, with its own backward pass.  ``L`` is a
     multiple of both tiles; every query reads some key.  The tile table is made
     here, at trace time, from the static arguments alone; the kernels are
-    handed its loops, and q, k and v at the widths they have."""
+    handed its loops, and q, k and v at the widths they have.  Under
+    ``Selected`` the allowed pairs are the operand ``pairs``, (B, L, L) int8,
+    nonzero where the query (its row) reads the key."""
     plan = (mask, _slots(tile_table(mask, q.shape[1], q_tile, k_tile)), q_tile, k_tile)
-    return _fused(q, k, v, plan)
+    if isinstance(mask, Selected) != (pairs is not None):
+        raise ValueError("%r and pairs %s: a mask that is data comes with its pairs, a "
+                         "predicate without" % (mask, "given" if pairs is not None else "missing"))
+    return _fused(q, k, v, plan) if pairs is None else _fused_by_pairs(q, k, v, pairs, plan)
 
 
 # --------------------------------------------------------------------------- #
@@ -512,9 +590,11 @@ def _announce(form, shape, v_dim, mask, tiles):
          % ("x".join(map(str, shape)), widths, mask, form, counts))
 
 
-def attend(q, k, v, mask, xla_form):
+def attend(q, k, v, mask, xla_form, pairs=None):
     """``fused_attention`` where ``attention_form`` says so, else
     ``xla_form(q, k, v)``; v may be narrower than q and k (``fused_attention``).
+    ``pairs`` are the allowed pairs of a mask that is data (``Selected``): the
+    kernel's operand, and what the caller's ``xla_form`` holds itself.
     On a TPU each decision is logged once a shape and mask, with the tile
     table's three counts."""
     _, length, kv_heads, rep, head_dim = q.shape
@@ -522,4 +602,6 @@ def attend(q, k, v, mask, xla_form):
     tiles = tiles_for(length, rep)
     if hw.on_tpu():
         _announce(form, tuple(q.shape), v.shape[-1], mask, tiles)
-    return fused_attention(q, k, v, mask, *tiles) if form == "kernel" else xla_form(q, k, v)
+    if form != "kernel":
+        return xla_form(q, k, v)
+    return fused_attention(q, k, v, mask, *tiles, pairs=pairs)

@@ -32,7 +32,10 @@ workers; models/sdar.py) — output and the gradients of q, k and v at
 ``highest`` precision (what separates the two is then the order of float32
 sums) and at the default (what the step runs), and each form alone, forward
 and forward + backward, on a device-synchronised host clock, least of
-``--attention-reps``.  ``--attention-shapes block-diffusion`` keeps some of
+``--attention-reps``; and at ``keye_avgmedian_sparse8k``'s (32 over 4 under a
+mask that is DATA, each query's 2,048 keys of up to 8,192 from seeded scores,
+L = 8192, three workers; models/keye_vl2.py: the row ``selected``).
+``--attention-shapes block-diffusion`` keeps some of
 the rows; ``--attention-tiles 128x256,256x512`` times the kernel alone at
 other tiles, ``--attention-tiles sweep`` at the five of ``TILE_SWEEP``.
 """
@@ -167,7 +170,8 @@ def run_check(n, f, dims, rules=RULES, reps=10, nan_workers=2,
 
 def _laguna_attention(window):
     """``(length, kv_heads, head_dim) -> (mask, the model's attention, (key
-    heads, the scores' width, the values'))``."""
+    heads, the scores' width, the values'), the pairs of a mask that is data or
+    None)``."""
     def make(length, kv_heads, head_dim):
         from aggregathor_tpu.models import laguna
         from aggregathor_tpu.ops import attention
@@ -176,7 +180,7 @@ def _laguna_attention(window):
                                   attn_chunk=min(length, laguna.LagunaConfig.attn_chunk))
         return (attention.Causal(window),
                 lambda q, k, v: laguna.causal_attention(q, k, v, cfg, window),
-                (kv_heads, head_dim, head_dim))
+                (kv_heads, head_dim, head_dim), None)
     return make
 
 
@@ -189,7 +193,7 @@ def _sdar_attention(block):
                               attn_chunk=min(half, sdar.SdarConfig.attn_chunk))
         return (sdar.BlockDiffusion(half, block),
                 lambda q, k, v: sdar.masked_attention(q, k, v, cfg),
-                (kv_heads, head_dim, head_dim))
+                (kv_heads, head_dim, head_dim), None)
     return make
 
 
@@ -205,18 +209,46 @@ def _latent_attention(length, kv_heads, head_dim):
             lambda q, k, v: attention.attend(
                 q, k, v, attention.Causal(),
                 lambda q, k, v: laguna.chunked_attention(q, k, v, cfg, None)),
-            (4 * kv_heads, head_dim * 3 // 2, head_dim))
+            (4 * kv_heads, head_dim * 3 // 2, head_dim), None)
 
 
-#: (name, workers, query heads a kv head, the model's mask and attention) of
-#: the grid's Laguna cell — layers 0 and 4, layers 1-3
-#: (grid/configs/laguna-xs2-ep32-n3.json) — of its SDAR cell
-#: (grid/configs/sdar-30b-a3b-ep16-n4.json) and of its Kanana cell
-#: (grid/configs/kanana2-30b-a3b-ep16-n3.json: G 16, R 1, 192 / 128).
-ATTENTION_SHAPES = (("full", 3, 6, _laguna_attention(None)),
-                    ("window", 3, 8, _laguna_attention(512)),
-                    ("block-diffusion", 4, 8, _sdar_attention(4)),
-                    ("latent", 3, 1, _latent_attention))
+def _selected_attention(length, kv_heads, head_dim):
+    """models/keye_vl2.py's: a mask that is DATA.  Each query reads the
+    ``length // 4`` keys up to its own of largest seeded score (2,048 of up to
+    8,192 at the cell's length), one selection for every head and, here, for
+    every worker; the pairs are the kernel's operand and what the model's
+    chunked XLA form masks by."""
+    import jax
+    import jax.numpy as jnp
+
+    from aggregathor_tpu.models import keye_vl2
+    from aggregathor_tpu.ops import attention
+
+    cfg = keye_vl2.KeyeVL2Config(seq=length, index_topk=length // 4, head_dim=head_dim,
+                                 kv_heads=kv_heads, attn_chunk=min(length, 256))
+    chunk = min(length, 512)
+    scores = jax.random.normal(jax.random.PRNGKey(13), (length // chunk, 1, chunk, length))
+    pairs = jax.lax.map(lambda numbered: keye_vl2.top_keys(
+        numbered[1], numbered[0] * chunk + jnp.arange(chunk), cfg.index_topk).astype(jnp.int8),
+        (jnp.arange(length // chunk), scores)).swapaxes(0, 1).reshape(1, length, length)
+    mask = attention.Selected(cfg.index_topk)
+    return (mask, lambda q, k, v: attention.attend(
+        q, k, v, mask, lambda q, k, v: keye_vl2.chunked_attention(q, k, v, pairs, cfg),
+        pairs=pairs), (kv_heads, head_dim, head_dim), pairs)
+
+
+#: (name, workers, query heads a kv head, the model's mask and attention, the
+#: row's length in ``run_attention_check``'s) of the grid's Laguna cell — layers 0 and 4,
+#: layers 1-3 (grid/configs/laguna-xs2-ep32-n3.json) — of its SDAR cell
+#: (grid/configs/sdar-30b-a3b-ep16-n4.json), of its Kanana cell
+#: (grid/configs/kanana2-30b-a3b-ep16-n3.json: G 16, R 1, 192 / 128) and of
+#: its Keye cell (grid/configs/keye-vl2-30b-a3b-ep16-n3.json: the mask is
+#: data, L = 8192, twice the others').
+ATTENTION_SHAPES = (("full", 3, 6, _laguna_attention(None), 1),
+                    ("window", 3, 8, _laguna_attention(512), 1),
+                    ("block-diffusion", 4, 8, _sdar_attention(4), 1),
+                    ("latent", 3, 1, _latent_attention, 1),
+                    ("selected", 3, 8, _selected_attention, 2))
 
 #: ``--attention-tiles sweep``: queries x keys a tile
 TILE_SWEEP = ((128, 256), (256, 256), (512, 256), (256, 512), (512, 512))
@@ -252,8 +284,11 @@ def run_attention_check(reps=5, tiles=(), length=4096, kv_heads=4, head_dim=128,
         raise RuntimeError("the attention column needs a TPU backend (the kernel would "
                            "interpret on %r)" % jax.default_backend())
     failed = []
-    for name, workers, rep, make in shapes:
-        mask, model_attention, (key_heads, qk_dim, v_dim) = make(length, kv_heads, head_dim)
+    given = length
+    for name, workers, rep, make, longer in shapes:
+        length = given * longer
+        mask, model_attention, (key_heads, qk_dim, v_dim), pairs = make(
+            length, kv_heads, head_dim)
         key = jax.random.PRNGKey(11)
         normal = lambda place, *dims: jax.random.normal(
             jax.random.fold_in(key, place), (workers, 1, length) + dims, jnp.float32)
@@ -318,7 +353,7 @@ def run_attention_check(reps=5, tiles=(), length=4096, kv_heads=4, head_dim=128,
                    **attention.table_counts(attention.tile_table(mask, length, q_tile, k_tile))}
             try:
                 forward, gradients = forms(lambda q, k, v: attention.fused_attention(
-                    q, k, v, mask, q_tile, k_tile))
+                    q, k, v, mask, q_tile, k_tile, pairs=pairs))
                 row["kernel_fwd_ms"] = round(_least_ms(lambda: forward(q, k, v), reps), 4)
                 row["kernel_fwd_bwd_ms"] = round(_least_ms(lambda: gradients(q, k, v), reps), 4)
             except Exception as exc:

@@ -18,7 +18,7 @@ import pytest
 from aggregathor_tpu import models
 from aggregathor_tpu.models import laguna, sdar
 from aggregathor_tpu.ops import attention
-from aggregathor_tpu.ops.attention import CLEAR, EDGED, SKIPPED, Causal
+from aggregathor_tpu.ops.attention import CLEAR, EDGED, SKIPPED, Causal, Selected
 from aggregathor_tpu.models.sdar import BlockDiffusion
 
 LENGTH, KV_HEADS, HEAD_DIM, WORKERS, LAYERS = 32, 2, 16, 3, 2
@@ -297,7 +297,7 @@ def test_the_float32_sdar_loss_with_the_kernel_forced_holds_no_narrow_product():
     assert all(jnp.finfo(dtype).bits >= 32 for operands, _ in products for dtype in operands)
 
 
-@pytest.mark.parametrize("name", ["full", "window", "block-diffusion", "latent"])
+@pytest.mark.parametrize("name", ["full", "window", "block-diffusion", "latent", "selected"])
 def test_the_check_scripts_attention_column_runs_interpreted(name):
     """scripts/pallas_tpu_check.py ``run_attention_check`` — the chip's parity
     and timing of the kernel against each model's XLA form — off a TPU at a
@@ -323,7 +323,8 @@ def test_the_check_scripts_attention_column_runs_interpreted(name):
     assert rows[0]["parity"] == "ok" and rows[0]["workers"] == shapes[0][1]
     assert rows[0]["mask"] == {"full": "Causal(window=None)", "window": "Causal(window=512)",
                                "block-diffusion": "BlockDiffusion(half=16, block=4)",
-                               "latent": "Causal(window=None)"}[name]
+                               "latent": "Causal(window=None)",
+                               "selected": "Selected(k=16)"}[name]   # twice the length: 64 // 4
     assert "kernel_fwd_bwd_ms" in rows[1] and "error" not in rows[1]
     with pytest.raises(RuntimeError):
         pallas_tpu_check.run_attention_check(reps=1, length=LENGTH, shapes=shapes)
@@ -480,3 +481,100 @@ def test_equal_widths_trace_the_program_they_traced(name, form):
         text = str(jax.make_jaxpr(jax.value_and_grad(
             lambda p: experiment.loss(p, batch), has_aux=True))(params))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] == PARENT_JAXPRS[name, form]
+
+
+# --------------------------------------------------------------------------- #
+#  A mask that is data (models/keye_vl2.py)                                   #
+# --------------------------------------------------------------------------- #
+
+
+def seeded_pairs(keys, empty=()):
+    """(LAYERS, WORKERS, 1, L, L) int8: every query reads ``keys`` seeded keys up
+    to its own (all of them while there are no more), a selection a layer a
+    worker; each (query tile, key tile) of 8 x 8 in ``empty`` is cleared whole,
+    its queries reading what else they chose."""
+    rng = np.random.default_rng(7)
+    scores = np.where(np.tril(np.ones((LENGTH, LENGTH), bool)),
+                      rng.random((LAYERS, WORKERS, 1, LENGTH, LENGTH)), -1.0)
+    rank = np.argsort(np.argsort(-scores, axis=-1, kind="stable"), axis=-1)
+    pairs = (rank < keys) & (scores >= 0)
+    for i, j in empty:
+        pairs[..., 8 * i:8 * i + 8, 8 * j:8 * j + 8] = False
+    assert pairs.any(axis=-1).all()   # every query still reads some key
+    return jnp.asarray(pairs.astype(np.int8))
+
+
+def dense_by_pairs(q, k, v, pairs):
+    scores = jnp.einsum("bqgrd,bkgd->bgrqk", q, k) / np.sqrt(q.shape[-1])
+    weights = jax.nn.softmax(jnp.where(pairs[:, None, None] != 0, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bgrqk,bkgd->bqgrd", weights, v).reshape(q.shape[0], q.shape[1], -1)
+
+
+def stepped_by_pairs(attend):
+    """``stepped`` with a selection a layer a worker beside q, k and v."""
+    def total(q, k, v, w, pairs):
+        @jax.checkpoint
+        def layer(carry, leaves):
+            q, k, v, w, pairs = leaves
+            return carry + jnp.sum(jax.vmap(attend)(q, k, v, pairs) * w), None
+
+        return jax.lax.scan(layer, jnp.float32(0), (q, k, v, w, pairs))[0]
+
+    return jax.jit(jax.value_and_grad(total, argnums=(0, 1, 2)))
+
+
+@pytest.mark.parametrize("tiles", [(32, 32), (8, 8), (16, 8)], ids=["one-tile", "8x8", "16x8"])
+@pytest.mark.parametrize("keys,empty", [(6, ()), (6, ((2, 1), (3, 0), (3, 3))), (LENGTH, ())],
+                         ids=["six-keys", "empty-tiles", "all-keys"])
+def test_kernel_under_a_mask_that_is_data_is_a_dense_masked_softmax(keys, empty, tiles):
+    """``Selected``: the allowed pairs arrive as an operand.  Output and the
+    gradients of q, k and v against one dense softmax under the same pairs and
+    against models/keye_vl2.py's chunked XLA form, as the step calls it; with
+    whole key tiles that a query tile selected nothing of — the diagonal one
+    among them — and with every causal key selected (``Causal()``'s result)."""
+    from aggregathor_tpu.models import keye_vl2
+
+    q, k, v, w = seeded(8)
+    pairs = seeded_pairs(keys, empty)
+    mask = Selected(keys)
+    cfg = keye_vl2.KeyeVL2Config(seq=LENGTH, attn_chunk=8, head_dim=HEAD_DIM, kv_heads=KV_HEADS)
+    ours, ours_grads = stepped_by_pairs(lambda q, k, v, pairs: attention.fused_attention(
+        q, k, v, mask, *tiles, pairs=pairs))(q, k, v, w, pairs)
+    for theirs, theirs_grads in (
+            stepped_by_pairs(dense_by_pairs)(q, k, v, w, pairs),
+            stepped_by_pairs(lambda q, k, v, pairs: keye_vl2.chunked_attention(
+                q, k, v, pairs, cfg))(q, k, v, w, pairs)):
+        assert abs(float(ours) - float(theirs)) <= 1e-5 * abs(float(theirs))
+        for mine, dense in zip(ours_grads, theirs_grads):
+            assert mine.shape == dense.shape and mine.dtype == dense.dtype
+            np.testing.assert_allclose(np.asarray(mine), np.asarray(dense), rtol=1e-4, atol=2e-5)
+    if keys == LENGTH:
+        causal, causal_grads = stepped(lambda q, k, v: attention.fused_attention(
+            q, k, v, Causal(), *tiles))(q, k, v, w)
+        assert abs(float(ours) - float(causal)) <= 1e-5 * abs(float(causal))
+        for mine, predicate in zip(ours_grads, causal_grads):
+            np.testing.assert_allclose(np.asarray(mine), np.asarray(predicate), rtol=1e-4, atol=2e-5)
+
+
+def test_a_mask_that_is_data_has_the_causal_table_with_every_tile_edged():
+    """``Selected``'s tile table is ``Causal()``'s with no tile CLEAR (below the
+    diagonal only the data says which pairs are allowed): at the cell's shape
+    528 edged and 496 skipped, one loop a kernel; its two calls carry names of
+    their own, the predicates' theirs; and a predicate with pairs, or
+    ``Selected`` without, is refused."""
+    table = attention.tile_table(Selected(2048), 8192, 256, 256)
+    causal = attention.tile_table(Causal(), 8192, 256, 256)
+    assert np.array_equal(table == SKIPPED, causal == SKIPPED) and not (table == CLEAR).any()
+    assert attention.table_counts(table) == {"clear": 0, "edged": 528, "skipped": 496}
+    assert len(attention._slots(table)) == 1
+    assert attention.attention_form(8192, 128, 128, 4, 8) == "xla"   # off a TPU
+    q, k, v, _ = seeded(2, lead=(1,))
+    pairs = seeded_pairs(6)[0, 0]
+    grad = lambda mask, pairs: jax.make_jaxpr(jax.grad(lambda q: jnp.sum(
+        attention.fused_attention(q, k, v, mask, 8, 8, pairs=pairs))))(q).jaxpr
+    assert kernels_in(grad(Selected(6), pairs)) == ["selected_attention_fwd",
+                                                    "selected_attention_bwd"]
+    assert kernels_in(grad(Causal(), None)) == ["causal_attention_fwd", "causal_attention_bwd"]
+    for mask, given in ((Causal(), pairs), (Selected(6), None)):
+        with pytest.raises(ValueError, match="pairs"):
+            attention.fused_attention(q, k, v, mask, 8, 8, pairs=given)

@@ -231,7 +231,8 @@ def test_crop_neither_loops_nor_slices_on_the_chip(v5e_2x2):
 
 @pytest.mark.parametrize("workers,rep,mask,kv_heads,widths", [
     (3, 6, "full", 4, (128, 128)), (3, 8, "window", 4, (128, 128)), (4, 8, "block", 4, (128, 128)),
-    (3, 1, "full", 16, (192, 128))], ids=["full", "window", "block-diffusion", "latent"])
+    (3, 1, "full", 16, (192, 128)), (3, 8, "selected", 4, (128, 128))],
+    ids=["full", "window", "block-diffusion", "latent", "selected"])
 def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, monkeypatch, workers,
                                                                     rep, mask, kv_heads, widths):
     """The fused attention kernel and its backward pass as the step of
@@ -248,7 +249,10 @@ def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, mon
     output and their gradients is one log-sum-exp a query a head (128 lanes
     wide as the chip stores it) and a row-major copy of q: nothing
     score-shaped, nothing a fold, and at two widths no padded copy of q, k or
-    v in and nothing 256 lanes a head out."""
+    v in and nothing 256 lanes a head out.  And as ``keye_avgmedian_sparse8k``'s
+    does — three workers, L = 8192, the mask an int8 (L, L) operand a worker
+    whose (256, L) rows of a query tile ride in VMEM beside a head's whole K, V,
+    dk and dv at twice the other cells' length."""
     from jax.sharding import SingleDeviceSharding
 
     from aggregathor_tpu.models.sdar import BlockDiffusion
@@ -256,19 +260,23 @@ def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, mon
 
     monkeypatch.setattr(attention.hw, "on_tpu", lambda: True)  # compile the kernels, not interpret
     monkeypatch.setattr(attention, "info", lambda *_: None)
-    length, (head_dim, v_dim) = 4096, widths
+    length, (head_dim, v_dim) = 8192 if mask == "selected" else 4096, widths
     mask = {"full": attention.Causal(None), "window": attention.Causal(512),
-            "block": BlockDiffusion(length // 2, 4)}[mask]
+            "block": BlockDiffusion(length // 2, 4), "selected": attention.Selected(2048)}[mask]
     assert attention.attention_form(length, head_dim, v_dim, kv_heads, rep) == "kernel"
     one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
-    shape = lambda *dims: jax.ShapeDtypeStruct((workers, 1, length) + dims, jnp.float32,
-                                               sharding=one_chip)
-    attend = jax.vmap(lambda q, k, v: attention.attend(q, k, v, mask, None))
+    shape = lambda *dims, dtype=jnp.float32: jax.ShapeDtypeStruct(
+        (workers, 1, length) + dims, dtype, sharding=one_chip)
+    by_data = isinstance(mask, attention.Selected)  # its pairs: one more operand
+    pairs = (shape(length, dtype=jnp.int8),) if by_data else ()
+    attend = jax.vmap(lambda q, k, v, *pairs: attention.attend(q, k, v, mask, None, *pairs))
     compiled = compile_uncached(
-        jax.jit(jax.grad(lambda q, k, v: jnp.sum(attend(q, k, v) ** 2), argnums=(0, 1, 2))),
-        shape(kv_heads, rep, head_dim), shape(kv_heads, head_dim), shape(kv_heads, v_dim))
+        jax.jit(jax.grad(lambda q, k, v, *pairs: jnp.sum(attend(q, k, v, *pairs) ** 2),
+                         argnums=(0, 1, 2))),
+        shape(kv_heads, rep, head_dim), shape(kv_heads, head_dim), shape(kv_heads, v_dim), *pairs)
     text = compiled.as_text()
-    calls = re.findall(r"^ *%?([\w.-]*causal_attention_(?:fwd|bwd)[\w.-]*) = .* custom-call\(.*"
+    calls = re.findall(r"^ *%?([\w.-]*" + ("selected" if by_data else "causal")
+                       + r"_attention_(?:fwd|bwd)[\w.-]*) = .* custom-call\(.*"
                        r'custom_call_target="tpu_custom_call"', text, re.M)
     assert len(calls) == 2 and any("fwd" in name for name in calls) and any(
         "bwd" in name for name in calls), calls
