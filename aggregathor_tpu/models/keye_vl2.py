@@ -38,9 +38,12 @@ token has three position ids (temporal, height, width: equal for text):
 
 How it is computed here.  A layer's index scores and its selection are made
 ``select_chunk`` queries at a time (``lax.map``): the (index heads, chunk, L)
-products, their weighted sum, then a stable sort of each query's causal
-scores whose ``index_topk``-th entry is the threshold (score and key) that a
-key has to pass (``top_keys``) — never an (L, L) array of floats.  What leaves
+products, their weighted sum, then the threshold (score and key) that a key
+has to pass (``top_keys``): on a TPU found by counting, ops/select.py's one
+kernel from the scores to the int8 pairs (32 + log2 L passes of compare and
+sum over rows held in VMEM; ``select_form`` chooses), everywhere else the
+``index_topk``-th entry of a stable sort of each query's causal scores, which
+is also the kernel's oracle — never an (L, L) array of floats.  What leaves
 is ``pairs``, (B, L, L) int8, nonzero where the query reads the key: the operand
 of ops/attention.py's kernel under ``Selected(index_topk)`` (a mask that is
 data) on a TPU, and of ``chunked_attention`` (plain XLA: a dense masked softmax
@@ -62,6 +65,7 @@ from jax.ad_checkpoint import checkpoint_name
 from . import Experiment, register
 from ..utils import UserException, parse_keyval
 from ..ops.attention import Selected, attend
+from ..ops.select import chosen_form, select_threshold
 from .common import check_dtype
 from .laguna import LagunaExperiment, layer_runs, next_token_loss, seeded_corpus, seeded_leaves
 from .sdar import _parse_held, moe
@@ -189,14 +193,18 @@ def index_scores(q, k, weights):
 def top_keys(scores, q_pos, topk):
     """(B, C, L) booleans: the ``topk`` keys ``s <= q_pos`` of largest score a
     query, every one of them where there are no more; equal scores go to the
-    lower ``s``.  One stable ascending sort of the negated scores (a key past
-    the query at +inf, a zero of either sign at +0) carrying each key's
+    lower ``s``.  Where ``ops.select.select_form`` says so, ops/select.py's
+    kernel, which finds the same pairs by counting; else (the CPU's path and
+    the kernel's oracle) one stable ascending sort of the negated scores (a key
+    past the query at +inf, a zero of either sign at +0) carrying each key's
     position: the ``topk``-th entry is the last one in, and a key is in iff its
     (negated score, position) is not after that entry's."""
     k_pos = jnp.arange(scores.shape[-1])
     causal = k_pos[None, :] <= q_pos[:, None]
     if topk >= scores.shape[-1]:
         return jnp.broadcast_to(causal, scores.shape)
+    if chosen_form(scores.shape, topk) == "kernel":
+        return select_threshold(scores, q_pos, topk) != 0
     negated = jnp.where(causal, jnp.where(scores == 0, 0.0, -scores), jnp.inf)
     by_score, keys = jax.lax.sort(
         (negated, jnp.broadcast_to(k_pos.astype(jnp.int32), scores.shape)),

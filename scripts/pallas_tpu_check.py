@@ -38,6 +38,15 @@ L = 8192, three workers; models/keye_vl2.py: the row ``selected``).
 ``--attention-shapes block-diffusion`` keeps some of
 the rows; ``--attention-tiles 128x256,256x512`` times the kernel alone at
 other tiles, ``--attention-tiles sweep`` at the five of ``TILE_SWEEP``.
+
+The select column (``run_select_check``; ``--columns select``): ops/select.py's
+threshold by counting against models/keye_vl2.py's stable sort at
+``keye_avgmedian_sparse8k``'s shape (three workers, a chunk of 512 queries over
+8,192 scores, k = 2,048), the chunks numbered 0, 4 and 15 of the 16: seeded
+scores with a run of 300 equal ones planted across the threshold, infinities
+and a NaN, and for one worker a row set whose threshold falls among zeros of
+both signs and denormals — ``array_equal`` of the two forms' int8 pairs (nothing
+but time may differ) and each form's least ms of three.
 """
 
 import argparse
@@ -362,6 +371,62 @@ def run_attention_check(reps=5, tiles=(), length=4096, kv_heads=4, head_dim=128,
     return failed
 
 
+def run_select_check(reps=3, workers=3, chunk=512, length=8192, topk=2048, numbers=(0, 4, 15),
+                     allow_interpret=False, emit=_print_row):
+    """``array_equal`` and time of ops/select.py's kernel against
+    models/keye_vl2.py's sort, one row a chunk of ``numbers``; ``emit(row)``
+    each.  Returns the rows whose pairs differ."""
+    import jax
+    import jax.numpy as jnp
+
+    from aggregathor_tpu.models import keye_vl2
+    from aggregathor_tpu.ops import select
+
+    if not allow_interpret and select._interpret():
+        raise RuntimeError("the select column needs a TPU backend (the kernel would "
+                           "interpret on %r)" % jax.default_backend())
+
+    def traced(form):
+        def pairs(scores, number):
+            with select.forced_form(form):
+                q_pos = number * chunk + jnp.arange(chunk)
+                return jax.vmap(lambda scores: keye_vl2.top_keys(
+                    scores, q_pos, topk).astype(jnp.int8))(scores)
+        return jax.jit(pairs)
+
+    ours, theirs, failed = traced("kernel"), traced("xla"), []
+    for number in numbers:
+        scores = np.array(jax.random.normal(
+            jax.random.PRNGKey(17 + number), (workers, 1, chunk, length), jnp.float32))
+        middle = number * chunk + chunk // 2
+        if middle >= topk:      # the topk-th largest causal score of one query: ties planted there
+            scores[..., 64:364] = np.sort(scores[0, 0, chunk // 2, :middle + 1])[-topk]
+        scores[..., 7::1031] = np.inf
+        scores[..., 11::1033] = -np.inf
+        scores[0, 0, ::5, 13] = np.nan
+        # a worker whose threshold falls among zeros of both signs and denormals
+        edge = np.resize(np.float32([0.0, -0.0, 1e-40, -1e-40, 2e-40]), length)
+        scores[-1, 0, :, :] = np.where(np.arange(length) % 3 == 0, edge, -1.0 - np.abs(scores[-1, 0]))
+        scores, number = jnp.asarray(scores), jnp.int32(number)
+        row = {"metric": "pallas_tpu_check", "rule": "select", "workers": workers, "chunk": chunk,
+               "length": length, "topk": topk, "number": int(number),
+               "tile_rows": select.tile_rows(chunk, length), "passes": list(select.passes(length))}
+        try:
+            pairs_k, pairs_x = ours(scores, number), theirs(scores, number)
+            row["selected"] = int(jnp.sum(pairs_k.astype(jnp.int32)))
+            row["differing"] = int(jnp.sum((pairs_k != pairs_x).astype(jnp.int32)))
+            row["kernel_ms"] = round(_least_ms(lambda: ours(scores, number), reps), 4)
+            row["sort_ms"] = round(_least_ms(lambda: theirs(scores, number), reps), 4)
+            row["parity"] = "ok" if row["differing"] == 0 and pairs_k.dtype == jnp.int8 else "FAIL"
+        except Exception as exc:  # a kernel the compiler refuses is a finding
+            row["parity"] = "ERROR"
+            row["error"] = "%s: %s" % (type(exc).__name__, str(exc)[:400])
+        emit(row)
+        if row["parity"] != "ok":
+            failed.append(row)
+    return failed
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=32)
@@ -375,7 +440,8 @@ def main():
                     help="harness self-test: run off-TPU in interpreter mode "
                          "(timings meaningless; parity logic still exercised)")
     ap.add_argument("--columns", default="gar,attention",
-                    help="which checks run: 'gar' (the rules), 'attention' (the fused kernel)")
+                    help="which checks run: 'gar' (the rules), 'attention' (the fused kernel), "
+                         "'select' (the threshold by counting)")
     ap.add_argument("--attention-reps", type=int, default=5)
     ap.add_argument("--attention-shapes", default=",".join(name for name, *_ in ATTENTION_SHAPES),
                     help="which rows of the attention column run")
@@ -409,6 +475,8 @@ def main():
             args.attention_reps, tiles, allow_interpret=args.allow_interpret,
             shapes=[shape for shape in ATTENTION_SHAPES
                     if shape[0] in args.attention_shapes.split(",")])
+    if "select" in columns:
+        failed += run_select_check(allow_interpret=args.allow_interpret)
     sys.exit(1 if failed else 0)
 
 

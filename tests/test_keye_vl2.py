@@ -19,6 +19,7 @@ import pytest
 from aggregathor_tpu import gars, models
 from aggregathor_tpu.models import keye_vl2
 from aggregathor_tpu.models.transformer import rope, rope_frequencies
+from aggregathor_tpu.ops import select
 from aggregathor_tpu.ops.attention import forced_form
 from aggregathor_tpu.parallel import RobustEngine, make_mesh
 from aggregathor_tpu.utils import UserException
@@ -135,15 +136,22 @@ def test_loss_and_gradients_match_the_reference(layers, topk, form):
     assert float(counters["routed_positions"]) > 0
 
 
-def test_every_query_selects_its_count_of_causal_keys():
+SELECT_FORMS = pytest.mark.parametrize("select_form", ["xla", "kernel"], ids=["sort", "count"])
+
+
+@SELECT_FORMS
+def test_every_query_selects_its_count_of_causal_keys(select_form):
     """``select``'s pairs: query t reads min(t + 1, k) keys, none after itself,
     the same ones as the reference's two argsorts choose from the same scores;
-    the three counts are those of the pairs."""
+    the three counts are those of the pairs.  By the sort and by the counting
+    kernel (ops/select.py, interpreted) alike."""
     cfg = config()
     layer = one_layer(seeded_params())
     u = jax.random.normal(jax.random.PRNGKey(6), (2, LENGTH, 64))
     positions = keye_vl2.text_positions(LENGTH)
-    pairs, (selected, far, live) = jax.jit(lambda u: keye_vl2.select(u, layer, cfg, positions))(u)
+    with select.forced_form(select_form):
+        pairs, (selected, far, live) = jax.jit(
+            lambda u: keye_vl2.select(u, layer, cfg, positions))(u)
     pairs = np.asarray(pairs)
     assert pairs.shape == (2, LENGTH, LENGTH) and pairs.dtype == np.int8
     assert set(np.unique(pairs)) == {0, 1} and not np.triu(pairs, 1).any()
@@ -161,7 +169,8 @@ def test_every_query_selects_its_count_of_causal_keys():
     assert float(live) == tiles.sum() and 2 * 4 <= tiles.sum() <= 2 * 10  # of 10 causal tiles each
 
 
-def test_equal_scores_go_to_the_lower_key():
+@SELECT_FORMS
+def test_equal_scores_go_to_the_lower_key(select_form):
     """Five keys tie for the last three places: the three lowest are in, in the
     program's threshold and in the reference's rank alike; a zero of either
     sign ties with the other; a key after the query is never in, whatever its
@@ -170,14 +179,15 @@ def test_equal_scores_go_to_the_lower_key():
     scores = scores.at[0, :, jnp.asarray([2, 5, 7, 8, 9])].set(1.0).at[0, :, 11].set(9.0)
     scores = scores.at[0, 1, 3].set(-0.0)
     q_pos = jnp.asarray([10, 3])
-    ours = np.asarray(keye_vl2.top_keys(scores, q_pos, 5))
-    assert np.flatnonzero(ours[0, 0]).tolist() == [1, 2, 4, 5, 7]       # 8 and 9 tie and lose
-    assert np.flatnonzero(ours[0, 1]).tolist() == [0, 1, 2, 3]          # all four causal keys
-    assert np.flatnonzero(np.asarray(keye_vl2.top_keys(scores, q_pos, 3))[0, 1]).tolist() == [
-        0, 1, 2]                                                         # -0.0 at 3 ties with 0.0 at 0
-    for topk in (1, 3, 5, 12, 20):
-        assert np.array_equal(np.asarray(keye_vl2.top_keys(scores, q_pos, topk)),
-                              np.asarray(reference._selected(scores, q_pos, topk))), topk
+    with select.forced_form(select_form):
+        ours = np.asarray(keye_vl2.top_keys(scores, q_pos, 5))
+        assert np.flatnonzero(ours[0, 0]).tolist() == [1, 2, 4, 5, 7]       # 8 and 9 tie and lose
+        assert np.flatnonzero(ours[0, 1]).tolist() == [0, 1, 2, 3]          # all four causal keys
+        assert np.flatnonzero(np.asarray(keye_vl2.top_keys(scores, q_pos, 3))[0, 1]).tolist() == [
+            0, 1, 2]                                                     # -0.0 at 3 ties with 0.0 at 0
+        for topk in (1, 3, 5, 12, 20):
+            assert np.array_equal(np.asarray(keye_vl2.top_keys(scores, q_pos, topk)),
+                                  np.asarray(reference._selected(scores, q_pos, topk))), topk
 
 
 @pytest.mark.parametrize("form", ["xla", "kernel"])
@@ -409,25 +419,40 @@ def test_the_layer_check_script_sees_a_wrong_selection(fault):
         assert all(row["grads_by_leaf"][name] == 0.0 for name in keye_layer_check.INDEXER)
 
 
-def test_the_selection_is_made_once_a_layer_and_kept():
+@SELECT_FORMS
+def test_the_selection_is_made_once_a_layer_and_kept(select_form):
     """Under ``jax.checkpoint`` a layer's forward is computed again for its
     backward pass; the selection is not: its pairs are the one residual a layer
     keeps by name (``KEPT``), so the program of loss and gradient holds ONE sort
-    (the scanned layers' forward body), not two, and one stacked int8 (layers, B,
-    L, L) residual between the forward scan and the backward one."""
-    def primitives(jaxpr, found):
+    (the scanned layers' forward body), not two — under the forced kernel no
+    sort and ONE ``pallas_call`` named ``select_threshold``, in the forward scan
+    and not in the backward one — and one stacked int8 (layers, B, L, L) residual
+    between the forward scan and the backward one."""
+    def primitives(jaxpr, found, scans=()):
         for eqn in jaxpr.eqns:
-            found.append((eqn.primitive.name, [v.aval for v in eqn.outvars]))
+            name = eqn.primitive.name
+            if name == "pallas_call":
+                name = str(eqn.params["name"])
+            found.append((name, [v.aval for v in eqn.outvars], scans))
+            inside = scans + (len(found),) if name == "scan" else scans
             for inner in jax.core.jaxprs_in_params(eqn.params):
-                primitives(inner, found)
+                primitives(inner, found, inside)
         return found
 
     experiment = models.instantiate("keye_vl2", arguments())
     params = seeded_params()
     batch = {"tokens": jnp.asarray(experiment.corpus[:2])}
-    found = primitives(jax.make_jaxpr(jax.grad(lambda p: experiment.loss(p, batch)[0]))(
-        params).jaxpr, [])
-    assert sum(name == "sort" for name, _ in found) == 1
+    with select.forced_form(select_form):
+        found = primitives(jax.make_jaxpr(jax.grad(lambda p: experiment.loss(p, batch)[0]))(
+            params).jaxpr, [])
+    choosing = "sort" if select_form == "xla" else "select_threshold"
+    assert sum(name == "sort" for name, _, _ in found) == (select_form == "xla")
+    assert sum("select_threshold" in name for name, _, _ in found) == (select_form == "kernel")
+    (chosen_in,) = [scans[0] for name, _, scans in found if choosing in name]
+    (keeping,) = [place + 1 for place, (name, avals, _) in enumerate(found) if name == "scan"
+                  and any(aval.shape == (3, 2, LENGTH, LENGTH) for aval in avals)]
+    assert chosen_in == keeping                  # the forward scan, which keeps the pairs
+    found = [(name, avals) for name, avals, _ in found]
     kept = [aval for name, avals in found if name == "scan" for aval in avals
             if aval.dtype == jnp.int8 and aval.shape[-2:] == (LENGTH, LENGTH)]   # not a chunk's
     assert [aval.shape for aval in kept] == [(3, 2, LENGTH, LENGTH)]

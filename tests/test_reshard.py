@@ -293,3 +293,34 @@ def test_attention_kernels_compile_for_the_chip_at_the_cells_shapes(v5e_2x2, mon
         kernels = [line for line in text.splitlines() if "tpu_custom_call" in line]
         assert all(wide(v_dim) in line for line in kernels)     # the output; v's gradient
         assert any(line.count(wide(head_dim)) >= 2 for line in kernels)  # q's and k's
+
+
+def test_the_select_kernel_compiles_for_the_chip_at_the_cells_shape(v5e_2x2, monkeypatch):
+    """ops/select.py's threshold by counting as the step of
+    ``keye_avgmedian_sparse8k`` calls it — three workers under ``vmap``, a chunk
+    of 512 queries over 8,192 scores numbered by a traced index, k = 2,048 —
+    compiles for the described chip: a tile's whole rows and their keys fit VMEM,
+    every slice is on a tile boundary, the int8 pairs leave the kernel, and
+    nothing is sorted."""
+    from jax.sharding import SingleDeviceSharding
+
+    from aggregathor_tpu.models import keye_vl2
+    from aggregathor_tpu.ops import select
+
+    monkeypatch.setattr(select.hw, "on_tpu", lambda: True)  # compile the kernel, not interpret
+    monkeypatch.setattr(select, "info", lambda *_: None)
+    workers, chunk, length, topk = 3, 512, 8192, 2048
+    assert select.select_form(chunk, length, topk) == "kernel"
+    one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
+    compiled = compile_uncached(
+        jax.jit(lambda scores, number: jax.vmap(lambda scores: keye_vl2.top_keys(
+            scores, number * chunk + jnp.arange(chunk), topk).astype(jnp.int8))(scores)),
+        jax.ShapeDtypeStruct((workers, 1, chunk, length), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=one_chip))
+    text = compiled.as_text()
+    calls = re.findall(r"^ *(?:ROOT )?%?([\w.-]*select_threshold[\w.-]*) = s8\[3,1,512,8192\].* "
+                       r'custom-call\(.*custom_call_target="tpu_custom_call"', text, re.M)
+    assert len(calls) == 1, calls
+    assert " sort(" not in text and " while(" not in text
+    # beside the scores and the pairs: the pairs as booleans' int8 again at most
+    assert compiled.memory_analysis().temp_size_in_bytes <= workers * chunk * length
