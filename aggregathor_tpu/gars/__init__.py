@@ -240,6 +240,26 @@ class GAR:
         finally:
             self._drop_memos()
 
+    #: The rule's kernel over one gradient leaf AS IT LIES, a method ``(n, ...,
+    #: A, B) -> (..., A, B)`` float32 of the rules that have one (the rank rules:
+    #: ops/pallas_kernels' leaf entries), else None.
+    leaf_kernel = None
+
+    def aggregate_leaf(self, leaf):
+        """In-place tier of a COORDINATE-WISE rule with no distances, axis or
+        key: the (n, ...) stack of the n workers' copies of one gradient leaf
+        to the aggregated (...) leaf, float32.  Such a rule is elementwise
+        across the workers whatever the shape, so the engine's in-place path
+        (parallel/in_place.py) never lays the leaves out as (n, d) rows: a
+        leaf goes to ``leaf_kernel`` as it lies where ``common.leaf_tier`` says
+        so, and else, flattened alone, through ``aggregate_block``."""
+        from .common import leaf_tier
+
+        if leaf_tier(self, leaf) == "kernel":
+            return self.leaf_kernel(leaf)
+        block = leaf.reshape(leaf.shape[0], -1).astype("float32")
+        return self._call_aggregate(block, None).reshape(leaf.shape[1:])
+
 
 # Self-registering rule modules (reference: aggregators/__init__.py:76-85)
 import_directory(__name__, __path__, skip=("oracle",))

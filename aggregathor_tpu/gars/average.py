@@ -16,5 +16,8 @@ class AverageGAR(GAR):
     def aggregate_block(self, block, dist2=None):
         return jnp.mean(block, axis=0)
 
+    def aggregate_leaf(self, leaf):
+        return jnp.mean(leaf.astype(jnp.float32), axis=0)
+
 
 register("average", AverageGAR)

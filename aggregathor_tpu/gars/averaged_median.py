@@ -52,5 +52,10 @@ class AveragedMedianGAR(GAR):
     def aggregate_block(self, block, dist2=None):
         return averaged_median_columns(block, self.nb_workers, self.beta)
 
+    def leaf_kernel(self, leaf):
+        from ..ops import pallas_kernels as pk
+
+        return pk.coordinate_averaged_median_leaf(leaf, beta=self.beta)
+
 
 register("averaged-median", AveragedMedianGAR)

@@ -307,3 +307,30 @@ def select_combine(weights, block):
     touched = (jnp.abs(w) > 0).astype(jnp.float32) @ (~finite).astype(jnp.float32)
     out = jnp.where(touched > 0, jnp.nan, out)
     return out if weights.ndim == 2 else out[0]
+
+
+# --------------------------------------------------------------------------- #
+# The in-place tier: a gradient leaf reduced as it lies (parallel/in_place.py).
+# New code below this line only (``kernel_tier`` above is the rows path's).
+
+def leaf_tier(gar, leaf):
+    """Which tier of ``gar`` reduces the (n, ...) stack of ONE gradient leaf
+    across its workers in place (``GAR.aggregate_leaf``; what the engine's log
+    counts its leaves by): ``"kernel"`` — ``gar.leaf_kernel``, the plane
+    kernels' leaf entry (``ops/pallas_kernels._plane_leaf_call``) — or
+    ``"jnp"``, the rule's own ``aggregate_block`` on ``leaf.reshape(n, -1)``.
+    A rule without a leaf kernel has the one tier; the kernel can take a leaf
+    of up to ``PLANE_ROWS_MAX`` workers whose last two dimensions hold a
+    whole (8, 128) tile, and never a vmapped one; on a TPU it does from
+    ``PALLAS_MIN_COLUMNS`` elements a worker, inside ``forced_tier("pallas")``
+    at any size, inside ``forced_tier("jnp")`` and off a TPU never."""
+    from math import prod
+
+    from ..ops.pallas_kernels import leaf_blocks
+
+    if (gar.leaf_kernel is None or _forced == "jnp" or _is_batched_tracer(leaf)
+            or leaf_blocks(leaf) is None):
+        return "jnp"
+    if _forced == "pallas" or (on_tpu() and prod(leaf.shape[1:]) >= PALLAS_MIN_COLUMNS):
+        return "kernel"
+    return "jnp"

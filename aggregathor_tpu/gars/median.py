@@ -42,5 +42,10 @@ class MedianGAR(GAR):
     def aggregate_block(self, block, dist2=None):
         return median_columns(block, self.nb_workers)
 
+    def leaf_kernel(self, leaf):
+        from ..ops import pallas_kernels as pk
+
+        return pk.coordinate_median_leaf(leaf)
+
 
 register("median", MedianGAR)

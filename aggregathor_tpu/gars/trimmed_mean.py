@@ -57,5 +57,11 @@ class TrimmedMeanGAR(GAR):
     def aggregate_block(self, block, dist2=None):
         return trimmed_mean_columns(block, self.nb_workers, self.nb_trim)
 
+    def leaf_kernel(self, leaf):
+        from ..ops import pallas_kernels as pk
+
+        return pk.coordinate_trimmed_mean_leaf(
+            leaf, trim=self.nb_trim, keep=self.nb_workers - 2 * self.nb_trim)
+
 
 register("trimmed-mean", TrimmedMeanGAR)

@@ -569,3 +569,141 @@ def pairwise_sq_distances(x, block_d=None, use_mxu=None, row_tile=None):
     # go slightly negative from cancellation — clamp it (NaN passes through
     # jnp.maximum); downstream scoring masks the diagonal itself.
     return jnp.maximum(out, 0.0) if use_mxu else out
+
+
+# --------------------------------------------------------------------------- #
+# The plane form over a gradient leaf as it lies (the step's in-place path,
+# parallel/in_place.py).  New code below this line only: what stands above is
+# what the rows path and the harness's probe compile, line for line.
+
+def _lanes_from_rows(rows, lanes):
+    """True where a leaf of ``rows`` x ``lanes`` goes to the kernel with its
+    last two dimensions swapped: its lanes are not whole and its rows are (a
+    head over a vocabulary slice of 18,992 or 16,032 ids, ``wkv_a``'s 576).
+    The kernel then covers all of it and no slice is left to jnp — and that is
+    how the backward pass leaves such a leaf (XLA lays a minor dimension that
+    is not whole lanes second: the swap is a bitcast in the grid's three cells
+    that have one, where the leaf as it is declared cost a transposing copy of
+    394-622 MB a step; PERF.md section 6, PR 47)."""
+    return lanes % LANE != 0 and rows % LANE == 0
+
+
+def leaf_blocks(x):
+    """The (ta, tb) block of the plane form's LEAF entry over the last two
+    dimensions of ``x``, the (n, ..., A, B) stack of n workers' copies of one
+    leaf (anything with a shape): ``ta`` a multiple of 8 that divides the
+    whole sublane groups of A, ``tb`` a multiple of ``LANE`` that divides the
+    whole lanes of B — no block reaches past an edge (PR 30) — with the n
+    planes of a block inside ``PLANE_BLOCK_BYTES``: the widest such ``tb``,
+    then the tallest ``ta`` that still fits (a wide block is long runs of
+    whole tiles to its DMA).  None where the entry does not serve: a
+    leaf of fewer than two dimensions a worker, more than ``PLANE_ROWS_MAX``
+    workers, A under 8 or B under ``LANE``."""
+    if len(x.shape) < 3:
+        return None
+    n, (rows, lanes) = x.shape[0], x.shape[-2:]
+    if _lanes_from_rows(rows, lanes):
+        rows, lanes = lanes, rows
+    if n > PLANE_ROWS_MAX or rows < 8 or lanes < LANE:
+        return None
+    groups, vregs = rows // 8, lanes // LANE
+    budget = PLANE_BLOCK_BYTES // (4 * n * PLANE)  # (8, LANE) vregs a worker a block
+    wide = max(w for w in range(1, min(vregs, budget) + 1) if vregs % w == 0)
+    high = max(h for h in range(1, min(groups, budget // wide) + 1) if groups % h == 0)
+    return 8 * high, LANE * wide
+
+
+def _plane_leaf_call(name, rule, x, interpret):
+    """``_plane_call`` for a gradient leaf AS IT LIES: ``x`` is (n, ..., A, B),
+    the n workers' copies of one leaf, in (8, LANE) tiles over A x B the way
+    the backward pass left it; the result is the aggregated leaf, (..., A, B)
+    float32.  The kernel's operand is (X, n, A, B): the worker axis moved to
+    just above the two tiled dimensions and the others merged into X — both
+    bitcasts where the leaf lies so, and it does: a scan over layers stacks a
+    layer's (n, A, B) gradients layer-major, and the held experts' come out
+    of their batched product [layer][expert][worker] (with the workers
+    leading, XLA copied 0.9-2.4 GB a step in front of the kernels in the
+    grid's language cells; PERF.md section 6, PR 47).  A block is
+    ``leaf_blocks``' (1, n, ta, tb), and per (8, LANE) tile of it the kernel
+    stacks the n workers' vregs — each read densely, as the tile it is — and
+    runs the SAME ``rule`` closure on the same (n, 8, LANE) stack as
+    ``_plane_call``: same selections, same ties, same non-finite handling.  No
+    row of d columns is laid out in front of it and nothing is inflated behind
+    it.  The rows past the last whole 8 and the lanes past the last whole
+    ``LANE`` go through the same rule as jnp on the slice (as a slab, like
+    ``_plane_call``'s) and into the result in place; a leaf whose rows are
+    whole lanes where its lanes are not is handed over with the two swapped
+    (``_lanes_from_rows``) and has no such slice."""
+    if _lanes_from_rows(*x.shape[-2:]):
+        return jnp.swapaxes(
+            _plane_leaf_call(name, rule, jnp.swapaxes(x, -1, -2), interpret), -1, -2)
+    n, shape = x.shape[0], x.shape[1:]
+    rows, lanes = shape[-2:]
+    ta, tb = leaf_blocks(x)
+    xp = jnp.moveaxis(x.astype(jnp.float32), 0, -3).reshape(-1, n, rows, lanes)
+    outer = xp.shape[0]
+    whole_rows, whole_lanes = rows // 8 * 8, lanes // LANE * LANE
+
+    def kernel(x_ref, out_ref):
+        def group(r, carry):
+            sub = pl.ds(pl.multiple_of(r * 8, 8), 8)
+
+            def tile(c, carry):
+                lane = pl.ds(pl.multiple_of(c * LANE, LANE), LANE)
+                out_ref[0, sub, lane] = rule(jnp.stack([x_ref[0, j, sub, lane] for j in range(n)]))
+                return carry
+
+            return jax.lax.fori_loop(0, tb // LANE, tile, carry)
+
+        jax.lax.fori_loop(0, ta // 8, group, 0)
+
+    out = pl.pallas_call(
+        kernel,
+        grid=(outer, whole_rows // ta, whole_lanes // tb),
+        in_specs=[pl.BlockSpec((1, n, ta, tb), lambda o, i, j: (o, 0, i, j),
+                               memory_space=pltpu.VMEM)],
+        out_specs=pl.BlockSpec((1, ta, tb), lambda o, i, j: (o, i, j), memory_space=pltpu.VMEM),
+        out_shape=jax.ShapeDtypeStruct((outer, rows, lanes), jnp.float32),
+        interpret=interpret,
+        name=name,
+    )(xp)
+
+    def as_slab(piece):  # the rule on a slice of the leaf, as (n, w) -> (w,)
+        piece = jnp.moveaxis(piece, 1, 0)
+        return rule(piece.reshape(n, -1)).reshape(piece.shape[1:])
+
+    if whole_lanes < lanes:
+        out = jax.lax.dynamic_update_slice(out, as_slab(xp[..., whole_lanes:]), (0, 0, whole_lanes))
+    if whole_rows < rows:
+        out = jax.lax.dynamic_update_slice(
+            out, as_slab(xp[:, :, whole_rows:, :whole_lanes]), (0, whole_rows, 0))
+    return out.reshape(shape)
+
+
+# The leaf entries of the three rank rules (``_plane_leaf_call``): the n
+# workers' copies of ONE gradient leaf, (n, ..., A, B), to the aggregated leaf,
+# under the 2-D entry's kernel name: a device trace shows the rule with the
+# leaf's shape.  They share one ``jit``, so that a step traces the wrapper
+# once a leaf shape and not once a leaf (0.09 s each; PERF.md section 5);
+# whether the kernel is interpreted is read outside it and is part of its key.
+
+@functools.partial(jax.jit, static_argnames=("name", "rule", "args", "interpret"))
+def _leaf_entry(x, name, rule, args, interpret):
+    return _plane_leaf_call(name, functools.partial(rule, x.shape[0], *args), x, interpret)
+
+
+def coordinate_median_leaf(x):
+    """Upper median over the leading axis of (n, ..., A, B), non-finite last."""
+    return _leaf_entry(x, "coordinate_median_planes", _median_rule, (), _interpret())
+
+
+def coordinate_averaged_median_leaf(x, beta):
+    """Mean over the leading axis of the ``beta`` values closest to the median."""
+    return _leaf_entry(
+        x, "coordinate_averaged_median_planes", _averaged_median_rule, (beta,), _interpret())
+
+
+def coordinate_trimmed_mean_leaf(x, trim, keep):
+    """Mean over the leading axis of the values at sorted ranks [trim, trim+keep)."""
+    return _leaf_entry(
+        x, "coordinate_trimmed_mean_planes", _trimmed_mean_rule, (trim, keep), _interpret())

@@ -49,7 +49,7 @@ from ..gars.common import centered_gram_sq_distances
 from ..obs import trace
 from ..obs.profiler import PHASE_PREFIX
 from ..ops.pallas_kernels import LANE, MAX_BLOCK
-from ..utils import UserException
+from ..utils import UserException, info
 from ..utils.hw import on_tpu
 from .mesh import model_axis, pipe_axis, worker_axis
 
@@ -486,6 +486,13 @@ class RobustEngine:
             )
         # jitted slice-concat executables for assemble_batches, per slice count
         self._assemble_cache = {}
+        # Which of the flat step's two dataflows this engine runs, decided
+        # here, once, from the arguments above (parallel/in_place.py): None
+        # where nothing needs the workers' gradients as (n, d) rows — the step
+        # then reduces each gradient leaf in place — else what does.
+        from .in_place import rows_reason
+
+        self._rows_reason = rows_reason(self)
 
     # ------------------------------------------------------------------ #
 
@@ -1032,8 +1039,24 @@ class RobustEngine:
         )
         return (state_shardings, NamedSharding(self.mesh, P()))
 
+    @property
+    def gradient_path(self):
+        """``"in place"`` where the flat step reduces the gradient leaves where
+        they lie (parallel/in_place.py), ``"rows"`` where it lays them out as
+        the (n, d) matrix of the module docstring; fixed when the engine was
+        built."""
+        return "in place" if self._rows_reason is None else "rows"
+
     def _make_flat_body(self, loss_fn, tx):
-        """The per-step SPMD body shared by build_step and build_multi_step."""
+        """The per-step SPMD body shared by build_step and build_multi_step:
+        the in-place path's (parallel/in_place.py) for an engine in which
+        nothing needs the rows, else the rows path's, below.  Which one is
+        logged once a build."""
+        if self._rows_reason is None:
+            from .in_place import make_body
+
+            return make_body(self, loss_fn, tx)
+        info("step lays gradients out as (n, d) rows: needed by %s" % self._rows_reason)
         W = self.nb_devices
 
         def body(state, batch):

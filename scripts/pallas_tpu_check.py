@@ -47,6 +47,12 @@ scores with a run of 300 equal ones planted across the threshold, infinities
 and a NaN, and for one worker a row set whose threshold falls among zeros of
 both signs and denormals — ``array_equal`` of the two forms' int8 pairs (nothing
 but time may differ) and each form's least ms of three.
+
+The leaf column (``run_leaf_check``; ``--columns leaf``): each plane kernel's
+leaf entry (the step's in-place path, parallel/in_place.py) against its 2-D
+entry at the grid's largest leaves — (4, 8, 768, 2048) x 4 workers, (2048,
+18992) x 3, (4, 2048, 576) x 3 — NaN, both infinities and ties planted:
+``differing`` 0 bit for bit, and each entry's least ms of five.
 """
 
 import argparse
@@ -427,6 +433,82 @@ def run_select_check(reps=3, workers=3, chunk=512, length=8192, topk=2048, numbe
     return failed
 
 
+#: ``--columns leaf``: (workers, a worker's leaf) — the grid's largest leaves:
+#: cell 5's held experts, cells 5 and 8's head (18,992 is 148 whole lanes and
+#: 48 columns), cell 7's ``wkv_a`` (576: 4 whole lanes and 64)
+LEAF_SHAPES = ((4, (4, 8, 768, 2048)), (3, (2048, 18992)), (3, (4, 2048, 576)))
+
+
+def run_leaf_check(reps=5, shapes=LEAF_SHAPES, allow_interpret=False, emit=_print_row):
+    """Each plane kernel's LEAF entry (``ops/pallas_kernels._plane_leaf_call``:
+    the n workers' copies of one gradient leaf as they lie) against its 2-D
+    entry on the same numbers laid out as (n, d) rows, at ``shapes``, NaN, both
+    infinities and ties planted: ``differing`` elements of the two results
+    (bit for bit; 0 wanted: one ``rule`` closure serves both) and each entry's
+    least ms — the 2-D entry's WITHOUT the flatten in front of it and the
+    reshape behind it, which the step's rows path pays besides.  ``emit(row)``
+    each; returns the rows that differ."""
+    import jax
+    import jax.numpy as jnp
+
+    from aggregathor_tpu.ops import pallas_kernels as pk
+
+    if not allow_interpret and pk._interpret():
+        raise RuntimeError("the leaf column needs a TPU backend (the kernels would "
+                           "interpret on %r)" % jax.default_backend())
+
+    def entries(n):
+        f = 1
+        return (
+            ("median", pk.coordinate_median_leaf, pk.coordinate_median),
+            ("averaged-median",
+             lambda x: pk.coordinate_averaged_median_leaf(x, beta=n - f),
+             lambda x: pk.coordinate_averaged_median(x, n - f)),
+            ("trimmed-mean",
+             lambda x: pk.coordinate_trimmed_mean_leaf(x, trim=f, keep=n - 2 * f),
+             lambda x: pk.coordinate_trimmed_mean(x, f, n - 2 * f)),
+        )
+
+    @jax.jit
+    def planted(key, like):
+        x = jax.random.normal(key, like.shape, jnp.float32)
+        x = x.at[0, ..., 5::131].set(jnp.nan).at[1, ..., 7::257].set(jnp.inf)
+        x = x.at[-1, ..., 9::263].set(-jnp.inf).at[:, ..., 11::127].set(1.0)
+        x = x.at[1, ..., 3, :].set(jnp.nan)  # a worker's whole row of every tile column
+        # as the backward pass leaves a stacked leaf: the workers just above the tiles
+        return jnp.moveaxis(x, 0, -3)
+
+    def workers_first(lain):
+        return jnp.moveaxis(lain, -3, 0)
+
+    failed = []
+    for n, shape in shapes:
+        lain = planted(jax.random.PRNGKey(n + len(shape)), jnp.zeros((n,) + shape))
+        rows = jax.jit(lambda lain: workers_first(lain).reshape(n, -1))(lain)
+        for rule, leaf_entry, flat_entry in entries(n):
+            leaf_entry = jax.jit(lambda lain, entry=leaf_entry: entry(workers_first(lain)))
+            flat_entry = jax.jit(flat_entry)
+            row = {"metric": "pallas_tpu_check", "rule": rule, "entry": "leaf", "n": n,
+                   "shape": list(shape), "block": list(pk.leaf_blocks(workers_first(lain)))}
+            try:
+                ours, theirs = leaf_entry(lain), flat_entry(rows).reshape(shape)
+                row["differing"] = int(jnp.sum(
+                    jax.lax.bitcast_convert_type(ours, jnp.int32)
+                    != jax.lax.bitcast_convert_type(theirs, jnp.int32)))
+                row["nonfinite"] = int(jnp.sum(~jnp.isfinite(ours)))
+                row["leaf_ms"] = round(_least_ms(lambda: leaf_entry(lain), reps), 4)
+                row["rows_ms"] = round(_least_ms(lambda: flat_entry(rows), reps), 4)
+                row["leaf_gb_per_s"] = round((n + 1) * 4 * ours.size / row["leaf_ms"] / 1e6, 1)
+                row["parity"] = "ok" if row["differing"] == 0 and ours.shape == shape else "FAIL"
+            except Exception as exc:  # a kernel the compiler refuses is a finding
+                row["parity"] = "ERROR"
+                row["error"] = "%s: %s" % (type(exc).__name__, str(exc)[:400])
+            emit(row)
+            if row["parity"] != "ok":
+                failed.append(row)
+    return failed
+
+
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=32)
@@ -441,7 +523,8 @@ def main():
                          "(timings meaningless; parity logic still exercised)")
     ap.add_argument("--columns", default="gar,attention",
                     help="which checks run: 'gar' (the rules), 'attention' (the fused kernel), "
-                         "'select' (the threshold by counting)")
+                         "'select' (the threshold by counting), 'leaf' (the plane kernels' "
+                         "leaf entry against their 2-D entry)")
     ap.add_argument("--attention-reps", type=int, default=5)
     ap.add_argument("--attention-shapes", default=",".join(name for name, *_ in ATTENTION_SHAPES),
                     help="which rows of the attention column run")
@@ -477,6 +560,8 @@ def main():
                     if shape[0] in args.attention_shapes.split(",")])
     if "select" in columns:
         failed += run_select_check(allow_interpret=args.allow_interpret)
+    if "leaf" in columns:
+        failed += run_leaf_check(allow_interpret=args.allow_interpret)
     sys.exit(1 if failed else 0)
 
 
