@@ -155,7 +155,7 @@ def check_spec(spec):
         if out.dtype != jnp.float32:
             findings.append(_finding(
                 "GC004", spec, "dtype",
-                "float32 input aggregated to %s: the exchange-dtype "
+                "float32 input aggregated to %s: the wire-dtype "
                 "round-trip in the engines relies on dtype preservation"
                 % out.dtype,
             ))

@@ -56,12 +56,6 @@ def build_parser():
         help="scan this many steps per dispatch (cadences then fire at chunk granularity)",
     )
     parser.add_argument(
-        "--exchange-dtype", default=None, choices=["float32", "bfloat16"],
-        help="wire precision of the gradient exchange (bfloat16 halves the "
-             "collective bytes; GAR math stays float32).  Subsumed by "
-             "--exchange, which also reaches int8/top-k",
-    )
-    parser.add_argument(
         "--exchange", default=None, metavar="SPEC",
         help="wire codec of the gradient exchange (parallel/compress.py, "
              "docs/engine.md 'The wire'): f32 | bf16 | int8[:ef] | "
@@ -102,15 +96,6 @@ def build_parser():
              "reference's semantics, graph.py:144-168) or per parameter "
              "leaf (leaf — per-layer selection; each layer picks its own "
              "honest set)",
-    )
-    parser.add_argument(
-        "--leaf-bucketing", default="auto", choices=["auto", "on", "off"],
-        help="granularity:leaf implementation: bucket same-shaped leaves "
-             "into one vmapped rule call per distinct size (the TPU-shaped "
-             "program) or loop per leaf (faster on XLA:CPU — measured, "
-             "BENCHMARKS.md row 6b). auto picks by backend; the two paths "
-             "make identical selections (same per-leaf PRNG keys) and agree "
-             "numerically to float tolerance",
     )
     parser.add_argument(
         "--reputation-decay", type=float, default=None, metavar="BETA",
@@ -618,19 +603,8 @@ def main(argv=None):
         )
     # The wire codec (--exchange, parallel/compress.py): parsed up front so
     # a bad spec or an infeasible composition fails before any compilation.
-    exchange_codec = None
-    if args.exchange:
-        if args.exchange_dtype:
-            raise UserException(
-                "--exchange generalizes --exchange-dtype (bf16 is spelled "
-                "--exchange bf16); pass only one"
-            )
-        spec_dtype, exchange_codec = compress.parse_exchange_spec(args.exchange)
-        if spec_dtype is not None:
-            # bf16 normalizes onto the historical dtype twin (works on
-            # BOTH engines, bit-compatible with existing runs)
-            args.exchange_dtype = "bfloat16"
-            args.exchange = None
+    # (bf16/f32 are wire dtypes, no codec: they work on BOTH engines.)
+    _, exchange_codec = compress.parse_exchange_spec(args.exchange)
     if exchange_codec is not None:
         if args.mesh:
             raise UserException(
@@ -917,21 +891,11 @@ def main(argv=None):
                         name for name in models.itemize()
                         if getattr(models.get(name), "supports_sharded", False)) or "none")
                 )
-            if args.leaf_bucketing != "auto":
-                warning(
-                    "--leaf-bucketing applies to the flat engine's leaf path "
-                    "only; the sharded engine always aggregates per bucket"
-                )
         else:
             if args.granularity in ("layer", "global"):
                 raise UserException(
                     "--granularity %s needs the sharded engine: pass --mesh W,PP,TP"
                     % args.granularity
-                )
-            if args.leaf_bucketing != "auto" and args.granularity != "leaf":
-                warning(
-                    "--leaf-bucketing only affects --granularity leaf; ignored "
-                    "for granularity %r" % args.granularity
                 )
             if args.input_source == "device":
                 if jax.process_count() > 1:
@@ -1200,7 +1164,7 @@ def main(argv=None):
                 engine = RobustEngine(
                     mesh, gar, nb_workers=n, sharding="sharded",
                     nb_real_byz=r, attack=attack, lossy_link=lossy,
-                    granularity=gran, exchange_dtype=args.exchange_dtype,
+                    granularity=gran, exchange=args.exchange,
                     worker_momentum=args.worker_momentum,
                     worker_metrics=args.worker_metrics,
                     reputation_decay=ov.reputation_decay,
@@ -1262,14 +1226,13 @@ def main(argv=None):
             else:
                 engine = RobustEngine(
                     mesh, gar, n, nb_real_byz=r, attack=attack, lossy_link=lossy,
-                    exchange_dtype=args.exchange_dtype, exchange=exchange_codec,
+                    exchange=args.exchange,
                     worker_momentum=args.worker_momentum,
                     batch_transform=experiment.device_transform(),
                     worker_metrics=args.worker_metrics,
                     reputation_decay=ov.reputation_decay,
                     quarantine_threshold=ov.quarantine_threshold,
                     granularity=args.granularity,
-                    leaf_bucketing={"auto": "auto", "on": True, "off": False}[args.leaf_bucketing],
                     # under bounded-wait the straggler schedule moved to the
                     # HOST clock (straggler_model); in-graph chaos is off
                     chaos=None if bounded_wait else chaos,

@@ -10,7 +10,10 @@ the tiers are:
   replaces both the pure-TF tier and the C++ CPU/GPU custom ops;
 - **oracle** (``gars/oracle.py``): plain numpy, reference-faithful semantics,
   the cross-check used by the property tests (SURVEY.md §4);
-- **pallas** (``ops/``): hand-written TPU kernels for the O(n²·d) hot path;
+- **pallas** (``ops/pallas_kernels.py``): hand-written TPU kernels — the
+  O(n²·d) pairwise distances (the row count alone picks one of two) and the
+  coordinate-wise selections; ``gars/common.py`` ``kernel_tier`` /
+  ``leaf_tier`` say where they run, from the platform and the shape;
 - **native** (``ops/native``): C++ host library via ctypes, parity with the
   reference's ``aggregators/deprecated_native`` tier.
 

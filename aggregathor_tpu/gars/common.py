@@ -35,7 +35,7 @@ def _is_batched_tracer(x):
     """True when ``x`` is being traced under ``jax.vmap`` (batching trace).
 
     Both engines call the rules under vmap on their bucketed paths
-    (engine._aggregate_per_leaf_bucketed, the sharded per-bucket loop); a
+    (engine._aggregate_per_leaf, the sharded per-bucket loop); a
     vmapped ``pallas_call`` lowers through Pallas' batching rule.
     Detecting the batching trace centrally means no call site can forget an
     opt-out wrapper; ``forced_tier("pallas")`` remains the one way to

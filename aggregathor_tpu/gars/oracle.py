@@ -95,14 +95,14 @@ def krum(grads, f):
     return np.mean(grads[selected], axis=0)
 
 
-def bulyan(grads, f):
-    """Iterative Multi-Krum selection with pruned incremental rescoring, then
-    coordinate-wise averaged-median (op_bulyan/cpu.cpp:52-188)."""
+def bulyan_rounds(grads, f):
+    """The workers each of Bulyan's t = n - 2f - 2 Multi-Krum rounds averages
+    (round k: the m - k smallest live scores), under pruned incremental
+    rescoring (op_bulyan/cpu.cpp:52-160)."""
     grads = np.asarray(grads, dtype=np.float64)
-    n, d = grads.shape
+    n = grads.shape[0]
     m = n - f - 2
     t = n - 2 * f - 2
-    b = t - 2 * f
     in_score = n - f - 2
     dist = _pairwise_sq_distances(grads)
     np.fill_diagonal(dist, np.inf)
@@ -115,18 +115,28 @@ def bulyan(grads, f):
         pruned[i, kept] = np.where(np.isfinite(dist[i, kept]), dist[i, kept], np.inf)
         scores[i] = np.sum(pruned[i, kept])
     # Selection loop
-    selections = np.empty((t, d))
+    rounds = []
     live_scores = scores.copy()
     for k in range(t):
         key = np.where(np.isfinite(live_scores), live_scores, np.inf)
         order = np.argsort(key, kind="stable")
-        selections[k] = np.mean(grads[order[: m - k]], axis=0)
+        rounds.append(order[: m - k])
         if k + 1 < t:
             best = order[0]
             with np.errstate(invalid="ignore"):  # inf - inf on dead rows; masked via isfinite above
                 live_scores = live_scores - pruned[:, best]
             live_scores[best] = np.inf
-    # Coordinate-wise averaged-median over the t selections (cpu.cpp:163-187)
+    return rounds
+
+
+def bulyan(grads, f):
+    """Iterative Multi-Krum selection (``bulyan_rounds``), then coordinate-wise
+    averaged-median of the rounds' averages (op_bulyan/cpu.cpp:163-187)."""
+    grads = np.asarray(grads, dtype=np.float64)
+    n, d = grads.shape
+    t = n - 2 * f - 2
+    b = t - 2 * f
+    selections = np.stack([np.mean(grads[workers], axis=0) for workers in bulyan_rounds(grads, f)])
     out = np.empty(d)
     for x in range(d):
         col = selections[:, x]

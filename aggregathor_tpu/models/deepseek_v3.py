@@ -42,8 +42,9 @@ What is computed here and what is another model's: the held-experts loop
 ``checkpoint``, the chunked XLA attention, the head and its loss, the seeded
 leaves and rows and the experiment's feeds (models/laguna.py), the norm and
 RoPE (models/transformer.py).  Attention goes through ops/attention.py
-``attend``: on a TPU the fused kernel, its one head width padded from 192 / 128
-to 256; elsewhere ``chunked_attention``, whose accumulator is as wide as v.
+``attend``: on a TPU the fused kernel, scores at 192 and values at 128 as they
+are, two heads a grid step; elsewhere ``chunked_attention``, whose accumulator
+is as wide as v.
 """
 
 import dataclasses

@@ -228,14 +228,6 @@ def _span(head, width, heads):
     return first, -(-(lo + width) // unit) * unit - first, lo - first
 
 
-def _rows_of(head, heads, rows):
-    """Where head ``head`` of a step's ``heads`` lies in the log-sum-exp's
-    (heads * rows, lanes) block, head under head: all of it for a lone head,
-    said as the kernel always said it (equal widths trace the text they
-    traced: tests/test_attention_kernel.py holds its hash)."""
-    return Ellipsis if heads == 1 else slice(head * rows, (head + 1) * rows)
-
-
 def _stack(ref, scr, head, rep, q_tile, width, scale=None, front=0):
     """The ``rep`` query heads of key head ``head`` in the (q_tile, heads * R *
     width) block of ``ref``, head beside head, into the rows of ``scr`` (R *
@@ -324,7 +316,7 @@ def _fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, qs, m, l, acc, *, mask, slo
         for r in range(rep):
             at = (h * rep + r) * dv
             o_ref[:, at:at + dv] = out[r * q_tile:(r + 1) * q_tile].astype(o_ref.dtype)
-        lse_ref[_rows_of(h, heads, rows)] = m[...] + jnp.log(total)
+        lse_ref[h * rows:(h + 1) * rows] = m[...] + jnp.log(total)   # head under head
 
 
 def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref, dk_ref, dv_ref, qs, dos,
@@ -343,7 +335,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref, dk_ref, dv_
         _stack(do_ref, dos, h, rep, q_tile, dv)
         _stack(o_ref, dq, h, rep, q_tile, dv)   # the output, in dq's room for a moment
         delta[...] = jnp.broadcast_to(
-            jnp.sum(dos[...] * (dq[...] if span == dv else dq[:, :dv]), axis=1, keepdims=True),
+            jnp.sum(dos[...] * dq[:, :dv], axis=1, keepdims=True),
             delta.shape)
         dq[...] = jnp.zeros(dq.shape, jnp.float32)
 
@@ -354,7 +346,7 @@ def _bwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref, do_ref, dq_ref, dk_ref, dv_
                 dv_ref[...] = jnp.zeros(dv_ref.shape, jnp.float32)
 
         def fold(j, edged, across=slice(first, first + span), own=slice(h * dv, (h + 1) * dv),
-                 stats=_rows_of(h, heads, rows)):
+                 stats=slice(h * rows, (h + 1) * rows)):
             tile = pl.ds(pl.multiple_of(j * k_tile, k_tile), k_tile)
             s = _scores(qs[...], k_ref, across, j, i, edged, mask, rep, q_tile, k_tile, pairs_ref)
             p = jnp.exp(s - _wide(lse_ref[stats], k_tile))
@@ -427,7 +419,7 @@ def _pairs_at(kernel, place, q_tile, length):
     return with_pairs, pl.BlockSpec((None, q_tile, length), lambda b, g, i: (b, i, 0))
 
 
-def _forward(q, k, v, plan, pairs=None):
+def _forward(q, k, v, plan, pairs):
     """(the output (B, L, G * R * Dv), the log-sum-exp (B, G / heads a step, L *
     R * heads a step, lanes): a query tile's rows head under head).  With
     ``pairs`` (a mask that is data) they are one more input, and the call has
@@ -449,8 +441,11 @@ def _forward(q, k, v, plan, pairs=None):
             v.reshape(b, length, g * dv), *operands)
 
 
-def _backward(plan, kept, dout, pairs=None):
-    q, k, v, out, lse = kept
+def _backward(plan, kept, dout):
+    """The rule's backward pass: (dq, dk, dv, the pairs' cotangent).  ``pairs``
+    are int8 and take the zero of their tangent type, ``float0``; a
+    predicate's ``None`` takes ``None``."""
+    q, k, v, out, lse, pairs = kept
     b, length, g, rep, dqk = q.shape
     dv = v.shape[-1]
     static, (q_wide, k_wide, o_wide, v_wide, per_row), grid, lanes, span, room = _static(q, v, plan)
@@ -468,38 +463,22 @@ def _backward(plan, kept, dout, pairs=None):
         [room(span), room(dv), room(lanes), room(span)], grid, True, **static)(
             q.reshape(b, length, g * rep * dqk), k.reshape(b, length, g * dqk),
             v.reshape(b, length, g * dv), out, lse, dout, *operands)
-    return tuple(grad.reshape(a.shape).astype(a.dtype) for grad, a in zip(grads, (q, k, v)))
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _fused(q, k, v, plan):
-    return _forward(q, k, v, plan)[0]
-
-
-def _fused_fwd(q, k, v, plan):
-    out, lse = _forward(q, k, v, plan)
-    return out, (q, k, v, out, lse)
-
-
-_fused.defvjp(_fused_fwd, _backward)
+    no_tangent = None if pairs is None else np.zeros(pairs.shape, jax.dtypes.float0)
+    return tuple(grad.reshape(a.shape).astype(a.dtype)
+                 for grad, a in zip(grads, (q, k, v))) + (no_tangent,)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
-def _fused_by_pairs(q, k, v, pairs, plan):
+def _fused(q, k, v, pairs, plan):
     return _forward(q, k, v, plan, pairs)[0]
 
 
-def _fused_by_pairs_fwd(q, k, v, pairs, plan):
+def _fused_fwd(q, k, v, pairs, plan):
     out, lse = _forward(q, k, v, plan, pairs)
     return out, (q, k, v, out, lse, pairs)
 
 
-def _fused_by_pairs_bwd(plan, kept, dout):
-    *kept, pairs = kept
-    return _backward(plan, kept, dout, pairs) + (np.zeros(pairs.shape, jax.dtypes.float0),)
-
-
-_fused_by_pairs.defvjp(_fused_by_pairs_fwd, _fused_by_pairs_bwd)
+_fused.defvjp(_fused_fwd, _backward)
 
 
 def fused_attention(q, k, v, mask, q_tile, k_tile, pairs=None):
@@ -515,7 +494,7 @@ def fused_attention(q, k, v, mask, q_tile, k_tile, pairs=None):
     if isinstance(mask, Selected) != (pairs is not None):
         raise ValueError("%r and pairs %s: a mask that is data comes with its pairs, a "
                          "predicate without" % (mask, "given" if pairs is not None else "missing"))
-    return _fused(q, k, v, plan) if pairs is None else _fused_by_pairs(q, k, v, pairs, plan)
+    return _fused(q, k, v, pairs, plan)
 
 
 # --------------------------------------------------------------------------- #

@@ -5,14 +5,13 @@ The framework's counterpart of the reference's C++/CUDA custom ops
 aggregators/deprecated_native/native.cpp:678-747).  Two hot shapes:
 
 - **Pairwise squared distances** of the (n, d) gradient matrix — O(n²·d),
-  streamed over column blocks so the whole matrix never sits in VMEM.  Three
-  kernels.  Up to 64 rows (every cell of the grid) the exact difference form
-  runs as one row tile, ``_dist_pairs_kernel``: one read of each (n, blk)
-  block, blocks of up to 16,384 columns, each unordered pair of 8-row groups
-  taken once, squared differences kept as 128-lane partials in VMEM and
-  reduced across lanes once, at the last grid step.  Beyond, or with a forced
-  ``row_tile``: the (i, j, k)-tiled difference form (VPU, a tile x tile x
-  blk tensor per step) and the MXU Gram form (``|a|² + |b|² − 2ab`` per block,
+  streamed over column blocks so the whole matrix never sits in VMEM.  Two
+  kernels, chosen from the row count.  Up to 64 rows (every cell of the grid)
+  the exact difference form runs as one row tile, ``_dist_pairs_kernel``: one
+  read of each (n, blk) block, blocks of up to 16,384 columns, each unordered
+  pair of 8-row groups taken once, squared differences kept as 128-lane
+  partials in VMEM and reduced across lanes once, at the last grid step.
+  Beyond: the (i, j, k)-tiled MXU Gram form (``|a|² + |b|² − 2ab`` per block,
   median-centered against catastrophic cancellation — the same math the
   sharded engine psums, parallel/engine.py).
 - **Coordinate-wise selection** (median / averaged-median / trimmed mean,
@@ -85,7 +84,7 @@ def _pad_axis(x, axis, multiple, value=0.0):
 
 
 #: Lane width of the (8, 128) tiles, and the widest column block of the
-#: coordinate kernels and of the tiled distance kernels: their blocks are a
+#: coordinate kernels and of the tiled distance kernel: their blocks are a
 #: multiple of the first and at most the second.  ``engine._block_width`` cuts
 #: the four-chip column blocks on the same two numbers, so that every block
 #: starts on a tile boundary and ends on a whole kernel block.
@@ -115,28 +114,21 @@ def _whole_blocks(x, blk, fill):
     return xp, xp.shape[1] // blk * blk
 
 
-#: Worker-row tile of the distance kernels: above this many (padded) rows
+#: Worker-row tile of the Gram distance kernel: above this many (padded) rows
 #: the row axis is tiled so n=128..512 lowers without holding the whole
 #: (n, d_block) slab pair — per grid cell only two (ROW_TILE, blk) input
 #: tiles and one (ROW_TILE, ROW_TILE) output tile live in VMEM.
 ROW_TILE = 128
 
 
-def _pick_block_diff(tile, d, vmem_budget=1 << 22):
-    """Block of the TILED difference form (more than ``PAIR_ROWS_MAX`` rows,
-    or a forced ``row_tile``): the tile·tile·blk difference tensor sets the
-    size (``tile`` is the ROW TILE, not n — row tiling keeps the budget
-    independent of the worker count)."""
-    return _clamp_block(vmem_budget // max(tile * tile * 4, 1), d)
-
-
 def _pick_block_coord(n, d, vmem_budget=1 << 21):
     """Coordinate-kernel block: footprint is O(n·blk) (value slab + rank
-    temporaries, ~8 live (n, blk) f32 buffers).  The budget is HALF the
-    distance kernels' — the coordinate kernels cannot tile the row axis
-    (every rank needs all n comparators), so large n must come out of the
-    column block instead: at n=512 this picks blk=128, ~2 MB of live slab,
-    which lowers without spilling where the old budget's blk=256 doubled it."""
+    temporaries, ~8 live (n, blk) f32 buffers).  The coordinate kernels
+    cannot tile the row axis (every rank needs all n comparators), so large n
+    must come out of the column block instead: at n=512 this picks blk=128,
+    ~2 MB of live slab, which lowers without spilling where twice the budget's
+    blk=256 doubled it.  The Gram distance kernel sizes its blocks by the
+    same count, n being its row tile."""
     return _clamp_block(vmem_budget // max(n * 4 * 8, 1), d)
 
 
@@ -390,24 +382,12 @@ def average_nan_columns(x, block_d=None):
 
 
 # --------------------------------------------------------------------------- #
-# Pairwise squared distances, tiled over row pairs and streamed over column
-# blocks.  The grid is (row tile i, row tile j, column block k) with k
-# innermost, so each (i, j) output tile stays resident in VMEM while its
-# column blocks accumulate — per grid cell only two (T, blk) input tiles and
-# one (T, T) output tile are live, which is what lets n=128..512 lower
-# without spilling (a single-tile grid reproduces the old full-slab kernels
-# bit-for-bit: same per-block accumulation order).
-
-def _dist_diff_kernel(xa_ref, xb_ref, out_ref):
-    @pl.when(pl.program_id(2) == 0)
-    def _():
-        out_ref[:] = jnp.zeros_like(out_ref)
-
-    xa = xa_ref[:].astype(jnp.float32)
-    xb = xb_ref[:].astype(jnp.float32)
-    diff = xa[:, None, :] - xb[None, :, :]
-    out_ref[:] += jnp.sum(diff * diff, axis=-1)
-
+# Pairwise squared distances.  Above ``PAIR_ROWS_MAX`` rows: tiled over row
+# pairs and streamed over column blocks.  The grid is (row tile i, row tile j,
+# column block k) with k innermost, so each (i, j) output tile stays resident
+# in VMEM while its column blocks accumulate — per grid cell only two (T, blk)
+# input tiles and one (T, T) output tile are live, which is what lets
+# n=128..512 lower without spilling.
 
 def _dist_gram_kernel(xa_ref, xb_ref, out_ref):
     # Input is pre-centered by the NaN-ignoring coordinate median (see
@@ -441,7 +421,7 @@ PAIR_UNROLL = 16
 def _pick_block_pairs(rows, d, vmem_budget=1 << 21):
     """Pair-kernel block: the (rows, blk) slab is all that is held (twice: the
     pipeline double-buffers it), so 2 MB buy 16,384 columns at 32 rows where
-    the tiled form's tile x tile x blk tensor buys 1,024 for 4 MB."""
+    a tile x tile x blk tensor of differences would buy 1,024 for 4 MB."""
     return _clamp_block(vmem_budget // (rows * 4), d, PAIR_MAX_BLOCK)
 
 
@@ -497,24 +477,20 @@ def _dist_pairs_kernel(x_ref, out_ref, acc_ref):
         out_ref[:] = jnp.where(col >= row, upper, upper.T)
 
 
-def pairwise_sq_distances(x, block_d=None, use_mxu=None, row_tile=None):
+def pairwise_sq_distances(x, block_d=None):
     """(n, n) all-pairs squared L2 distances of the rows of (n, d).
 
-    ``use_mxu=None`` picks the difference-form (exact) up to n = 64 and the
-    Gram-form (one MXU matmul per tile pair) beyond.  NaN rows yield NaN
-    entries (callers map to +inf), matching the jnp tier.  The difference
-    form of up to ``PAIR_ROWS_MAX`` rows runs as one row tile over the rows
-    as they are (``_dist_pairs_kernel``: no padded copy, one read); with a
-    forced ``row_tile``, more rows or the Gram form, rows are processed in
-    ``row_tile``-sized tiles (default: one tile up to ROW_TILE rows, ROW_TILE
-    beyond) so the VMEM footprint is independent of the worker count.
+    The form follows n.  Up to ``PAIR_ROWS_MAX`` rows the exact difference
+    form runs as one row tile over the rows as they are
+    (``_dist_pairs_kernel``: no padded copy, one read).  Beyond, the Gram
+    form (one MXU matmul per tile pair) takes the rows in tiles of at most
+    ROW_TILE, so the VMEM footprint is independent of the worker count.  NaN
+    rows yield NaN entries (callers map to +inf), matching the jnp tier.
     """
     n, d = x.shape
     rows = n + (-n) % 8  # sublane-padded row count
-    if use_mxu is None:
-        use_mxu = n > 64
     x = x.astype(jnp.float32)
-    if not use_mxu and row_tile is None and rows <= PAIR_ROWS_MAX:
+    if rows <= PAIR_ROWS_MAX:
         blk = block_d or _pick_block_pairs(rows, d)
         xp, whole = _whole_blocks(x, blk, 0.0)  # zero rows are sliced off below
         out = pl.pallas_call(
@@ -531,19 +507,13 @@ def pairwise_sq_distances(x, block_d=None, use_mxu=None, row_tile=None):
             diff = x[:, None, whole:] - x[None, :, whole:]
             out = out + jnp.sum(diff * diff, axis=-1)
         return out
-    tile = row_tile or (rows if rows <= ROW_TILE else ROW_TILE)
-    tile = max(8, tile + (-tile) % 8)
-    if use_mxu:
-        kernel = _dist_gram_kernel
-        blk = block_d or _pick_block_coord(tile, d)
-        # Robust centering outside the kernel (distances are translation-
-        # invariant, one global center suffices): NaN-ignoring coordinate
-        # median, same scheme as gars/common.py centered_gram_sq_distances.
-        center = jnp.nan_to_num(jnp.nanmedian(jnp.where(jnp.isfinite(x), x, jnp.nan), axis=0))
-        x = x - center[None, :]
-    else:
-        kernel = _dist_diff_kernel
-        blk = block_d or _pick_block_diff(tile, d)
+    tile = min(rows, ROW_TILE)
+    blk = block_d or _pick_block_coord(tile, d)
+    # Robust centering outside the kernel (distances are translation-
+    # invariant, one global center suffices): NaN-ignoring coordinate
+    # median, same scheme as gars/common.py centered_gram_sq_distances.
+    center = jnp.nan_to_num(jnp.nanmedian(jnp.where(jnp.isfinite(x), x, jnp.nan), axis=0))
+    x = x - center[None, :]
     xp = _pad_axis(x, 1, blk)
     # Row-pad the worker dim to the tile multiple with zero rows; every
     # real-pair entry is computed rowwise-independently, so padded rows only
@@ -553,7 +523,7 @@ def pairwise_sq_distances(x, block_d=None, use_mxu=None, row_tile=None):
     nt = rows_p // tile
     grid = (nt, nt, xp.shape[1] // blk)
     out = pl.pallas_call(
-        kernel,
+        _dist_gram_kernel,
         grid=grid,
         in_specs=[
             pl.BlockSpec((tile, blk), lambda i, j, k: (i, k), memory_space=pltpu.VMEM),
@@ -564,11 +534,10 @@ def pairwise_sq_distances(x, block_d=None, use_mxu=None, row_tile=None):
         interpret=_interpret(),
         name="pairwise_sq_distances",
     )(xp, xp)
-    out = out[:n, :n]
     # Column padding contributes zero to every distance.  The Gram form can
     # go slightly negative from cancellation — clamp it (NaN passes through
     # jnp.maximum); downstream scoring masks the diagonal itself.
-    return jnp.maximum(out, 0.0) if use_mxu else out
+    return jnp.maximum(out[:n, :n], 0.0)
 
 
 # --------------------------------------------------------------------------- #

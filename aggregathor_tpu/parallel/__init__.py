@@ -27,6 +27,6 @@ construction (graph.py:204-315) and the gRPC/MPI/UDP transports
 """
 
 from .mesh import make_mesh, worker_axis  # noqa: F401
-from .engine import RobustEngine, ShardedRobustEngine  # noqa: F401
+from .engine import RobustEngine  # noqa: F401
 from . import attacks  # noqa: F401
 from . import lossy  # noqa: F401

@@ -2,22 +2,21 @@
 wire").
 
 At production ``n`` and ``d`` the (n, d) submission stack IS the bandwidth
-bill — the reference paid it in full-precision UDP datagrams, and the bf16
-``exchange_dtype`` twin only halves it.  This module generalizes that
-dtype-only twin into a pluggable **wire codec**: every worker's submission
-is ENCODED at the sender (after the worker-local attacks — an attacker
-forges what it transmits), crosses the simulated transport as the encoded
-payload (a dropped packet drops ENCODED bytes), and is DECODED at the
-aggregation boundary so every GAR sees float32 rows.  OptiReduce
+bill — the reference paid it in full-precision UDP datagrams, and a bf16
+wire only halves it.  This module is what the engine's one wire option
+(``exchange=``) means, a wire dtype or a **wire codec**: every worker's
+submission is ENCODED at the sender (after the worker-local attacks — an
+attacker forges what it transmits), crosses the simulated transport as the
+encoded payload (a dropped packet drops ENCODED bytes), and is DECODED at
+the aggregation boundary so every GAR sees float32 rows.  OptiReduce
 (arXiv:2310.06993) motivates the lever: the cloud tail is bandwidth-bound,
 so fewer bytes per row is steps/s, not just a smaller bill.
 
 Codecs (``--exchange`` on the runner; ``parse_exchange_spec`` grammar):
 
 - ``f32``/``float32`` — the uncompressed wire (no codec, no dtype cast).
-- ``bf16``/``bfloat16`` — the historical dtype twin: normalizes onto the
-  engine's ``exchange_dtype`` path (bit-compatible with existing runs,
-  applied at the collective boundary), 2x.
+- ``bf16``/``bfloat16`` — a wire dtype, no codec: the engine's
+  ``exchange_dtype``, a cast at the collective boundary, 2x.
 - ``int8[:ef]`` — per-row symmetric quantization with a traced float32
   scale (``max|row| / 127``): ~3.97x at large d.  A row whose magnitude
   is non-finite cannot encode — its wire image is a NaN row, absorbed by
@@ -81,10 +80,9 @@ def _parse_options(body):
 def parse_exchange_spec(spec):
     """``--exchange`` spec -> ``(exchange_dtype, codec)``.
 
-    Exactly one of the pair is non-None (both None for the f32 wire):
-    ``bf16`` normalizes onto the engine's historical dtype path so
-    existing bf16 runs stay bit-identical; ``int8``/``topk`` return a
-    :class:`WireCodec`.  Accepts an already-constructed codec and passes
+    At most one of the pair is non-None (both None for the f32 wire):
+    ``bf16`` is a dtype cast at the collective boundary, no codec;
+    ``int8``/``topk`` return a :class:`WireCodec`.  Accepts an already-constructed codec and passes
     it through (the test/benchmark surface)."""
     if spec is None:
         return None, None
@@ -364,8 +362,8 @@ def wire_roundtrip(rows, dtype=None, codec=None):
     """THE precision-loss semantics of rows crossing the wire, in one
     place: forged rows are squeezed through the exchange exactly like
     honest ones (an omniscient attacker's matrix still ships as encoded
-    bytes).  ``dtype`` is the engine's ``exchange_dtype`` twin, ``codec``
-    the generalized wire; both None is the f32 wire (identity)."""
+    bytes).  ``dtype`` is the engine's ``exchange_dtype``, ``codec``
+    its codec; both None is the f32 wire (identity)."""
     import jax.numpy as jnp
 
     if codec is not None:

@@ -50,7 +50,6 @@ from ..obs import trace
 from ..obs.profiler import PHASE_PREFIX
 from ..ops.pallas_kernels import LANE, MAX_BLOCK
 from ..utils import UserException, info
-from ..utils.hw import on_tpu
 from .mesh import model_axis, pipe_axis, worker_axis
 
 #: the in-group (within one logical worker's submesh) mesh axes of the
@@ -249,9 +248,9 @@ class RobustEngine:
     """
 
     def __init__(self, mesh, gar, nb_workers=None, nb_real_byz=0, attack=None, lossy_link=None,
-                 exchange_dtype=None, exchange=None, worker_momentum=None, batch_transform=None,
+                 exchange=None, worker_momentum=None, batch_transform=None,
                  worker_metrics=False, reputation_decay=None, quarantine_threshold=0.0,
-                 granularity=None, leaf_bucketing="auto", chaos=None,
+                 granularity=None, chaos=None,
                  health_probe=True, secure=False, flight=None,
                  l1_regularize=None, l2_regularize=None, sharding=None):
         self.mesh = mesh
@@ -372,23 +371,6 @@ class RobustEngine:
         # Captured by the sharded init_state for put_state (checkpoint
         # restore re-sharding).
         self._state_shardings = None
-        # Two numerically-equivalent leaf implementations (identical
-        # selections and PRNG keys; values agree to float tolerance —
-        # vmapped reductions need not lower bit-exactly), dispatched by backend
-        # (measured, BENCHMARKS.md row 6b): stacking same-shaped leaves into
-        # one vmapped rule call per distinct size is the TPU-shaped program
-        # (O(#shapes) collectives/kernels instead of O(#leaves)), but on
-        # XLA:CPU the batched sorts/selects lower WORSE than the plain loop
-        # (ResNet-50: 157 vs 93 s/step on the 1-core host).  "auto" picks
-        # bucketed on TPU, unrolled elsewhere; True/False force it.
-        if leaf_bucketing != "auto":
-            if not isinstance(leaf_bucketing, bool):
-                # 1/0 would pass a tuple-membership check (bool-int equality)
-                # yet miss an `is True` dispatch — normalize strictly instead
-                raise UserException(
-                    "leaf_bucketing must be 'auto' or a bool (got %r)" % (leaf_bucketing,)
-                )
-        self.leaf_bucketing = leaf_bucketing
         # History-aware robustness (Karimireddy et al. 2021): with
         # worker_momentum = beta in (0, 1), every worker sends its momentum
         # m_i <- beta*m_i + (1-beta)*g_i instead of the raw gradient, so the
@@ -402,32 +384,19 @@ class RobustEngine:
         # halves it.  Gradients are quantized ONCE before the reshard and all
         # GAR math runs in f32 on the upcast values, so every device still
         # sees bit-identical inputs (replicated-update determinism holds).
-        # float32 normalizes to None (no quantization path compiled in).
-        dt = jnp.dtype(exchange_dtype) if exchange_dtype else None
-        self.exchange_dtype = None if dt == jnp.float32 else dt
-        # Generalized wire codec (parallel/compress.py, docs/engine.md "The
-        # wire"): ``exchange`` accepts a spec string (int8[:ef] /
-        # topk:... / bf16 / f32) or a WireCodec.  bf16/f32 normalize onto
-        # the dtype twin above (bit-compatible with existing runs);
-        # int8/topk engage the codec in the submission pipeline — encoded
-        # after the worker-local attacks, decoded at the aggregation
-        # boundary so every GAR sees float32 rows.  Feasibility (masked
-        # fixed-point path, sharded mode, topk budget) refuses HERE, which
-        # is also the guardian escalation rebuild path — a ladder rung
-        # that re-builds the stack re-validates the codec.
-        self.codec = None
-        if exchange is not None:
-            from .compress import parse_exchange_spec
+        # ``exchange`` (parallel/compress.py, docs/engine.md "The wire")
+        # accepts a spec string (int8[:ef] / topk:... / bf16 / f32) or a
+        # WireCodec.  bf16 sets the wire dtype, f32 and None leave it None
+        # (no quantization path compiled in); int8/topk engage the codec in
+        # the submission pipeline — encoded after the worker-local attacks,
+        # decoded at the aggregation boundary so every GAR sees float32
+        # rows.  Feasibility (masked fixed-point path, sharded mode, topk
+        # budget) refuses HERE, which is also the guardian escalation
+        # rebuild path — a ladder rung that re-builds the stack
+        # re-validates the codec.
+        from .compress import parse_exchange_spec
 
-            if self.exchange_dtype is not None:
-                raise UserException(
-                    "pass either exchange= (the wire codec spec) or "
-                    "exchange_dtype=, not both — bf16 is spelled "
-                    "exchange='bf16' on the codec surface"
-                )
-            spec_dtype, self.codec = parse_exchange_spec(exchange)
-            if spec_dtype is not None:
-                self.exchange_dtype = spec_dtype
+        self.exchange_dtype, self.codec = parse_exchange_spec(exchange)
         if self.codec is not None:
             if self.sharded:
                 raise UserException(
@@ -748,16 +717,6 @@ class RobustEngine:
         return agg, None, block, raw_block
 
     def _aggregate_per_leaf(self, gvecs, flatmap, key, reputation, ridx=None):
-        """granularity:leaf dispatch — bucketed on TPU, unrolled elsewhere
-        (numerically equivalent; see ``leaf_bucketing`` in __init__)."""
-        bucketed = (
-            self.leaf_bucketing is True
-            or (self.leaf_bucketing == "auto" and on_tpu())
-        )
-        impl = self._aggregate_per_leaf_bucketed if bucketed else self._aggregate_per_leaf_unrolled
-        return impl(gvecs, flatmap, key, reputation, ridx=ridx)
-
-    def _aggregate_per_leaf_bucketed(self, gvecs, flatmap, key, reputation, ridx=None):
         """granularity:leaf — gather and reduce each leaf's (n, d_leaf) rows
         independently (per-layer selection), BUCKETED by leaf size.
 
@@ -767,12 +726,10 @@ class RobustEngine:
         O(#distinct sizes) collectives and selection graphs instead of
         O(#leaves) (the compile-time/step-latency blowup VERDICT r2 flagged;
         same stacking trick as the sharded dataflow's layer axis,
-        ``_make_sharded_body``).  Per-leaf PRNG keys reproduce the unrolled
-        path's exactly (fold_in by ORIGINAL leaf index), so the two paths
-        make the same selections and agree with
-        ``_aggregate_per_leaf_unrolled`` to float tolerance (vmapped
-        reductions are not guaranteed to lower bit-exactly) — asserted by
-        tests/test_engine.py.
+        ``_make_sharded_body``).  Each leaf's PRNG keys fold in its ORIGINAL
+        leaf index, so a leaf's selection does not depend on which leaves
+        share its size; tests/test_engine.py holds one step to the numpy
+        oracle applied leaf by leaf.
 
         Returns ``(agg, participation, wdist, rep_dist)``: the concatenated
         (d,) aggregate (identical on every device), the mean per-leaf
@@ -858,64 +815,6 @@ class RobustEngine:
         if not concat_parts:
             return jnp.zeros((0,), jnp.float32), None, wdist, rep_dist
         agg = jnp.concatenate(concat_parts)[perm]  # back to flattening order
-        participation = (
-            participation_sum / participation_count if participation_count else None
-        )
-        return agg, participation, wdist, rep_dist
-
-    def _aggregate_per_leaf_unrolled(self, gvecs, flatmap, key, reputation, ridx=None):
-        """The plain per-leaf loop (one all_gather + one rule call per
-        leaf).  Semantically the definition of granularity:leaf — and the
-        DEFAULT path off-TPU (``leaf_bucketing="auto"``; measured faster
-        than the batched form on XLA:CPU, BENCHMARKS.md row 6b), CLI-
-        reachable via ``--leaf-bucketing off`` anywhere."""
-        from ..gars import GAR_KEY_TAG
-        from ..gars.common import pairwise_sq_distances
-
-        W = self.nb_devices
-        base_key = jax.random.fold_in(key, GAR_KEY_TAG)
-        agg_parts = []
-        participation_sum = jnp.zeros((self.nb_workers,), jnp.float32)
-        participation_count = 0
-        wdist = jnp.zeros((self.nb_workers,), jnp.float32) if self.worker_metrics else None
-        rep_dist = (
-            jnp.zeros((self.nb_workers,), jnp.float32)
-            if self.reputation_decay is not None else None
-        )
-        for i, (_, offset, size, _, _) in enumerate(flatmap.slices):
-            local = gvecs[:, offset:offset + size]  # static slice
-            if self.exchange_dtype is not None:
-                local = local.astype(self.exchange_dtype)  # wire precision
-            if W > 1:
-                rows = jax.lax.all_gather(local, worker_axis).reshape(self.nb_workers, size)
-            else:
-                rows = local
-            rows = rows.astype(jnp.float32)
-            rows, raw_rows = self._prepare_rows(
-                rows, jax.random.fold_in(key, 20_000 + i), reputation, ridx=ridx
-            )
-            dist2 = (
-                jnp.maximum(pairwise_sq_distances(rows), 0.0)
-                if self.gar.needs_distances else None
-            )
-            leaf_key = jax.random.fold_in(base_key, i)
-            if self.worker_metrics:
-                agg_leaf, part = self.gar.aggregate_block_and_participation(
-                    rows, dist2, axis_name=None, key=leaf_key
-                )
-                if part is not None:
-                    participation_sum = participation_sum + part
-                    participation_count += 1
-            else:
-                agg_leaf = self.gar._call_aggregate(rows, dist2, axis_name=None, key=leaf_key)
-            if wdist is not None:
-                diff = rows - agg_leaf[None, :]
-                wdist = wdist + jnp.sum(diff * diff, axis=1)
-            if rep_dist is not None:
-                rdiff = raw_rows - agg_leaf.astype(jnp.float32)[None, :]
-                rep_dist = rep_dist + jnp.sum(rdiff * rdiff, axis=1)
-            agg_parts.append(agg_leaf.astype(jnp.float32))
-        agg = jnp.concatenate(agg_parts) if agg_parts else jnp.zeros((0,), jnp.float32)
         participation = (
             participation_sum / participation_count if participation_count else None
         )
@@ -2801,29 +2700,6 @@ class RobustEngine:
         )
         jitted = jax.jit(fold, donate_argnums=(0,))
         return trace.traced("bounded_fold.dispatch", jitted, cat="train"), fresh
-
-
-class ShardedRobustEngine(RobustEngine):
-    """Thin compatibility shim: ``RobustEngine(..., sharding="sharded")``
-    under the historical name/signature.  New code should construct
-    :class:`RobustEngine` directly."""
-
-    def __init__(self, mesh, gar, nb_real_byz=0, attack=None, lossy_link=None,
-                 granularity="layer", exchange_dtype=None, worker_momentum=None,
-                 worker_metrics=False, reputation_decay=None,
-                 quarantine_threshold=0.0, l1_regularize=None,
-                 l2_regularize=None, chaos=None, health_probe=True,
-                 nb_workers=None, secure=False, flight=None):
-        super().__init__(
-            mesh, gar, nb_workers=nb_workers, nb_real_byz=nb_real_byz,
-            attack=attack, lossy_link=lossy_link, granularity=granularity,
-            exchange_dtype=exchange_dtype, worker_momentum=worker_momentum,
-            worker_metrics=worker_metrics, reputation_decay=reputation_decay,
-            quarantine_threshold=quarantine_threshold,
-            l1_regularize=l1_regularize, l2_regularize=l2_regularize,
-            chaos=chaos, health_probe=health_probe, secure=secure,
-            flight=flight, sharding="sharded",
-        )
 
 
 # --------------------------------------------------------------------- #

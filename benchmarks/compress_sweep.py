@@ -119,7 +119,6 @@ def build_stack(args, exchange, gar_name, attack, nb_real_byz):
     from aggregathor_tpu import gars, models
     from aggregathor_tpu.core import build_optimizer, build_schedule
     from aggregathor_tpu.parallel import RobustEngine, attacks, make_mesh
-    from aggregathor_tpu.parallel.compress import parse_exchange_spec
 
     n, f = args.nb_workers, args.nb_byz
     exp = models.instantiate("digits", ["batch-size:%d" % args.batch_size])
@@ -127,10 +126,9 @@ def build_stack(args, exchange, gar_name, attack, nb_real_byz):
     tx = build_optimizer("sgd", build_schedule("fixed", ["initial-rate:0.05"]))
     atk = (attacks.instantiate(attack, n, nb_real_byz, ["deviation:10000.0"])
            if attack else None)
-    dtype, codec = parse_exchange_spec(EXCHANGE_SPECS[exchange])
     engine = RobustEngine(
         make_mesh(nb_workers=1), gar, n, attack=atk, nb_real_byz=nb_real_byz,
-        exchange_dtype=dtype, exchange=codec,
+        exchange=EXCHANGE_SPECS[exchange],
     )
     state = engine.init_state(exp.init(jax.random.PRNGKey(0)), tx, seed=1)
     return exp, engine, tx, state

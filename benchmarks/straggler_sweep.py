@@ -96,7 +96,6 @@ def _make_stack(gar_name, exchange, args, attack=None, nb_real_byz=0,
     from aggregathor_tpu import gars, models
     from aggregathor_tpu.core import build_optimizer, build_schedule
     from aggregathor_tpu.parallel import (RobustEngine, attacks, make_mesh)
-    from aggregathor_tpu.parallel import compress
 
     n, f = args.nb_workers, args.nb_byz
     exp = models.instantiate("digits", ["batch-size:%d" % args.batch_size])
@@ -105,10 +104,8 @@ def _make_stack(gar_name, exchange, args, attack=None, nb_real_byz=0,
     atk = (attacks.instantiate(attack, n, nb_real_byz,
                                ["deviation:%g" % deviation])
            if attack else None)
-    dt, codec = compress.parse_exchange_spec(exchange)
     engine = RobustEngine(make_mesh(nb_workers=1), gar, n, attack=atk,
-                          nb_real_byz=nb_real_byz, exchange_dtype=dt,
-                          exchange=codec)
+                          nb_real_byz=nb_real_byz, exchange=exchange)
     state = engine.init_state(exp.init(jax.random.PRNGKey(0)), tx, seed=1)
     return exp, engine, tx, state
 

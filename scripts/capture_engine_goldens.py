@@ -72,14 +72,14 @@ def run_flat(granularity, secure=False, momentum=None, attack_name=None,
 def run_sharded(granularity, l1=None, l2=None, momentum=None, gar_name="krum",
                 f=1, nb_workers=4):
     from aggregathor_tpu.models import transformer as tfm
-    from aggregathor_tpu.parallel import ShardedRobustEngine
 
     cfg = tfm.TransformerConfig(vocab_size=17, d_model=8, n_heads=2, n_layers=2)
     mesh = make_mesh(nb_workers=2, model_parallelism=2)
     gar = gars.instantiate(gar_name, nb_workers, f)
-    eng = ShardedRobustEngine(
+    eng = RobustEngine(
         mesh, gar, nb_workers=nb_workers, granularity=granularity,
         l1_regularize=l1, l2_regularize=l2, worker_momentum=momentum,
+        sharding="sharded",
     )
     tx = optax.sgd(0.05)
     state = eng.init_state(

@@ -148,8 +148,8 @@ def run_check(n, f, dims, rules=RULES, reps=10, nan_workers=2,
                     row["error"] = "%s: %s" % (type(exc).__name__, str(exc)[:400])
                 report(row)
 
-        # Vmapped kernels: the bucketed leaf path calls the rules under
-        # jax.vmap (engine._aggregate_per_leaf_bucketed), which routes every
+        # Vmapped kernels: the per-leaf path calls the rules under
+        # jax.vmap (engine._aggregate_per_leaf), which routes every
         # guarded kernel — coordinate median, averaged-median, trimmed-mean,
         # AND the streamed pairwise distances — through Pallas' batching
         # rule: interpret-mode in the CPU suite, compiled here.

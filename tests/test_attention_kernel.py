@@ -452,9 +452,14 @@ def test_the_chooser_admits_two_widths_by_what_is_resident(monkeypatch, length, 
 
 
 #: sha256 (first 16 hex digits) of the jaxpr of loss-and-gradient of the tiny float32 Laguna and
-#: SDAR experiments below, read at the parent of the PR that gave ``attend`` two widths (PR 40)
-PARENT_JAXPRS = {("laguna", "kernel"): "0e5667dacc0f1d38", ("laguna", "xla"): "9ff6822bddfffb14",
-                 ("sdar", "kernel"): "4f34e6666579e587", ("sdar", "xla"): "1ccb145e6db057f4"}
+#: SDAR experiments below, captured ONCE at PR 48 (the tree on top of commit ee56f59, PR 47), when
+#: ``ops/attention.py`` came down to one ``custom_vjp`` and one way to say a lone head's rows.  The
+#: ``xla`` values are those read at PR 40's parent; the ``kernel`` texts differ from ee56f59's
+#: (0e5667dacc0f1d38, 4f34e6666579e587) in one spelling only: a whole block of the log-sum-exp and
+#: of ``dq`` is read and written as ``[:,:]`` where it was ``[...]`` (9 lines of Laguna's text, 3
+#: of SDAR's; CHANGES.md, PR 48).
+PARENT_JAXPRS = {("laguna", "kernel"): "d7c55fd8440a7e01", ("laguna", "xla"): "9ff6822bddfffb14",
+                 ("sdar", "kernel"): "59a7b47f6f7d47c7", ("sdar", "xla"): "1ccb145e6db057f4"}
 LAGUNA_ARGS = ["vocab:50", "hidden:64", "kv-heads:2", "head-dim:16",
                "layer-types:full,sliding,sliding,sliding,full",
                "mlp-types:dense,sparse,sparse,sparse,sparse", "heads:6,8,8,8,6", "window:12",
@@ -465,11 +470,12 @@ LAGUNA_ARGS = ["vocab:50", "hidden:64", "kv-heads:2", "head-dim:16",
 
 @pytest.mark.parametrize("name,form", sorted(PARENT_JAXPRS))
 def test_equal_widths_trace_the_program_they_traced(name, form):
-    """Laguna's and SDAR's steps are untouched by the two-width route: the
-    jaxpr of each one's loss and gradient, the kernels' bodies included where
-    the kernel is forced, is to the byte what the parent traced (the text
-    carries no file and no line; models/laguna.py's helpers that
-    models/deepseek_v3.py now shares trace to the same equations)."""
+    """Laguna's and SDAR's steps are untouched by the two-width route and by
+    the mask that is data: the jaxpr of each one's loss and gradient, the
+    kernels' bodies included where the kernel is forced, is to the byte what
+    was captured at PR 48 on top of commit ee56f59 (``PARENT_JAXPRS``; the
+    text carries no file and no line; models/laguna.py's helpers that
+    models/deepseek_v3.py shares trace to the same equations)."""
     import hashlib
 
     experiment = models.instantiate(name, LAGUNA_ARGS if name == "laguna" else SDAR_ARGS)

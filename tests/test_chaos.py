@@ -363,12 +363,12 @@ def test_sharded_engine_adam_state_sharded():
     import optax
 
     from aggregathor_tpu.models import transformer as tfm
-    from aggregathor_tpu.parallel import ShardedRobustEngine
+    from aggregathor_tpu.parallel import RobustEngine
 
     cfg = tfm.TransformerConfig(vocab_size=32, d_model=16, n_heads=2, n_layers=2)
     mesh = make_mesh(nb_workers=2, model_parallelism=2, pipeline_parallelism=2)
     tx = optax.adam(1e-3)
-    engine = ShardedRobustEngine(mesh, gars.instantiate("median", 2, 0))
+    engine = RobustEngine(mesh, gars.instantiate("median", 2, 0), sharding="sharded")
     state = engine.init_state(lambda k: tfm.init_params(cfg, k, n_stages=2),
                               tfm.param_specs(cfg), tx)
     param_shardings = jax.tree_util.tree_leaves(
@@ -397,7 +397,7 @@ def test_sharded_engine_chaos_regimes():
     import optax
 
     from aggregathor_tpu.models import transformer as tfm
-    from aggregathor_tpu.parallel import ShardedRobustEngine
+    from aggregathor_tpu.parallel import RobustEngine
 
     cfg = tfm.TransformerConfig(vocab_size=64, d_model=32, n_heads=2, n_layers=2)
     mesh = make_mesh(nb_workers=2, model_parallelism=2, pipeline_parallelism=2)
@@ -406,8 +406,8 @@ def test_sharded_engine_chaos_regimes():
         "0:calm 2:attack=signflip,scale=5.0 4:straggle=1.0,straggle-mode=stale",
         2, nb_real_byz=1,
     )
-    engine = ShardedRobustEngine(mesh, gars.instantiate("median", 2, 0),
-                                 nb_real_byz=1, chaos=chaos)
+    engine = RobustEngine(mesh, gars.instantiate("median", 2, 0), nb_real_byz=1, chaos=chaos,
+                          sharding="sharded")
     assert engine.carries_gradients
     state = engine.init_state(lambda k: tfm.init_params(cfg, k, n_stages=2),
                               tfm.param_specs(cfg), tx)

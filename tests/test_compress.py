@@ -13,6 +13,7 @@ graftcheck GC005 int8-wire probe, and the checked-in
 
 import json
 import os
+import re
 import sys
 
 import jax
@@ -135,7 +136,7 @@ def test_error_feedback_telescopes(rng):
 
 
 def test_wire_roundtrip_matches_legacy_dtype_cast(rng):
-    """Satellite: the dedup helper owns the exchange-dtype precision-loss
+    """Satellite: the dedup helper owns the wire-dtype precision-loss
     semantics bit-exactly (the three engine call sites it replaced)."""
     rows = jnp.asarray(rng.normal(size=(6, 33)).astype(np.float32))
     legacy = rows.astype(jnp.bfloat16).astype(jnp.float32)
@@ -253,15 +254,28 @@ def test_fused_topk_ef_residual_moves_and_converges():
     assert ef_norms[0] > 0 and len(set(ef_norms)) > 1
 
 
+@pytest.mark.parametrize("spec,dtype", [("bf16", jnp.dtype(jnp.bfloat16)), ("f32", None)])
+def test_a_wire_dtype_is_an_exchange_spec(spec, dtype):
+    """``exchange="bf16"`` / ``"f32"`` are the one way to a wire dtype: no
+    codec, the engine's ``exchange_dtype`` (None compiles no cast in), and
+    under bf16 the rows cast down before the collective and up after it."""
+    exp, engine, tx, step, make_state = build_engine_stack(
+        experiment="digits", experiment_args=("batch-size:8",), gar="median", n=4, f=1,
+        nb_devices=2, cache=False, exchange=spec)
+    assert engine.codec is None and engine.exchange_dtype == dtype
+    batch = engine.shard_batch(next(exp.make_train_iterator(4, seed=1)))
+    text = str(jax.make_jaxpr(step)(make_state(), batch))
+    casts = len(re.findall(r"convert_element_type\[\s*new_dtype=bfloat16", text))
+    # bf16: the rows before the all_to_all and the aggregate before the all_gather, at least
+    assert (casts == 0) if dtype is None else (casts >= 2)
+
+
 def test_codec_feasibility_refusals():
     mesh = make_mesh(nb_workers=1)
     gar = gars.instantiate("krum", 8, 2)
     # sharded engine refuses the codec wire (bf16 dtype stays available)
     with pytest.raises(UserException, match="flat engine"):
         RobustEngine(mesh, gar, 8, sharding="sharded", exchange="int8")
-    # both wire knobs at once is ambiguous
-    with pytest.raises(UserException, match="not both"):
-        RobustEngine(mesh, gar, 8, exchange="int8", exchange_dtype="bfloat16")
     # the masked fixed-point path refuses loudly at construction — which
     # is also the guardian escalation REBUILD path (build_training
     # re-applies enable_masking, then re-constructs the engine)

@@ -153,7 +153,7 @@ def test_bf16_exchange_converges_and_stays_invariant():
         gar = gars.instantiate("krum", 8, 1)
         tx = optax.sgd(0.05)
         engine = RobustEngine(make_mesh(nb_workers=nb_devices), gar, nb_workers=8,
-                              exchange_dtype="bfloat16")
+                              exchange="bf16")
         step = engine.build_step(exp.loss, tx)
         state = engine.init_state(exp.init(jax.random.PRNGKey(42)), tx, seed=1)
         state, losses = run_steps(exp, engine, step, state, 20)
@@ -654,39 +654,65 @@ def test_leaf_granularity_quarantine():
     assert np.all(np.isfinite(flat_params(state)))
 
 
-@pytest.mark.slow
-def test_leaf_bucketed_matches_unrolled():
-    """The bucketed leaf path (stacked same-size leaves, vmapped rule, one
-    all_gather per distinct size) reproduces the unrolled per-leaf loop
-    exactly — same per-leaf fold_in keys, same selection, same metrics —
-    with every order-sensitive feature on (omniscient attack, quarantine,
-    worker metrics, multi-device gather)."""
+def oracle_leaf(rule, rows, f):
+    """(aggregate (d_leaf,), participation (n,)) of ``rule`` on one leaf's (n,
+    d_leaf) rows, by the numpy oracle: each worker's averaging weight, for
+    Bulyan the mean over its rounds (``GAR.worker_participation``)."""
+    from aggregathor_tpu.gars import oracle
+
+    n = rows.shape[0]
+    if rule == "krum":
+        rounds = [np.argsort(oracle.krum_scores(rows, f), kind="stable")[:n - f - 2]]
+    else:
+        rounds = oracle.bulyan_rounds(rows, f)
+    weights = np.zeros((len(rounds), n))
+    for weight, workers in zip(weights, rounds):
+        weight[workers] = 1.0 / len(workers)
+    return getattr(oracle, rule)(rows, f), weights.mean(axis=0)
+
+
+@pytest.mark.parametrize("rule,f", [("krum", 2), ("bulyan", 1)])
+def test_leaf_granularity_is_the_rule_leaf_by_leaf(rule, f):
+    """The definition of granularity:leaf: one step of the per-leaf path (the
+    bucketed, vmapped program a TPU runs) is the rule applied to each leaf's
+    (n, d_leaf) rows alone — here by the numpy oracle, on gradients taken with
+    ``jax.grad`` outside the engine.  Plain SGD, so the update is the
+    aggregate; one worker's batch carries a scale of its loss (its images'
+    would leave the bounded bias gradients where they were) that makes the
+    rule leave it out of every leaf, and any leaf whose selection differed
+    would move that leaf's aggregate and the participation."""
     import optax
 
-    atk = attacks.instantiate("little", 8, 2)
-    outs = {}
-    for impl in ("bucketed", "unrolled"):
-        exp = models.instantiate("mnist", ["batch-size:16"])
-        eng = RobustEngine(
-            make_mesh(nb_workers=4), gars.instantiate("krum", 8, 2), 8,
-            nb_real_byz=2, attack=atk, granularity="leaf", worker_metrics=True,
-            reputation_decay=0.5, quarantine_threshold=0.4,
-            leaf_bucketing=(impl == "bucketed"),  # force both paths on CPU
-        )
-        tx = optax.sgd(0.05)
-        state = eng.init_state(exp.init(jax.random.PRNGKey(7)), tx, seed=5)
-        step = eng.build_step(exp.loss, tx)
-        it = exp.make_train_iterator(8, seed=9)
-        for _ in range(3):
-            state, metrics = step(state, eng.shard_batch(next(it)))
-        outs[impl] = (
-            flat_params(state),
-            np.asarray(jax.device_get(metrics["worker_sq_dist"])),
-            np.asarray(jax.device_get(metrics["worker_participation"])),
-            np.asarray(jax.device_get(metrics["worker_reputation"])),
-        )
-    for a, b in zip(outs["bucketed"], outs["unrolled"]):
-        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
+    n, outlier, rate = 8, 5, 0.5
+    exp = models.instantiate("mnist", ["batch-size:16"])
+    batch = dict(next(exp.make_train_iterator(n, seed=4)), scale=np.ones((n, 1), np.float32))
+    batch["scale"][outlier] = 40.0
+
+    def loss(params, batch):
+        return exp.loss(params, batch) * batch["scale"][0]
+
+    params = exp.init(jax.random.PRNGKey(11))
+    grads = jax.vmap(jax.grad(loss), in_axes=(None, 0))(params, batch)
+
+    engine = RobustEngine(make_mesh(nb_workers=4), gars.instantiate(rule, n, f), n,
+                          granularity="leaf", worker_metrics=True)
+    tx = optax.sgd(rate)
+    state = engine.init_state(jax.tree.map(np.asarray, params), tx)
+    state, metrics = engine.build_step(loss, tx)(state, engine.shard_batch(batch))
+
+    participations = []
+    for before, after, leaf in zip(*map(jax.tree.leaves, (params, state.params, grads))):
+        rows = np.asarray(leaf, np.float64).reshape(n, -1)
+        aggregate, participation = oracle_leaf(rule, rows, f)
+        assert participation[outlier] == 0.0
+        participations.append(participation)
+        np.testing.assert_allclose(
+            (np.asarray(before) - np.asarray(after)).ravel() / rate, aggregate,
+            rtol=1e-4, atol=1e-6 * np.abs(rows).max())
+    # the leaves do not all keep the same workers: a whole-vector selection would not pass
+    assert len({tuple(p > 0) for p in participations}) > 1
+    np.testing.assert_allclose(np.asarray(metrics["worker_participation"]),
+                               np.mean(participations, axis=0), rtol=1e-5, atol=1e-7)
 
 
 def test_sampled_multi_step_trains_and_is_mesh_invariant():

@@ -15,7 +15,7 @@ from aggregathor_tpu.core import build_optimizer, build_schedule
 from aggregathor_tpu.gars import oracle, parse_spec, scaling
 from aggregathor_tpu.models import transformer as tfm
 from aggregathor_tpu.ops import pallas_kernels as pk
-from aggregathor_tpu.parallel import RobustEngine, ShardedRobustEngine, make_mesh
+from aggregathor_tpu.parallel import RobustEngine, make_mesh
 from aggregathor_tpu.utils import UserException
 
 
@@ -207,39 +207,32 @@ def test_bucketing_exact_division_unchanged(rng):
 # Row-tiled distance kernels (interpret mode on CPU, same body as TPU)
 
 
-@pytest.mark.parametrize("use_mxu", [False, True])
-def test_pairwise_distances_row_tiled_matches_oracle(rng, use_mxu):
-    """n > ROW_TILE exercises the (i, j, k) grid; a small forced row_tile
-    makes n=48 cross several tiles cheaply in interpret mode."""
-    g = make_grads(rng, 48, d=160)
-    out = np.asarray(pk.pairwise_sq_distances(
-        g, block_d=128, use_mxu=use_mxu, row_tile=16))
+def test_pairwise_distances_over_two_tiles_match_the_oracle(rng):
+    """n > ROW_TILE exercises the (i, j, k) grid: 136 rows are two row tiles
+    of the Gram form."""
+    g = make_grads(rng, 136, d=160)
+    out = np.asarray(pk.pairwise_sq_distances(g, block_d=128))
     ref = oracle._pairwise_sq_distances(g.astype(np.float64))
     np.testing.assert_allclose(out, ref, rtol=2e-4, atol=1e-3)
 
 
-def test_pairwise_distances_row_tiled_nan_rows(rng):
-    g = make_grads(rng, 40, d=128)
+def test_a_nan_row_over_two_tiles_spoils_only_itself(rng):
+    """A NaN row spoils its own row and column in every row tile it meets, and
+    nothing else (the Gram form centers on a NaN-ignoring median)."""
+    g = make_grads(rng, 136, d=128)
     g[7] = np.nan
-    out = np.asarray(pk.pairwise_sq_distances(g, use_mxu=False, row_tile=8))
+    out = np.asarray(pk.pairwise_sq_distances(g))
     assert np.all(np.isnan(out[7, :])) and np.all(np.isnan(out[:, 7]))
-    mask = np.ones(40, bool)
+    mask = np.ones(136, bool)
     mask[7] = False
     assert np.all(np.isfinite(out[np.ix_(mask, mask)]))
 
 
 @pytest.mark.parametrize("n,d", [(32, 256), (8, 129), (13, 1000), (32, 3 * 256 - 7), (64, 256 + 1)])
-def test_pairwise_distances_tile_invariance(rng, n, d):
-    """The tiling is a pure blocking choice: tiled == single-tile to float
-    tolerance, both MXU and diff forms — and in the diff form the single
-    tile is the pair kernel on the rows as they are, the tiled one the
-    (i, j, k) grid on rows padded to whole blocks, at ragged widths too."""
+def test_pairwise_distances_match_the_oracle_at_ragged_widths(rng, n, d):
+    """The pair kernel on the rows as they are, at widths that are whole
+    blocks and at ragged ones."""
     g = make_grads(rng, n, d=d)
-    for use_mxu in (False, True):
-        one = np.asarray(pk.pairwise_sq_distances(g, use_mxu=use_mxu, block_d=256))
-        tiled = np.asarray(pk.pairwise_sq_distances(g, use_mxu=use_mxu, block_d=256, row_tile=8))
-        # the Gram form's cancellation leaves ~1e-4 where a distance is 0
-        np.testing.assert_allclose(tiled, one, rtol=1e-5, atol=1e-3 if use_mxu else 1e-4)
     ref = oracle._pairwise_sq_distances(g.astype(np.float64))
     np.testing.assert_allclose(pk.pairwise_sq_distances(g, block_d=256), ref, rtol=1e-5, atol=1e-4)
 
@@ -338,7 +331,7 @@ def test_sharded_engine_n128_zero_recompiles(rng):
     submesh): compiles once, loss finite, probe worker flags sized (n,)."""
     mesh = make_mesh(nb_workers=2)
     gar = gars.instantiate("hier:g=16,inner=median,outer=krum", 128, 4)
-    eng = ShardedRobustEngine(mesh, gar, nb_workers=128, granularity="layer")
+    eng = RobustEngine(mesh, gar, nb_workers=128, granularity="layer", sharding="sharded")
     assert eng.workers_per_device == 64
     tx = optax.sgd(0.05)
     state = eng.init_state(
@@ -359,7 +352,7 @@ def test_sharded_engine_k_per_slot_matches_manual_sgd(rng):
     preserving, not just shape-compatible."""
     mesh = make_mesh(nb_workers=2)
     gar = gars.instantiate("average", 4, 0)
-    eng = ShardedRobustEngine(mesh, gar, nb_workers=4, granularity="layer")
+    eng = RobustEngine(mesh, gar, nb_workers=4, granularity="layer", sharding="sharded")
     tx = optax.sgd(0.1)
     state = eng.init_state(
         lambda k: tfm.init_params(TINY_CFG, k, n_stages=1),
@@ -393,7 +386,7 @@ def test_sharded_engine_rejects_indivisible_workers():
     mesh = make_mesh(nb_workers=2)
     gar = gars.instantiate("median", 3, 1)
     with pytest.raises(UserException):
-        ShardedRobustEngine(mesh, gar, nb_workers=3, granularity="layer")
+        RobustEngine(mesh, gar, nb_workers=3, granularity="layer", sharding="sharded")
 
 
 # --------------------------------------------------------------------------- #
@@ -412,7 +405,7 @@ def test_flat_engine_gar_probe_runs_and_is_deterministic():
 def test_sharded_engine_gar_probe_runs(rng):
     mesh = make_mesh(nb_workers=2)
     gar = gars.instantiate("krum", 8, 1)
-    eng = ShardedRobustEngine(mesh, gar, nb_workers=8, granularity="layer")
+    eng = RobustEngine(mesh, gar, nb_workers=8, granularity="layer", sharding="sharded")
     out = np.asarray(jax.block_until_ready(eng.build_gar_probe(d=64)(0)))
     assert out.shape == (64,) and np.all(np.isfinite(out))
 

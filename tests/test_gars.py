@@ -365,13 +365,13 @@ def test_global_granularity_rejected_for_iterative_rules():
     import pytest
 
     from aggregathor_tpu.parallel.mesh import make_mesh
-    from aggregathor_tpu.parallel import ShardedRobustEngine
+    from aggregathor_tpu.parallel import RobustEngine
     from aggregathor_tpu.utils import UserException
 
     mesh = make_mesh(nb_workers=2, model_parallelism=2, pipeline_parallelism=2)
     for rule in ("geometric-median", "bucketing"):
         with pytest.raises(UserException):
-            ShardedRobustEngine(mesh, gars.instantiate(rule, 2, 0), granularity="global")
+            RobustEngine(mesh, gars.instantiate(rule, 2, 0), granularity="global", sharding="sharded")
 
 
 def test_dnc_drops_colluders_and_reports_participation(rng):

@@ -265,7 +265,7 @@ ROWS_CASES = {
         lossy_link=lossy.LossyLink(4, ["drop-rate:0.2", "packet-coords:16"])), "lossy_link"),
     "chaos": ("median", 4, 1, lambda: dict(chaos=ChaosSchedule("0:calm 5:drop=0.5", 4)), "chaos"),
     "codec": ("median", 4, 1, lambda: dict(exchange="int8"), "exchange"),
-    "exchange_dtype": ("median", 4, 1, lambda: dict(exchange_dtype="bfloat16"), "exchange"),
+    "exchange_dtype": ("median", 4, 1, lambda: dict(exchange="bf16"), "exchange"),  # no codec
     "momentum": ("median", 4, 1, lambda: dict(worker_momentum=0.9), "worker_momentum"),
     "carry": ("median", 4, 1, lambda: dict(
         lossy_link=lossy.LossyLink(4, ["drop-rate:0.2", "packet-coords:16", "clever:true"])),
@@ -330,8 +330,7 @@ ARGUMENTS = {
     "gar": ("rows", ["krum", "bulyan", "geometric-median", "bucketing"]),
     "attack": ("rows", ["attack"]),
     "lossy_link": ("rows", ["lossy_link", "carry"]),
-    "exchange_dtype": ("rows", ["exchange_dtype"]),
-    "exchange": ("rows", ["codec", "error_feedback"]),
+    "exchange": ("rows", ["codec", "error_feedback", "exchange_dtype"]),
     "worker_momentum": ("rows", ["momentum"]),
     "worker_metrics": ("rows", ["worker_metrics"]),
     "reputation_decay": ("rows", ["reputation"]),
@@ -345,7 +344,6 @@ ARGUMENTS = {
     "batch_transform": ("in place", "applied a worker under the rows path's keys (IN_PLACE_CASES)"),
     "health_probe": ("in place", "worker_nan read off the leaves (IN_PLACE_CASES, the parity tests)"),
     "flight": ("in place", "written by the shared _finalize_step"),
-    "leaf_bucketing": ("in place", "read under granularity:leaf only, which needs the rows"),
     "l1_regularize": ("in place", "refused by a flat engine"),
     "l2_regularize": ("in place", "refused by a flat engine"),
 }

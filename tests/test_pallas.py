@@ -108,13 +108,13 @@ def test_average_nan_columns(case):
     np.testing.assert_allclose(out, oracle.average_nan(g), rtol=1e-5, atol=1e-6)
 
 
-@pytest.mark.parametrize("use_mxu", [False, True])
-def test_pairwise_distances(use_mxu):
-    g = _rand(12, 500, 7)
-    out = np.array(pk.pairwise_sq_distances(g, block_d=128, use_mxu=use_mxu))
+@pytest.mark.parametrize("n", [12, 72], ids=["pairs", "gram"])
+def test_pairwise_distances(n):
+    g = _rand(n, 500, 7)
+    out = np.array(pk.pairwise_sq_distances(g, block_d=128))
     ref = oracle._pairwise_sq_distances(g.astype(np.float64))
     np.fill_diagonal(out, 0.0)  # oracle pins the diagonal; kernels leave ~0
-    tol = 1e-4 if use_mxu else 1e-5
+    tol = 1e-4 if n > pk.PAIR_ROWS_MAX else 1e-5
     np.testing.assert_allclose(out, ref, rtol=tol, atol=tol)
 
 
@@ -133,7 +133,7 @@ def test_pairwise_distances_nan_row(n, d, block_d, poison):
         g[3] = np.inf
     else:
         g[3] = 1e30 * np.where(np.arange(d) % 2, 1.0, -1.0)  # squares overflow float32
-    out = np.asarray(pk.pairwise_sq_distances(g, block_d=block_d, use_mxu=False))
+    out = np.asarray(pk.pairwise_sq_distances(g, block_d=block_d))
     honest = np.arange(n) != 3
     assert not np.any(np.isfinite(out[3, honest])) and not np.any(np.isfinite(out[honest, 3]))
     if poison.startswith("nan"):
@@ -180,13 +180,14 @@ def test_majority_nan_column_tiers_agree():
 
 
 def test_gram_distance_nan_poisons_only_its_rows():
-    """Majority-NaN column must not poison the whole Gram distance matrix."""
-    g = _rand(12, 64, 6)
-    g[0:7, 10] = np.nan
-    out = np.array(pk.pairwise_sq_distances(g, block_d=128, use_mxu=True))
-    clean = np.ix_(range(7, 12), range(7, 12))
+    """Majority-NaN column must not poison the whole Gram distance matrix
+    (more than ``PAIR_ROWS_MAX`` rows: the form that centers on a median)."""
+    g = _rand(72, 64, 6)
+    g[0:37, 10] = np.nan
+    out = np.array(pk.pairwise_sq_distances(g, block_d=128))
+    clean = np.ix_(range(37, 72), range(37, 72))
     assert np.all(np.isfinite(out[clean]))
-    assert np.all(np.isnan(out[0, 7:]))
+    assert np.all(np.isnan(out[0, 37:]))
 
 
 def test_pallas_krum_rejects_outlier():
@@ -253,7 +254,8 @@ def test_kernel_name_says_the_form(n, form):
 # (n, d, block_d): the three boundary widths at n=6, then widths that are a
 # multiple neither of the block nor of the lane — blk + 1 and 3·blk − 7 at
 # blocks wide enough to run the pair kernel's chunk loop, and one at the
-# block the wrapper picks itself — at n = 8, 13, 32, 64.
+# block the wrapper picks itself — at n = 8, 13, 32, 64.  The Gram form
+# takes the same widths at 72 rows (one row tile) and 136 (two).
 DISTANCE_WIDTHS = [
     (6, 128, 128), (6, 129, 128), (6, 256, 128),
     (8, 129, 128), (13, 1000, 128), (32, 1000, 256), (64, 3 * 128 - 7, 128),
@@ -261,18 +263,35 @@ DISTANCE_WIDTHS = [
 ]
 
 
-@pytest.mark.parametrize("use_mxu", [False, True])
+@pytest.mark.parametrize("gram", [False, True], ids=["pairs", "gram"])
 @pytest.mark.parametrize("n,d,block_d", DISTANCE_WIDTHS)
-def test_pairwise_distances_at_tile_boundaries(use_mxu, n, d, block_d):
+def test_pairwise_distances_at_tile_boundaries(gram, n, d, block_d):
+    if gram:
+        n = 72 if n < 32 else 136
     g = _rand(n, d, 14)
-    out = np.array(pk.pairwise_sq_distances(g, block_d=block_d, use_mxu=use_mxu))
+    out = np.array(pk.pairwise_sq_distances(g, block_d=block_d))
     ref = oracle._pairwise_sq_distances(g.astype(np.float64))
-    if not use_mxu:  # exact: each pair once, mirrored; a row against itself is 0
+    if not gram:  # exact: each pair once, mirrored; a row against itself is 0
         np.testing.assert_array_equal(out, out.T)
         np.testing.assert_array_equal(np.diag(out), 0.0)
     np.fill_diagonal(out, 0.0)  # oracle pins the diagonal; the Gram form leaves ~0
-    tol = 1e-4 if use_mxu else 1e-5
+    tol = 1e-4 if gram else 1e-5
     np.testing.assert_allclose(out, ref, rtol=tol, atol=tol * np.sqrt(d / 128))
+
+
+@pytest.mark.parametrize("n,grid_rank", [(8, 1), (64, 1), (65, 3), (136, 3)])
+def test_the_distance_form_follows_the_row_count(n, grid_rank):
+    """Up to ``PAIR_ROWS_MAX`` rows the pair kernel, a grid over column blocks;
+    above it the Gram form, a grid over (row tile, row tile, column block), of
+    ``ROW_TILE`` rows a tile once there are more: nothing but n chooses."""
+    import jax
+
+    jaxpr = jax.make_jaxpr(pk.pairwise_sq_distances)(np.zeros((n, 300), np.float32)).jaxpr
+    [call] = [eqn for eqn in jaxpr.eqns if eqn.primitive.name == "pallas_call"]
+    grid = call.params["grid_mapping"].grid
+    assert len(grid) == grid_rank
+    if grid_rank == 3:
+        assert grid[:2] == ((1, 1) if n <= pk.ROW_TILE else (2, 2))
 
 
 def test_pallas_krum_excludes_fully_nan_row_like_jnp():

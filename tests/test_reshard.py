@@ -28,11 +28,11 @@ from aggregathor_tpu.parallel import RobustEngine, make_mesh
 from aggregathor_tpu.parallel.mesh import worker_axis
 
 
-def engine_over(devices, k, exchange_dtype=None):
+def engine_over(devices, k, exchange=None):
     nb_workers = len(devices) * k
     return RobustEngine(make_mesh(nb_workers=len(devices), devices=devices),
                         gars.instantiate("average", nb_workers, 0), nb_workers,
-                        exchange_dtype=exchange_dtype)
+                        exchange=exchange)
 
 
 def cut_and_gather(engine, d):
@@ -49,7 +49,7 @@ def cut_and_gather(engine, d):
         out_specs=(P(None, worker_axis), P()), check_vma=False))
 
 
-@pytest.mark.parametrize("d,W,k,exchange_dtype", [
+@pytest.mark.parametrize("d,W,k,exchange", [
     (96, 8, 4, None),       # d divides, ceil(d / W) = 12, under one lane tile
     (100, 8, 1, None),      # the 100-parameter model on 8 devices: 13 -> 128, not 1,024
     (300, 2, 1, None),      # 150 -> 256
@@ -60,14 +60,14 @@ def cut_and_gather(engine, d):
     (5160, 4, 8, None),     # ResNet-50 on four chips scaled down: 10 x 128 + 10 -> 2,048
     (5157, 4, 8, None),     # the same block, d does not divide
     (8200, 8, 4, None),     # 1,025 -> 2,048 on 8 devices
-    (5157, 4, 8, "bfloat16"),  # (16, 128) tiles on the wire: the cut must stay right
-    (100, 8, 4, "bfloat16"),
+    (5157, 4, 8, "bf16"),  # (16, 128) tiles on the wire: the cut must stay right
+    (100, 8, 4, "bf16"),
     (100, 1, 8, None),      # one device: the identity
     (5160, 1, 4, None),
-    (1290, 1, 1, "bfloat16"),
+    (1290, 1, 1, "bf16"),
 ])
-def test_block_cut(d, W, k, exchange_dtype):
-    engine = engine_over(jax.devices()[:W], k, exchange_dtype)
+def test_block_cut(d, W, k, exchange):
+    engine = engine_over(jax.devices()[:W], k, exchange)
     n, blk = W * k, engine._block_width(d)
     # whole numbers under 256: exact in bfloat16, and no two columns of a row
     # nor two rows of a column alike where it matters (251 and 241 are prime)

@@ -14,7 +14,7 @@ from jax.sharding import PartitionSpec as P
 
 from aggregathor_tpu import config, gars
 from aggregathor_tpu.models import transformer as tfm
-from aggregathor_tpu.parallel import ShardedRobustEngine
+from aggregathor_tpu.parallel import RobustEngine
 from aggregathor_tpu.parallel.mesh import factor_devices, make_mesh
 
 CFG = tfm.TransformerConfig(vocab_size=17, d_model=16, n_heads=2, n_layers=4)
@@ -94,7 +94,7 @@ def test_sharded_engine_average_matches_manual_sgd(rng):
     w, pp, tp = 2, 2, 2
     mesh = make_mesh(nb_workers=w, model_parallelism=tp, pipeline_parallelism=pp)
     gar = gars.instantiate("average", w, 0)
-    eng = ShardedRobustEngine(mesh, gar, granularity="global")
+    eng = RobustEngine(mesh, gar, granularity="global", sharding="sharded")
     lr = 0.1
     tx = optax.sgd(lr)
     state = eng.init_state(lambda k: tfm.init_params(CFG, k, n_stages=pp), tfm.param_specs(CFG), tx)
@@ -135,7 +135,7 @@ def test_sharded_engine_l1_l2_regularization_exact(rng):
     batch = _batch(rng, w)
 
     def run_engine(**reg):
-        eng = ShardedRobustEngine(mesh, gar, granularity="global", **reg)
+        eng = RobustEngine(mesh, gar, granularity="global", sharding="sharded", **reg)
         state = eng.init_state(
             lambda k: tfm.init_params(CFG, k, n_stages=pp), tfm.param_specs(CFG), tx
         )
@@ -195,7 +195,7 @@ def test_sharded_engine_multi_step_matches_per_step(rng):
             lambda k: tfm.init_params(CFG, k, n_stages=pp), tfm.param_specs(CFG), tx
         )
 
-    eng = ShardedRobustEngine(mesh, gar, granularity="layer")
+    eng = RobustEngine(mesh, gar, granularity="layer", sharding="sharded")
     state = fresh_state(eng)
     step = eng.build_step(loss_fn, tx, state)
     losses = []
@@ -231,8 +231,9 @@ def test_per_layer_krum_under_attack_converges(rng, granularity):
     w, pp, tp = 4, 2, 1
     mesh = make_mesh(nb_workers=w, model_parallelism=tp, pipeline_parallelism=pp)
     gar = gars.instantiate("krum", w, 1)
-    eng = ShardedRobustEngine(
-        mesh, gar, nb_real_byz=1, attack=make_attack("signflip", w, 1), granularity=granularity
+    eng = RobustEngine(
+        mesh, gar, nb_real_byz=1, attack=make_attack("signflip", w, 1), granularity=granularity,
+        sharding="sharded",
     )
     tx = optax.sgd(0.05)
     state = eng.init_state(lambda k: tfm.init_params(CFG, k, n_stages=pp), tfm.param_specs(CFG), tx)
@@ -272,7 +273,7 @@ def test_sharded_engine_bf16_exchange_converges():
 
     exp, eng, tx, step, make_state = build_engine_stack(
         mode="sharded", experiment="digits", experiment_args=("batch-size:8",),
-        gar="median", n=4, f=1, nb_devices=2, exchange_dtype="bfloat16")
+        gar="median", n=4, f=1, nb_devices=2, exchange="bf16")
     state = make_state()
     it = exp.make_train_iterator(4, seed=5)
     losses = []
@@ -364,7 +365,7 @@ def test_sharded_engine_uses_axis_rules_exact_across_tp(rng):
         for tp in (1, 2):
             mesh = make_mesh(nb_workers=2, model_parallelism=tp, pipeline_parallelism=1)
             gar = gars.instantiate(rule, 2, 0)
-            eng = ShardedRobustEngine(mesh, gar, granularity="layer")
+            eng = RobustEngine(mesh, gar, granularity="layer", sharding="sharded")
             tx = optax.sgd(0.05)
             state = eng.init_state(
                 lambda k: tfm.init_params(CFG, k, n_stages=1), tfm.param_specs(CFG), tx
@@ -392,10 +393,11 @@ def test_sharded_engine_worker_metrics(rng):
         w = 4
         mesh = make_mesh(nb_workers=w, model_parallelism=tp, pipeline_parallelism=pp)
         gar = gars.instantiate("krum", w, 1)
-        eng = ShardedRobustEngine(
+        eng = RobustEngine(
             mesh, gar, nb_real_byz=1,
             attack=make_attack("gaussian", w, 1, ["deviation:100"]),
             granularity="layer", worker_metrics=True,
+            sharding="sharded",
         )
         tx = optax.sgd(0.05)
         state = eng.init_state(lambda k: tfm.init_params(CFG, k, n_stages=pp), tfm.param_specs(CFG), tx)
@@ -419,11 +421,12 @@ def test_sharded_engine_reputation_quarantine(rng):
 
     w, pp, tp = 4, 2, 1
     mesh = make_mesh(nb_workers=w, model_parallelism=tp, pipeline_parallelism=pp)
-    eng = ShardedRobustEngine(
+    eng = RobustEngine(
         mesh, gars.instantiate("krum", w, 1), nb_real_byz=1,
         attack=make_attack("gaussian", w, 1, ["deviation:100"]),
         granularity="layer", worker_metrics=True,
         reputation_decay=0.5, quarantine_threshold=0.4,
+        sharding="sharded",
     )
     tx = optax.sgd(0.05)
     state = eng.init_state(lambda k: tfm.init_params(CFG, k, n_stages=pp), tfm.param_specs(CFG), tx)
