@@ -435,8 +435,10 @@ def run_select_check(reps=3, workers=3, chunk=512, length=8192, topk=2048, numbe
 
 #: ``--columns leaf``: (workers, a worker's leaf) — the grid's largest leaves:
 #: cell 5's held experts, cells 5 and 8's head (18,992 is 148 whole lanes and
-#: 48 columns), cell 7's ``wkv_a`` (576: 4 whole lanes and 64)
-LEAF_SHAPES = ((4, (4, 8, 768, 2048)), (3, (2048, 18992)), (3, (4, 2048, 576)))
+#: 48 columns), cell 7's ``wkv_a`` (576: 4 whole lanes and 64), cell 9's fused
+#: ``w_qkvz`` (the widest leaf a layer) and its ``w_ba`` (64 lanes: its rows as lanes)
+LEAF_SHAPES = ((4, (4, 8, 768, 2048)), (3, (2048, 18992)), (3, (4, 2048, 576)),
+               (3, (3, 2048, 12288)), (3, (3, 2048, 64)))
 
 
 def run_leaf_check(reps=5, shapes=LEAF_SHAPES, allow_interpret=False, emit=_print_row):
