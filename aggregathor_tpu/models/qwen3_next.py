@@ -44,8 +44,14 @@ updates are the solution of one unit lower-triangular system
 block by block in log2(chunk) rounds of matrix products), every product of a
 chunk is made for all chunks at once, and ONE state a head is carried from
 chunk to chunk by a ``lax.scan`` (three products a chunk: what the state
-already predicts, what it answers the queries, its update) — plain XLA, the
-yardstick a later kernel is held to (``model.delta_rule``).  Consecutive layers
+already predicts, what it answers the queries, its update).  That form is
+plain XLA: the CPU's path, every shape's fallback and the oracle the kernel is
+held to.  ON A TPU, where ``ops.delta_rule.delta_rule_form`` takes the shape
+(chunks of 64, heads of whole lanes, a length of whole tiles of chunks: the
+grid's), the same equations run as ops/delta_rule.py's Pallas kernel pair — the
+state a head in VMEM across a sequence's chunks, a chunk's matrices never in
+HBM, a backward kernel of its own — through the one entry ``delta_rule``
+(``model.delta_rule``).  Consecutive layers
 of one kind are a run of stacked leaves (models/laguna.py ``layer_runs``:
 scanned where several, each layer under ``jax.checkpoint``), so a period is a
 run of ``full_interval - 1`` DeltaNet layers and a run of one attention layer,
@@ -63,6 +69,7 @@ import jax.numpy as jnp
 
 from . import Experiment, register
 from ..utils import UserException, parse_keyval
+from ..ops.delta_rule import gated_delta_rule
 from .common import check_dtype
 from .laguna import (LagunaExperiment, causal_attention, gated_unit, layer_runs, next_token_loss,
                      seeded_corpus, seeded_leaves)
@@ -277,6 +284,13 @@ def chunked_delta_rule(q, k, v, g, beta, chunk):
     return out[:, :length], state
 
 
+def delta_rule(q, k, v, g, beta, chunk):
+    """``chunked_delta_rule``'s contract through ops/delta_rule.py's chooser: its
+    kernel pair on a TPU for the shapes it takes, ``chunked_delta_rule``
+    everywhere else."""
+    return gated_delta_rule(q, k, v, g, beta, chunk, chunked_delta_rule)
+
+
 def causal_conv(x, taps):
     """Depthwise causal convolution: x (B, L, C), taps (C, K) -> (B, L, C),
     ``y_t = sum_j taps[:, j] * x_{t - (K - 1) + j}`` with zeros before the
@@ -318,7 +332,7 @@ def gated_delta_net(u, layer, cfg):
     with jax.named_scope("model.gdn_project"):
         q, k, v, z, g, beta = delta_heads(u, layer, cfg)
     with jax.named_scope("model.delta_rule"):
-        out, state = chunked_delta_rule(q, k, v, g, beta, cfg.chunk)
+        out, state = delta_rule(q, k, v, g, beta, cfg.chunk)
     with jax.named_scope("model.gdn_project"):
         out = rms_norm(out.astype(cfg.dtype), layer["o_norm"].astype(cfg.dtype), cfg.norm_eps)
         out = (out * jax.nn.silu(z)).reshape(b, length, -1) @ layer["wo"].astype(cfg.dtype)

@@ -10,4 +10,9 @@
 - ``ops.attention`` — masked attention as one fused Pallas kernel, forward and
   backward, with its chooser (the kernel on a TPU for shapes it takes, the
   caller's XLA form elsewhere); models/laguna.py's attention calls it.
+- ``ops.select`` — the ``topk`` keys of largest score a query chosen by
+  counting, one Pallas kernel; models/keye_vl2.py's ``top_keys`` calls it.
+- ``ops.delta_rule`` — the chunked gated delta rule as a Pallas kernel pair,
+  forward and backward behind one ``custom_vjp``, the state a head in VMEM
+  across a sequence's chunks; models/qwen3_next.py's ``delta_rule`` calls it.
 """

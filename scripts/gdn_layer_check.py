@@ -1,7 +1,8 @@
 """On-chip, element by element: ONE layer of each kind of models/qwen3_next.py
 at the published widths and L = 4096 against the plain reference
-(grid/references/qwen3_next.py): the chunked gated delta rule against the
-recurrence walked token by token, the gated full layer (ops/attention.py's
+(grid/references/qwen3_next.py): the chunked gated delta rule (on a TPU
+ops/delta_rule.py's kernel pair, through the entry ``gated_delta_net`` calls)
+against the recurrence walked token by token, the gated full layer (ops/attention.py's
 kernel at heads of 256 lanes where ``attention_form`` takes them) against one
 dense softmax.
 
@@ -9,8 +10,8 @@ The grid's ``correct`` holds losses and NORMS (grid/check.py).  This script
 holds each layer's OUTPUT and the GRADIENTS of a seeded scalar of it
 (``sum(out * w)``, w seeded) with respect to x and every leaf, each as the
 largest difference over the reference's largest entry, beside its tolerance;
-for the DeltaNet layer also the delta rule's core alone (``core``: o of
-``chunked_delta_rule`` against the recurrence on the same q, k, v, g, beta).
+for the DeltaNet layer also the delta rule's core alone (``core``: o of the
+entry ``delta_rule`` against the recurrence on the same q, k, v, g, beta).
 Both at the precision the step runs (``default``: a float32 product multiplies
 in one bfloat16 pass on the chip) and at ``highest`` (the program's own
 equations in the reference's arithmetic: what is left is the order of float32
@@ -82,8 +83,8 @@ def planted(fault):
 
     from aggregathor_tpu.models import qwen3_next
 
-    sound = {name: getattr(qwen3_next, name)
-             for name in ("delta_heads", "chunked_delta_rule", "attention_heads")}
+    sound = {name: getattr(qwen3_next, name) for name in (
+        "delta_heads", "delta_rule", "chunked_delta_rule", "attention_heads")}
 
     def no_decay(u, layer, cfg):
         q, k, v, z, g, beta = sound["delta_heads"](u, layer, cfg)
@@ -93,19 +94,25 @@ def planted(fault):
         q, k, v, z, g, beta = sound["delta_heads"](u, layer, cfg)
         return q, k, v, z, g, jnp.ones_like(beta)
 
-    def no_carry(q, k, v, g, beta, chunk):
-        """Every chunk a sequence of its own: nothing crosses a boundary."""
-        b, length = q.shape[:2]
-        alone = lambda a: a.reshape((b * length // chunk, chunk) + a.shape[2:])
-        out, state = sound["chunked_delta_rule"](*(alone(a) for a in (q, k, v, g, beta)), chunk)
-        return out.reshape((b, length) + out.shape[2:]), state[length // chunk - 1::length // chunk]
+    def no_carry(whole):
+        """Every chunk a sequence of its own: nothing crosses a boundary,
+        whichever form ``whole`` runs."""
+        def broken(q, k, v, g, beta, chunk):
+            b, length = q.shape[:2]
+            alone = lambda a: a.reshape((b * length // chunk, chunk) + a.shape[2:])
+            out, state = sound[whole](*(alone(a) for a in (q, k, v, g, beta)), chunk)
+            return (out.reshape((b, length) + out.shape[2:]),
+                    state[length // chunk - 1::length // chunk])
+        return broken
 
     def no_gate(u, layer, cfg):
         q, k, v, gate = sound["attention_heads"](u, layer, cfg)
         return q, k, v, jnp.full_like(gate, 30.0)
 
     faults = {None: {}, "no-decay": {"delta_heads": no_decay}, "beta-one": {"delta_heads": beta_one},
-              "no-carry": {"chunked_delta_rule": no_carry}, "no-gate": {"attention_heads": no_gate}}
+              # the entry the layer calls, and the XLA form behind it off a TPU
+              "no-carry": {name: no_carry(name) for name in ("delta_rule", "chunked_delta_rule")},
+              "no-gate": {"attention_heads": no_gate}}
     if fault not in faults:
         raise SystemExit("no fault named %r: %s" % (fault, ", ".join(FAULTS)))
     for name, broken in faults[fault].items():
@@ -161,7 +168,7 @@ def run_check(seed=0, fault=None, tiny=False, emit=print):
             if kind == qwen3_next.DELTA:
                 u = qwen3_next.rms_norm(x, 1 + layer["attn_norm"], cfg.norm_eps)
                 q, k, v, _, g, beta = qwen3_next.delta_heads(u, layer, cfg)
-                core = qwen3_next.chunked_delta_rule(q, k, v, g, beta, cfg.chunk)[0]
+                core = qwen3_next.delta_rule(q, k, v, g, beta, cfg.chunk)[0]
             return jnp.sum(out * weight), jax.lax.stop_gradient((out, core))
 
         def theirs(x, layer, kind=kind):

@@ -48,6 +48,18 @@ and a NaN, and for one worker a row set whose threshold falls among zeros of
 both signs and denormals — ``array_equal`` of the two forms' int8 pairs (nothing
 but time may differ) and each form's least ms of three.
 
+The delta column (``run_delta_check``; ``--columns delta``): ops/delta_rule.py's
+kernel pair against models/qwen3_next.py's ``chunked_delta_rule`` at
+``qwen3next_avgmedian_causal4k``'s shape (three workers vmapped, 1 x 4,096 x 32
+heads of 128 by 128, chunks of 64; q, k, v, g, beta seeded as ``delta_heads``
+leaves them) — o, the last state and the gradients of q, k, v, g and beta, each
+as the largest difference over the oracle's largest entry, at the default
+precision and at ``highest``; beside each the XLA form's OWN difference from the
+recurrence walked token by token (grid/references/qwen3_next.py, ``highest``,
+the first worker), which is the bound: the kernel may stand no further from
+the XLA form than the XLA form stands from the recurrence; and each form alone,
+forward and forward + backward, least ms of ``--attention-reps``.
+
 The leaf column (``run_leaf_check``; ``--columns leaf``): each plane kernel's
 leaf entry (the step's in-place path, parallel/in_place.py) against its 2-D
 entry at the grid's largest leaves — (4, 8, 768, 2048) x 4 workers, (2048,
@@ -433,6 +445,89 @@ def run_select_check(reps=3, workers=3, chunk=512, length=8192, topk=2048, numbe
     return failed
 
 
+def run_delta_check(reps=5, workers=3, length=4096, heads=32, width=128, chunk=64,
+                    allow_interpret=False, emit=_print_row):
+    """Parity and time of the delta rule's kernel pair against the XLA form, one
+    row; ``emit(row)``.  Returns the row if its parity is not ``"ok"``."""
+    import importlib.util
+
+    import jax
+    import jax.numpy as jnp
+
+    from aggregathor_tpu.models import qwen3_next
+    from aggregathor_tpu.ops import delta_rule
+
+    if not allow_interpret and delta_rule._interpret():
+        raise RuntimeError("the delta column needs a TPU backend (the kernels would "
+                           "interpret on %r)" % jax.default_backend())
+    spec = importlib.util.spec_from_file_location("pallas_tpu_check_reference", os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))), "grid", "references",
+        "qwen3_next.py"))
+    reference = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(reference)
+
+    key = jax.random.PRNGKey(19)
+    normal = lambda place, *dims: jax.random.normal(
+        jax.random.fold_in(key, place), (workers, 1, length, heads) + dims, jnp.float32)
+    inputs = (qwen3_next.l2_normalised(normal(0, width)) * width ** -0.5,
+              qwen3_next.l2_normalised(normal(1, width)), normal(2, width),
+              # a head forgets from a fifth of its state to next to nothing a token
+              -jnp.exp(normal(3) - 3.0), jax.nn.sigmoid(normal(4)))
+    weight = normal(5, width)
+
+    def forms(rule, batched=True):
+        forward = jax.vmap(rule) if batched else rule
+        scalar = lambda *args: jnp.sum(forward(*args)[0] * (weight if batched else weight[0]))
+        return jax.jit(forward), jax.jit(jax.value_and_grad(scalar, argnums=(0, 1, 2, 3, 4)))
+
+    def traced(form, precision):
+        """(forward, scalar-and-gradients) of the model's entry, traced inside the seam."""
+        def enter(fn):
+            def call(*args):
+                with delta_rule.forced_form(form), jax.default_matmul_precision(precision):
+                    return fn(*args)
+            return call
+        return [enter(fn) for fn in forms(lambda *args: qwen3_next.delta_rule(*args, chunk))]
+
+    names = ("o", "state", "dq", "dk", "dv", "dg", "dbeta")
+    gap = lambda a, b: float("%.3g" % (jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))))
+    row = {"metric": "pallas_tpu_check", "rule": "delta", "workers": workers, "length": length,
+           "heads": heads, "widths": "%d/%d" % (width, width), "chunk": chunk,
+           "tile_chunks": delta_rule.tile_chunks_for(length, chunk), "quantities": list(names)}
+    try:
+        with jax.default_matmul_precision("highest"):   # the oracle: the first worker, token by token
+            walk, gradients = forms(lambda *args: (reference._recurrence(*args), None),
+                                    batched=False)
+            first = [a[0] for a in inputs]
+            exact = (walk(*first)[0],) + tuple(gradients(*first)[1])
+        within = {}
+        for precision in ("highest", "default"):
+            ours, theirs = traced("kernel", precision), traced("xla", precision)
+            (out_k, state_k), (out_x, state_x) = ours[0](*inputs), theirs[0](*inputs)
+            (_, grads_k), (_, grads_x) = ours[1](*inputs), theirs[1](*inputs)
+            all_k, all_x = (out_k, state_k) + tuple(grads_k), (out_x, state_x) + tuple(grads_x)
+            row["gap_" + precision] = [gap(a, b) for a, b in zip(all_k, all_x)]
+            # the last state has no oracle here (the recurrence hands back o alone)
+            oracle = lambda every: [gap(a[0], b) for a, b in zip(every[:1] + every[2:], exact)]
+            row["xla_from_recurrence_" + precision] = oracle(all_x)
+            row["kernel_from_recurrence_" + precision] = oracle(all_k)
+            bound = max(row["xla_from_recurrence_" + precision])
+            within[precision] = all(bool(jnp.all(jnp.isfinite(a))) for a in all_k) and max(
+                row["gap_" + precision]) <= bound
+            suffix = "_ms" if precision == "default" else "_highest_ms"
+            row["kernel_fwd" + suffix] = round(_least_ms(lambda: ours[0](*inputs), reps), 4)
+            row["kernel_fwd_bwd" + suffix] = round(_least_ms(lambda: ours[1](*inputs), reps), 4)
+            if precision == "default":
+                row["xla_fwd_ms"] = round(_least_ms(lambda: theirs[0](*inputs), reps), 4)
+                row["xla_fwd_bwd_ms"] = round(_least_ms(lambda: theirs[1](*inputs), reps), 4)
+        row["parity"] = "ok" if all(within.values()) else "FAIL"
+    except Exception as exc:  # a kernel the compiler refuses is a finding
+        row["parity"] = "ERROR"
+        row["error"] = "%s: %s" % (type(exc).__name__, str(exc)[:400])
+    emit(row)
+    return [row] if row["parity"] != "ok" else []
+
+
 #: ``--columns leaf``: (workers, a worker's leaf) — the grid's largest leaves:
 #: cell 5's held experts, cells 5 and 8's head (18,992 is 148 whole lanes and
 #: 48 columns), cell 7's ``wkv_a`` (576: 4 whole lanes and 64), cell 9's fused
@@ -526,7 +621,8 @@ def main():
     ap.add_argument("--columns", default="gar,attention",
                     help="which checks run: 'gar' (the rules), 'attention' (the fused kernel), "
                          "'select' (the threshold by counting), 'leaf' (the plane kernels' "
-                         "leaf entry against their 2-D entry)")
+                         "leaf entry against their 2-D entry), 'delta' (the gated delta rule's "
+                         "kernel pair against the XLA form)")
     ap.add_argument("--attention-reps", type=int, default=5)
     ap.add_argument("--attention-shapes", default=",".join(name for name, *_ in ATTENTION_SHAPES),
                     help="which rows of the attention column run")
@@ -564,6 +660,8 @@ def main():
         failed += run_select_check(allow_interpret=args.allow_interpret)
     if "leaf" in columns:
         failed += run_leaf_check(allow_interpret=args.allow_interpret)
+    if "delta" in columns:
+        failed += run_delta_check(args.attention_reps, allow_interpret=args.allow_interpret)
     sys.exit(1 if failed else 0)
 
 

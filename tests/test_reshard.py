@@ -324,3 +324,47 @@ def test_the_select_kernel_compiles_for_the_chip_at_the_cells_shape(v5e_2x2, mon
     assert " sort(" not in text and " while(" not in text
     # beside the scores and the pairs: the pairs as booleans' int8 again at most
     assert compiled.memory_analysis().temp_size_in_bytes <= workers * chunk * length
+
+
+def test_the_delta_rule_kernels_compile_for_the_chip_at_the_cells_shape(v5e_2x2, monkeypatch):
+    """ops/delta_rule.py's kernel pair as the step of
+    ``qwen3next_avgmedian_causal4k`` calls it — three workers under ``vmap``, L =
+    4096 in chunks of 64, 32 heads of 128 by 128, through models/qwen3_next.py's
+    entry — compiles for the described chip: a tile's blocks and the backward
+    kernel's scratch fit VMEM, every slice is on a tile boundary, the masked
+    sums and the transposed products lower.  What the pair leaves in HBM
+    beside its operands and results is the state entering each chunk (64 KiB a
+    chunk a head) and the row-major copies in and out; nothing chunk-shaped
+    (``f32[3,1,64,64,32,128]``: eleven copies of 201 MB in the XLA form), and no
+    scan."""
+    from jax.sharding import SingleDeviceSharding
+
+    from aggregathor_tpu.models import qwen3_next
+    from aggregathor_tpu.ops import delta_rule
+
+    monkeypatch.setattr(delta_rule.hw, "on_tpu", lambda: True)  # compile the kernels, not interpret
+    monkeypatch.setattr(delta_rule, "info", lambda *_: None)
+    workers, length, heads, chunk, width = 3, 4096, 32, 64, 128
+    assert delta_rule.delta_rule_form(length, chunk, width, width) == "kernel"
+    one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
+    shape = lambda *dims: jax.ShapeDtypeStruct((workers, 1, length, heads) + dims, jnp.float32,
+                                               sharding=one_chip)
+    rule = jax.vmap(lambda *args: qwen3_next.delta_rule(*args, chunk))
+
+    def scalar(*args):
+        out, state = rule(*args)
+        return jnp.sum(out ** 2) + jnp.sum(state ** 2)
+
+    compiled = compile_uncached(jax.jit(jax.grad(scalar, argnums=(0, 1, 2, 3, 4))),
+                                shape(width), shape(width), shape(width), shape(), shape())
+    text = compiled.as_text()
+    calls = re.findall(r"^ *%?([\w.-]*delta_rule_(?:fwd|bwd)[\w.-]*) = .* custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', text, re.M)
+    assert len(calls) == 2 and any("fwd" in name for name in calls) and any(
+        "bwd" in name for name in calls), calls
+    assert " while(" not in text and "f32[3,1,64,64,32,128]" not in text
+    operand = workers * length * heads * width * 4
+    states = workers * heads * (length // chunk) * width * width * 4
+    # the kept states, the output's cotangent, and a row-major copy each of q, k, v and of the
+    # three gradients the other way (parameters and results of THIS program lie head-major)
+    assert compiled.memory_analysis().temp_size_in_bytes < states + 8 * operand
