@@ -51,7 +51,13 @@ held to.  ON A TPU, where ``ops.delta_rule.delta_rule_form`` takes the shape
 grid's), the same equations run as ops/delta_rule.py's Pallas kernel pair — the
 state a head in VMEM across a sequence's chunks, a chunk's matrices never in
 HBM, a backward kernel of its own — through the one entry ``delta_rule``
-(``model.delta_rule``).  Consecutive layers
+(``model.delta_rule``).  What stands between ``u W_qkvz`` and that entry — the
+convolution, SiLU, the heads' L2 norm, the key heads handed to the value heads
+they serve — is ``split_heads`` in plain XLA and, on a TPU where
+``ops.gdn_operands.operands_form`` takes the shape (a float32 projection, heads
+of whole lanes, a length of whole tiles), ops/gdn_operands.py's kernel pair:
+one read of the projection as it lies, q, k and v written once in the blocks
+the delta rule's kernels read.  Consecutive layers
 of one kind are a run of stacked leaves (models/laguna.py ``layer_runs``:
 scanned where several, each layer under ``jax.checkpoint``), so a period is a
 run of ``full_interval - 1`` DeltaNet layers and a run of one attention layer,
@@ -70,6 +76,7 @@ import jax.numpy as jnp
 from . import Experiment, register
 from ..utils import UserException, parse_keyval
 from ..ops.delta_rule import gated_delta_rule
+from ..ops.gdn_operands import gdn_operands
 from .common import check_dtype
 from .laguna import (LagunaExperiment, causal_attention, gated_unit, layer_runs, next_token_loss,
                      seeded_corpus, seeded_leaves)
@@ -300,29 +307,45 @@ def causal_conv(x, taps):
     return sum(padded[:, j:j + x.shape[1]] * taps[:, j] for j in range(width))
 
 
-def l2_normalised(x, eps=1e-6):
+#: what ``l2_normalised`` adds to a head's sum of squares
+L2_EPS = 1e-6
+
+
+def l2_normalised(x, eps=L2_EPS):
     return x * jax.lax.rsqrt(jnp.sum(jnp.square(x), axis=-1, keepdims=True) + eps)
+
+
+def split_heads(projected, taps, cfg):
+    """``u @ w_qkvz`` (B, L, 2 keys + 2 values) and the convolution's taps -> q
+    and k (B, L, H, Dk), v and z (B, L, H, Dv), H the value heads: q, k and v
+    through the causal convolution and SiLU, q and k L2-normalised a key head,
+    q scaled, and handed to the value heads they serve; q, k, v float32."""
+    b, length, _ = projected.shape
+    keys, values = cfg.key_heads * cfg.key_dim, cfg.value_heads * cfg.value_dim
+    rep = cfg.value_heads // cfg.key_heads
+    mixed, z = jnp.split(projected, [2 * keys + values], axis=-1)
+    mixed = jax.nn.silu(causal_conv(mixed, taps)).astype(jnp.float32)
+    q, k, v = jnp.split(mixed, [keys, 2 * keys], axis=-1)
+    by_key_head = lambda a: jnp.repeat(
+        l2_normalised(a.reshape(b, length, cfg.key_heads, cfg.key_dim)), rep, axis=2)
+    heads = (b, length, cfg.value_heads, cfg.value_dim)
+    return by_key_head(q) * cfg.key_dim ** -0.5, by_key_head(k), v.reshape(heads), z.reshape(heads)
 
 
 def delta_heads(u, layer, cfg):
     """(B, L, D) normed inputs -> q and k (B, L, H, Dk), v and z (B, L, H, Dv),
-    g and beta (B, L, H), H the value heads; all but z float32."""
-    b, length, _ = u.shape
+    g and beta (B, L, H), H the value heads; all but z float32.  q, k, v and z
+    come out of the projection through ops/gdn_operands.py's chooser: its kernel
+    pair on a TPU for the shapes it takes, ``split_heads`` everywhere else."""
     w = lambda name: layer[name].astype(cfg.dtype)
-    keys, values = cfg.key_heads * cfg.key_dim, cfg.value_heads * cfg.value_dim
-    rep = cfg.value_heads // cfg.key_heads
-    mixed, z = jnp.split(u @ w("w_qkvz"), [2 * keys + values], axis=-1)
-    mixed = jax.nn.silu(causal_conv(mixed, w("conv"))).astype(jnp.float32)
-    q, k, v = jnp.split(mixed, [keys, 2 * keys], axis=-1)
-    by_key_head = lambda a: jnp.repeat(
-        l2_normalised(a.reshape(b, length, cfg.key_heads, cfg.key_dim)), rep, axis=2)
+    q, k, v, z = gdn_operands(
+        u @ w("w_qkvz"), w("conv"), cfg.key_heads, cfg.value_heads, cfg.key_dim, cfg.value_dim,
+        L2_EPS, lambda projected, taps: split_heads(projected, taps, cfg))
     gates = (u @ w("w_ba")).astype(jnp.float32)
     beta = jax.nn.sigmoid(gates[..., :cfg.value_heads])
     g = -jnp.exp(layer["A_log"].astype(jnp.float32)) * jax.nn.softplus(
         gates[..., cfg.value_heads:] + layer["dt_bias"].astype(jnp.float32))
-    heads = (b, length, cfg.value_heads, cfg.value_dim)
-    return (by_key_head(q) * cfg.key_dim ** -0.5, by_key_head(k), v.reshape(heads),
-            z.reshape(heads), g, beta)
+    return q, k, v, z, g, beta
 
 
 def gated_delta_net(u, layer, cfg):

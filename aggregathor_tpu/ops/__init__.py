@@ -15,4 +15,8 @@
 - ``ops.delta_rule`` — the chunked gated delta rule as a Pallas kernel pair,
   forward and backward behind one ``custom_vjp``, the state a head in VMEM
   across a sequence's chunks; models/qwen3_next.py's ``delta_rule`` calls it.
+- ``ops.gdn_operands`` — a Gated DeltaNet layer's q, k, v from the projection's
+  output in one pass (the causal convolution, SiLU, the heads' L2 norm, the
+  repeat to the value heads), a Pallas kernel pair behind one ``custom_vjp``;
+  models/qwen3_next.py's ``delta_heads`` calls it.
 """

@@ -60,6 +60,21 @@ the first worker), which is the bound: the kernel may stand no further from
 the XLA form than the XLA form stands from the recurrence; and each form alone,
 forward and forward + backward, least ms of ``--attention-reps``.
 
+The operands column (``run_operands_check``; ``--columns operands``):
+ops/gdn_operands.py's kernel pair against models/qwen3_next.py's ``split_heads``
+at ``qwen3next_avgmedian_causal4k``'s shape (three workers vmapped, a projection
+of 1 x 4,096 x 12,288 float32, 16 key heads and 32 value heads of 128 lanes, 4
+taps shared by the workers) — q, k, v, z and the gradients of a seeded scalar of
+all four to the projection and to the taps, each as the largest difference over
+the oracle's largest entry, at ``highest`` and at the default precision (the
+kernels hold no product: the two agree); and each form alone, least ms of
+``--attention-reps``: the forward kernel and the backward kernel (the gradient
+of a scalar of q, k, v needs no forward pass: the residuals are the arguments)
+beside the XLA form's forward and forward + backward, the bytes the kernels
+move (``fwd_bytes``, ``bwd_bytes``: blocks in with their halos, blocks out; dz
+a zero the compiler makes) and the share of 819 GB/s that is
+(``*_of_hbm_pct``; the backward's time holds the scalar's own passes too).
+
 The leaf column (``run_leaf_check``; ``--columns leaf``): each plane kernel's
 leaf entry (the step's in-place path, parallel/in_place.py) against its 2-D
 entry at the grid's largest leaves — (4, 8, 768, 2048) x 4 workers, (2048,
@@ -528,6 +543,95 @@ def run_delta_check(reps=5, workers=3, length=4096, heads=32, width=128, chunk=6
     return [row] if row["parity"] != "ok" else []
 
 
+def run_operands_check(reps=5, workers=3, length=4096, key_heads=16, value_heads=32, width=128,
+                       taps=4, allow_interpret=False, emit=_print_row):
+    """Parity and time of the operands' kernel pair against the XLA form, one
+    row; ``emit(row)``.  Returns the row if its parity is not ``"ok"``."""
+    import jax
+    import jax.numpy as jnp
+
+    from aggregathor_tpu.models import qwen3_next
+    from aggregathor_tpu.ops import gdn_operands
+
+    if not allow_interpret and gdn_operands._interpret():
+        raise RuntimeError("the operands column needs a TPU backend (the kernels would "
+                           "interpret on %r)" % jax.default_backend())
+    cfg = qwen3_next.Qwen3NextConfig(key_heads=key_heads, value_heads=value_heads, key_dim=width,
+                                     value_dim=width, conv=taps)
+    keys, values = key_heads * width, value_heads * width
+    key = jax.random.PRNGKey(23)
+    normal = lambda place, *dims: jax.random.normal(jax.random.fold_in(key, place), dims,
+                                                    jnp.float32)
+    projected = normal(0, workers, 1, length, 2 * keys + 2 * values)
+    conv = 0.5 * normal(1, 2 * keys + values, taps)
+    weights = [normal(2 + i, workers, 1, length, value_heads, width) for i in range(4)]
+
+    def traced(form, precision):
+        """(forward, scalar-and-gradients) of the model's entry, traced inside the seam."""
+        def operands(projected, taps):
+            return gdn_operands.gdn_operands(
+                projected, taps, key_heads, value_heads, width, width, qwen3_next.L2_EPS,
+                lambda projected, taps: qwen3_next.split_heads(projected, taps, cfg))
+
+        forward = jax.vmap(operands, in_axes=(0, None))
+        scalar = lambda projected, taps: sum(
+            jnp.sum(out * w) for out, w in zip(forward(projected, taps), weights))
+
+        def enter(fn):
+            def call(*args):
+                with gdn_operands.forced_form(form), jax.default_matmul_precision(precision):
+                    return fn(*args)
+            return call
+        return enter(jax.jit(forward)), enter(jax.jit(jax.grad(scalar, argnums=(0, 1))))
+
+    names = ("q", "k", "v", "z", "dprojected", "dtaps")
+    gap = lambda a, b: float("%.3g" % (jnp.max(jnp.abs(a - b)) / jnp.max(jnp.abs(b))))
+    tile = gdn_operands.tile_for(length)
+    rows, lanes, out = workers * length, 2 * keys + values, 3 * values
+    halo = gdn_operands.ROWS / tile
+    row = {"metric": "pallas_tpu_check", "rule": "operands", "workers": workers, "length": length,
+           "heads": "%d/%d" % (key_heads, value_heads), "width": width, "taps": taps,
+           "tile": tile, "quantities": list(names),
+           "fwd_bytes": int(4 * rows * (lanes * (1 + halo) + out)),
+           "bwd_bytes": int(4 * rows * (lanes * (1 + 2 * halo) + out * (1 + halo) + values
+                                        + lanes + values))}
+    try:
+        for precision in ("highest", "default"):
+            ours, theirs = traced("kernel", precision), traced("xla", precision)
+            all_k = tuple(ours[0](projected, conv)) + tuple(ours[1](projected, conv))
+            all_x = tuple(theirs[0](projected, conv)) + tuple(theirs[1](projected, conv))
+            row["gap_" + precision] = [gap(a, b) for a, b in zip(all_k, all_x)]
+            row["finite_" + precision] = all(bool(jnp.all(jnp.isfinite(a))) for a in all_k)
+        # each form alone: the kernels through ``fused_operands`` (their results as the delta
+        # rule's kernels read them: no z, no 4-D shape to lay out); the gradient of a scalar of
+        # q, k, v needs no forward pass (the residuals are the arguments), so it times the
+        # backward kernel alone where the XLA form runs forward and backward
+        alone = {"kernel": lambda projected, taps: gdn_operands.fused_operands(
+            projected, taps, key_heads, value_heads, width, width, qwen3_next.L2_EPS, tile)[:3],
+                 "xla": lambda projected, taps: qwen3_next.split_heads(projected, taps, cfg)[:3]}
+        for form, operands in alone.items():
+            forward = jax.jit(jax.vmap(operands, in_axes=(0, None)))
+            flat = [w.reshape(forward(projected, conv)[i].shape) for i, w in enumerate(weights[:3])]
+            backward = jax.jit(jax.grad(lambda projected, taps: sum(
+                jnp.sum(out * w) for out, w in zip(forward(projected, taps), flat)), argnums=(0, 1)))
+            row[form + "_fwd_ms"] = round(_least_ms(lambda: forward(projected, conv), reps), 4)
+            row[form + ("_bwd_ms" if form == "kernel" else "_fwd_bwd_ms")] = round(
+                _least_ms(lambda: backward(projected, conv), reps), 4)
+        if not allow_interpret:
+            share = lambda count, ms: round(100 * count / (ms * 1e-3) / 819e9, 2)
+            row["fwd_of_hbm_pct"] = share(row["fwd_bytes"], row["kernel_fwd_ms"])
+            row["bwd_of_hbm_pct"] = share(row["bwd_bytes"], row["kernel_bwd_ms"])
+        # float32 sums in another order on both sides, and a division that is two Newton steps
+        row["parity"] = "ok" if all(
+            row["finite_" + p] and max(row["gap_" + p]) < 2e-6 for p in ("highest", "default")) \
+            else "FAIL"
+    except Exception as exc:  # a kernel the compiler refuses is a finding
+        row["parity"] = "ERROR"
+        row["error"] = "%s: %s" % (type(exc).__name__, str(exc)[:400])
+    emit(row)
+    return [row] if row["parity"] != "ok" else []
+
+
 #: ``--columns leaf``: (workers, a worker's leaf) — the grid's largest leaves:
 #: cell 5's held experts, cells 5 and 8's head (18,992 is 148 whole lanes and
 #: 48 columns), cell 7's ``wkv_a`` (576: 4 whole lanes and 64), cell 9's fused
@@ -622,7 +726,8 @@ def main():
                     help="which checks run: 'gar' (the rules), 'attention' (the fused kernel), "
                          "'select' (the threshold by counting), 'leaf' (the plane kernels' "
                          "leaf entry against their 2-D entry), 'delta' (the gated delta rule's "
-                         "kernel pair against the XLA form)")
+                         "kernel pair against the XLA form), 'operands' (the DeltaNet "
+                         "operands' kernel pair against the XLA form)")
     ap.add_argument("--attention-reps", type=int, default=5)
     ap.add_argument("--attention-shapes", default=",".join(name for name, *_ in ATTENTION_SHAPES),
                     help="which rows of the attention column run")
@@ -662,6 +767,8 @@ def main():
         failed += run_leaf_check(allow_interpret=args.allow_interpret)
     if "delta" in columns:
         failed += run_delta_check(args.attention_reps, allow_interpret=args.allow_interpret)
+    if "operands" in columns:
+        failed += run_operands_check(args.attention_reps, allow_interpret=args.allow_interpret)
     sys.exit(1 if failed else 0)
 
 

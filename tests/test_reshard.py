@@ -368,3 +368,63 @@ def test_the_delta_rule_kernels_compile_for_the_chip_at_the_cells_shape(v5e_2x2,
     # the kept states, the output's cotangent, and a row-major copy each of q, k, v and of the
     # three gradients the other way (parameters and results of THIS program lie head-major)
     assert compiled.memory_analysis().temp_size_in_bytes < states + 8 * operand
+
+
+def test_the_operands_kernels_hand_the_delta_rules_their_blocks_on_the_chip(v5e_2x2, monkeypatch):
+    """ops/gdn_operands.py's kernel pair as the step of
+    ``qwen3next_avgmedian_causal4k`` calls it — three workers under ``vmap``
+    with the taps shared, a projection of 1 x 4,096 x 12,288, 16 key heads and
+    32 value heads of 128 lanes, through models/qwen3_next.py's ``delta_heads``
+    INTO the delta rule's entry — compiles for the described chip: whole rows
+    of the projection a tile and the backward kernel's scratch fit VMEM, the
+    sublane rotations and the selects lower.  Between the two kernel pairs
+    stands NOTHING: ``delta_rule_fwd`` reads ``gdn_operands_fwd``'s three
+    results and ``gdn_operands_bwd`` reads ``delta_rule_bwd``'s three as they
+    are (a ``get-tuple-element`` each: no ``copy``, no ``reshape``, no fusion),
+    and no (3, 1, 4096, 8192) tensor — the XLA form's passes — is left."""
+    from jax.sharding import SingleDeviceSharding
+
+    from aggregathor_tpu.models import qwen3_next
+    from aggregathor_tpu.ops import delta_rule, gdn_operands
+
+    for module in (delta_rule, gdn_operands):   # compile the kernels, not interpret
+        monkeypatch.setattr(module.hw, "on_tpu", lambda: True)
+        monkeypatch.setattr(module, "info", lambda *_: None)
+    cfg, workers = qwen3_next.Qwen3NextConfig(), 3
+    assert gdn_operands.operands_form(cfg.seq, cfg.key_dim, cfg.value_dim, cfg.conv,
+                                      cfg.dtype) == "kernel"
+    one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
+    shape = lambda *dims: jax.ShapeDtypeStruct(dims, jnp.float32, sharding=one_chip)
+    shapes = qwen3_next.run_shapes(cfg, qwen3_next.DELTA, 1)
+    layer = {name: shape(*dims[1:]) for name, dims in shapes.items()}
+
+    def one_worker(u, layer):
+        q, k, v, z, g, beta = qwen3_next.delta_heads(u, layer, cfg)
+        out, _ = qwen3_next.delta_rule(q, k, v, g, beta, cfg.chunk)
+        return jnp.sum(out * z)
+
+    scalar = lambda u, layer: jnp.sum(jax.vmap(one_worker, in_axes=(0, None))(u, layer))
+    compiled = compile_uncached(jax.jit(jax.grad(scalar, argnums=(0, 1))),
+                                shape(workers, 1, cfg.seq, cfg.hidden), layer)
+    text = compiled.as_text()
+    call = lambda name: re.search(
+        r"^ *%?([\w.-]*" + name + r"[\w.-]*) = .* custom-call\((.*?)\), "
+        r'custom_call_target="tpu_custom_call"', text, re.M)
+    calls = {name: call(name) for name in ("gdn_operands_fwd", "gdn_operands_bwd",
+                                           "delta_rule_fwd", "delta_rule_bwd")}
+    assert all(calls.values()), calls
+
+    def results_of(name):
+        """The names of ``name``'s results: its get-tuple-elements, by index."""
+        found = re.findall(r"^ *(%[\w.-]+) = [^\n]* get-tuple-element\(%" + re.escape(
+            calls[name].group(1)) + r"\), index=(\d)", text, re.M)
+        return {int(index): result for result, index in found}
+
+    operands = lambda name: [arg.strip() for arg in re.sub(
+        r"/\*index=\d+\*/", "", calls[name].group(2)).split(",")]
+    made, handed_back = results_of("gdn_operands_fwd"), results_of("delta_rule_bwd")
+    assert operands("delta_rule_fwd")[:3] == [made[0], made[1], made[2]]
+    # dq, dq's halo, dk, dk's halo, dv, dv's halo, after the projection thrice and the taps
+    assert operands("gdn_operands_bwd")[4:10] == [handed_back[i] for i in (0, 0, 1, 1, 2, 2)]
+    mixed = 2 * cfg.key_heads * cfg.key_dim + cfg.value_heads * cfg.value_dim
+    assert "f32[%d,1,%d,%d]" % (workers, cfg.seq, mixed) not in text
